@@ -13,6 +13,7 @@ import argparse
 import json
 import sys
 import traceback
+from pathlib import Path
 
 
 def main() -> None:
@@ -40,6 +41,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro import obs
+    from repro.runtime import use_compile_cache
+
+    use_compile_cache(Path(__file__).resolve().parent.parent)
 
     from . import (
         bench_combine,

@@ -21,7 +21,9 @@ from repro.core.matrices import rmat
 
 
 def main() -> None:
-    mesh = jax.make_mesh((8,), ("data",))
+    # one "data" axis over every device this process sees (8 virtual CPU
+    # devices by default; the chips of a TPU host when run there)
+    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
     A = rmat(1 << 13, 200_000, seed=0)
     x = np.random.default_rng(0).standard_normal(A.n_cols).astype(np.float32)
     y_ref = A.matvec(x)

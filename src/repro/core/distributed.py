@@ -1,33 +1,37 @@
 """Distributed SpMV: the paper's block scheduling at cluster scale.
 
-The 2D block grid maps onto the device mesh; the combine part becomes a
-collective.  Two placements mirror the paper's fixed/competitive split:
+The tile stream is split across the devices of one mesh axis; the combine
+part becomes a collective.  Two placements mirror the paper's
+fixed/competitive split:
 
-* ``grid``     — locality-first (the *fixed* part writ large): row blocks
-  shard over "data", column blocks over "model".  Each device owns the x
-  segments of its column shard, so SpMV needs **no communication at all**;
-  the combine is one ``psum_scatter`` over "model".
-* ``balanced`` — the *competitive* part: blocks are LPT-assigned to
-  devices by tile count regardless of position (deterministic replay of
-  the paper's ticket-lock), x is fully replicated, partials reduce with a
-  single ``psum``.  Better makespan on power-law matrices, more bytes on
-  the wire — exactly the trade the paper navigates on-chip.
+* ``grid``     — locality-first (the *fixed* part writ large): tiles follow
+  their column block (block ``b`` lives on device ``b mod n``), so each
+  device gathers from its own x segments only.
+* ``balanced`` — the *competitive* part: tiles are LPT-assigned to devices
+  by count regardless of position (deterministic replay of the paper's
+  ticket-lock).  Better makespan on power-law matrices.
 
-Implementation: ``shard_map`` over the mesh; per-device tile lists are
+Either way x is replicated and the per-device partial outputs reduce with
+one ``psum`` over the axis.
+
+Implementation: ``jax.shard_map`` over the mesh; per-device tile lists are
 padded to equal length with null tiles (rowgroup -1 → accumulated into a
 scratch row), so every device runs the same program — the SPMD analogue of
-the paper's equal-length fixed quota.
+the paper's equal-length fixed quota.  The stacked shards are placed with
+``NamedSharding(mesh, P(axis))``, one slice per device.  The per-device
+body is a jnp gather-multiply, not the Pallas kernel.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Literal
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .formats import CSRMatrix
 from .partition import PartitionConfig
@@ -50,12 +54,13 @@ def _pad_tiles(arrs, n_pad, rowgroup_fill=-1):
 
 @dataclasses.dataclass
 class ShardedSpmv:
-    """Device-placed tile shards + the jitted sharded matvec."""
+    """Device-placed tile shards + the sharded matvec."""
 
     mesh: Mesh
     mode: str
+    axis: str
     tiles: HBPTiles
-    # stacked per-device tiles [n_dev, T_max, ...]
+    # stacked per-device tiles [n_dev, T_max, ...], sharded over ``axis``
     data: jax.Array
     cols: jax.Array
     rowgroup: jax.Array
@@ -65,41 +70,43 @@ class ShardedSpmv:
     loads: np.ndarray
 
     def matvec(self, x: jax.Array) -> jax.Array:
-        from jax.experimental.shard_map import shard_map
-
         cfg = self.tiles.cfg
-        nrg = self.tiles.n_rowgroups
-        n_cb = -(-self.tiles.shape[1] // cfg.col_block)
-        axis = "data"  # worker axis
-        xb_len = n_cb * cfg.col_block
-
-        def local(data, cols, rowgroup, colblock, xb):
-            # data: [1, T, G, L] local shard; xb: [n_cb, col_block] replicated
-            segs = xb[colblock[0]]  # [T, col_block]
-            T, G, L = data.shape[1:]
-            gathered = jnp.take_along_axis(
-                segs[:, None, :], cols[0].reshape(T, 1, G * L), axis=2
-            ).reshape(T, G, L)
-            contrib = jnp.sum(data[0] * gathered, axis=2)  # [T, G]
-            seg_ids = jnp.where(rowgroup[0] < 0, nrg, rowgroup[0])
-            y_part = jax.ops.segment_sum(contrib, seg_ids, num_segments=nrg + 1)
-            y_part = y_part[:nrg]  # drop the null-tile scratch row
-            # combine part: one collective over the worker axis
-            return jax.lax.psum(y_part, axis)[None]
-
-        n_workers = self.data.shape[0]
-        fn = shard_map(
-            local,
-            mesh=self.mesh,
-            in_specs=(P(axis), P(axis), P(axis), P(axis), P()),
-            out_specs=P(axis),
-            check_rep=False,
+        return _sharded_matvec(
+            self.data, self.cols, self.rowgroup, self.colblock, self.perm,
+            jnp.asarray(x, jnp.float32),
+            mesh=self.mesh, axis=self.axis, n_rowgroups=self.tiles.n_rowgroups,
+            col_block=cfg.col_block, n_rows=self.n_rows,
         )
-        xb = jnp.pad(x, (0, xb_len - x.shape[0])).reshape(n_cb, cfg.col_block)
-        y_hashed = fn(self.data, self.cols, self.rowgroup, self.colblock, xb)[0]
-        flat = y_hashed.reshape(-1)
-        out = jnp.zeros(self.perm.shape[0], flat.dtype).at[self.perm].set(flat)
-        return out[: self.n_rows]
+
+
+@functools.partial(
+    jax.jit, static_argnames=("mesh", "axis", "n_rowgroups", "col_block", "n_rows")
+)
+def _sharded_matvec(data, cols, rowgroup, colblock, perm, x, *, mesh, axis,
+                    n_rowgroups, col_block, n_rows):
+    n_cb = -(-x.shape[0] // col_block)
+    xb = jnp.pad(x, (0, n_cb * col_block - x.shape[0])).reshape(n_cb, col_block)
+
+    def local(data, cols, rowgroup, colblock, xb):
+        # data: [1, T, G, L] local shard; xb: [n_cb, col_block] replicated
+        segs = xb[colblock[0]]  # [T, col_block]
+        T, G, L = data.shape[1:]
+        gathered = jnp.take_along_axis(
+            segs[:, None, :], cols[0].reshape(T, 1, G * L), axis=2
+        ).reshape(T, G, L)
+        contrib = jnp.sum(data[0] * gathered, axis=2)  # [T, G]
+        seg_ids = jnp.where(rowgroup[0] < 0, n_rowgroups, rowgroup[0])
+        y_part = jax.ops.segment_sum(contrib, seg_ids, num_segments=n_rowgroups + 1)
+        # combine part: one collective over the worker axis; the null-tile
+        # scratch row is dropped
+        return jax.lax.psum(y_part[:n_rowgroups], axis)
+
+    sharded = P(axis)
+    y_hashed = jax.shard_map(
+        local, mesh=mesh, in_specs=(sharded,) * 4 + (P(),), out_specs=P(),
+    )(data, cols, rowgroup, colblock, xb)
+    out = jnp.zeros(perm.shape[0], y_hashed.dtype).at[perm].set(y_hashed.reshape(-1))
+    return out[:n_rows]
 
 
 def build_sharded_spmv(
@@ -115,8 +122,7 @@ def build_sharded_spmv(
     n_workers = mesh.shape[axis]
 
     if mode == "balanced":
-        # competitive placement: LPT over per-rowgroup tile runs so each
-        # worker's output rows stay disjoint *per tile*, balance by count
+        # competitive placement: LPT over tiles, balanced by count
         costs = np.ones(tiles.n_tiles)
         sched = lpt_schedule(costs, n_workers)
         assign = sched.assignment
@@ -139,17 +145,22 @@ def build_sharded_spmv(
             tiles.colblock[ids],
         )
         per_dev.append(_pad_tiles(arrs, t_max - ids.size))
-    stacked = [np.stack([d[i] for d in per_dev]) for i in range(4)]
+    # each device receives only its own slice of the stacked shards
+    sharded = NamedSharding(mesh, P(axis))
+    data, cols, rowgroup, colblock = (
+        jax.device_put(np.stack([d[i] for d in per_dev]), sharded) for i in range(4)
+    )
 
     return ShardedSpmv(
         mesh=mesh,
         mode=mode,
+        axis=axis,
         tiles=tiles,
-        data=jnp.asarray(stacked[0]),
-        cols=jnp.asarray(stacked[1]),
-        rowgroup=jnp.asarray(stacked[2]),
-        colblock=jnp.asarray(stacked[3]),
-        perm=jnp.asarray(tiles.perm),
+        data=data,
+        cols=cols,
+        rowgroup=rowgroup,
+        colblock=colblock,
+        perm=jax.device_put(tiles.perm.astype(np.int32), NamedSharding(mesh, P())),
         n_rows=csr.n_rows,
         loads=loads,
     )

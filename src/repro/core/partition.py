@@ -142,6 +142,8 @@ class Partition2D:
       block-ordered entry arrays (the paper's ``begin_nnz``; plays the role
       CSR's ``ptr`` plays, but per block).
     * ``entry_perm`` — permutation taking CSR entry order to block order.
+    * ``entry_rows`` — row of each entry, in CSR entry order (computed once:
+      per-block lookups must not cost O(nnz) each).
     """
 
     csr: CSRMatrix
@@ -149,6 +151,7 @@ class Partition2D:
     counts: np.ndarray  # int64[n_rows, nbc]
     begin_nnz: np.ndarray  # int64[nbr * nbc + 1]
     entry_perm: np.ndarray  # int64[nnz]
+    entry_rows: np.ndarray  # int64[nnz]
 
     @classmethod
     def build(cls, csr: CSRMatrix, cfg: PartitionConfig | None = None) -> "Partition2D":
@@ -171,7 +174,8 @@ class Partition2D:
             begin = np.zeros(nbr * nbc + 1, dtype=np.int64)
             np.cumsum(block_tot.reshape(-1), out=begin[1:])
             perm = block_entry_order(csr, cfg)
-        return cls(csr, cfg, counts, begin, perm)
+            rows = np.repeat(np.arange(n_rows), csr.row_nnz())
+        return cls(csr, cfg, counts, begin, perm, rows)
 
     @property
     def grid(self) -> Tuple[int, int]:
@@ -191,7 +195,6 @@ class Partition2D:
         nbr, nbc = self.grid
         lo, hi = self.begin_nnz[bi * nbc + bj], self.begin_nnz[bi * nbc + bj + 1]
         idx = self.entry_perm[lo:hi]
-        all_rows = np.repeat(np.arange(self.csr.n_rows), self.csr.row_nnz())
-        rows = all_rows[idx] - bi * self.cfg.row_block
+        rows = self.entry_rows[idx] - bi * self.cfg.row_block
         cols = self.csr.indices[idx] - bj * self.cfg.col_block
         return rows, cols, self.csr.data[idx]

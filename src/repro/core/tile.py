@@ -163,6 +163,7 @@ def _build_tiles_impl(csr: CSRMatrix, cfg: PartitionConfig, method: str) -> HBPT
     gpb = R // G  # row groups per row block
 
     reorder = REORDER_METHODS[method]
+    block_nnz = part.block_nnz()  # once: per-block lookups stay O(1)
 
     tiles_data: list = []
     tiles_cols: list = []
@@ -190,12 +191,12 @@ def _build_tiles_impl(csr: CSRMatrix, cfg: PartitionConfig, method: str) -> HBPT
         nnz_hashed = counts[perm]  # [R, nbc]
 
         with obs.span("admit.pack_tiles", row_block=bi):
+            inv = np.empty(R, dtype=np.int64)
+            inv[perm] = np.arange(R)
             for bj in range(nbc):
-                if part.block_nnz()[bi, bj] == 0:
+                if block_nnz[bi, bj] == 0:
                     continue
                 rows, cols, vals = part.block_entries(bi, bj)
-                inv = np.empty(R, dtype=np.int64)
-                inv[perm] = np.arange(R)
                 row_pos = inv[rows]
                 order = np.lexsort((cols, row_pos))
                 row_pos, cols, vals = row_pos[order], cols[order], vals[order]
@@ -276,11 +277,10 @@ def tuned_partition_config(
     tiles, still streamed contiguously).  We choose::
 
         lane  = clip(next_pow2(quantile_0.75 of per-(row, col-block) nnz), 8, 128)
-        group = tile_elems // lane      (tile stays 8x128-sized in VMEM)
+        group = 8
 
-    Narrow lanes trade VPU lane padding (compute, which SpMV has to spare)
-    for HBM bytes (which it does not).  EXPERIMENTS.md §Perf quantifies
-    the utilization/traffic win per suite matrix.
+    Narrow lanes trade VPU lane padding (compute) for HBM bytes; which of
+    the two binds on the chip is not measured yet.
     """
     from .partition import count_block_nnz
 

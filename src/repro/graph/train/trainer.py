@@ -29,6 +29,7 @@ import numpy as np
 
 from repro import obs
 from repro.core.formats import CSRMatrix
+from repro.kernels.ops import default_strategy
 from repro.optim.adamw import AdamWConfig, adamw_update, init_opt_state
 
 from ..aggregate import make_diff_aggregator, plan_diff_aggregator
@@ -84,9 +85,7 @@ class NodeClassifierTrainer:
             lr_peak=2e-2, warmup_steps=5, decay_steps=500, weight_decay=0.0
         )
         self.registry = registry
-        if strategy is None:
-            strategy = "fused" if jax.default_backend() == "tpu" else "stable"
-        self.strategy = strategy
+        self.strategy = strategy or default_strategy()
         self.interpret = interpret
         self.mode = mode
 

@@ -12,7 +12,6 @@ from typing import Literal, NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro import obs
 from repro.core.tile import HBPTiles
@@ -33,6 +32,9 @@ __all__ = [
     "LANE_TILE",
     "blocked_vector",
     "blocked_matrix",
+    "default_strategy",
+    "resolve_interpret",
+    "lower_launch",
 ]
 
 # RHS-width buckets of the k-padded SpMM entry.  ``_hbp_spmm_device`` is
@@ -67,17 +69,10 @@ class DeviceTiles(NamedTuple):
     first: jax.Array  # i32[T]
     data: jax.Array  # f32[T, group, lane]
     cols: jax.Array  # i32[T, group, lane]
-    perm: jax.Array  # i32[padded_rows]
-    visited: jax.Array  # f32[n_rowgroups, 1]: 0 for all-zero row groups
-    # (the hash clusters empty rows, so whole groups can have no tiles;
-    # Pallas leaves never-visited output blocks undefined — mask them)
+    perm: jax.Array  # i32[padded_rows] = i32[n_rowgroups * group]
 
 
 def device_tiles(tiles: HBPTiles) -> DeviceTiles:
-    import numpy as np
-
-    visited = np.zeros((tiles.n_rowgroups, 1), np.float32)
-    visited[tiles.rowgroup] = 1.0
     return DeviceTiles(
         rowgroup=jnp.asarray(tiles.rowgroup, jnp.int32),
         colblock=jnp.asarray(tiles.colblock, jnp.int32),
@@ -85,7 +80,6 @@ def device_tiles(tiles: HBPTiles) -> DeviceTiles:
         data=jnp.asarray(tiles.data, jnp.float32),
         cols=jnp.asarray(tiles.cols, jnp.int32),
         perm=jnp.asarray(tiles.perm, jnp.int32),
-        visited=jnp.asarray(visited),
     )
 
 
@@ -106,10 +100,19 @@ def blocked_matrix(x: jax.Array, col_block: int) -> jax.Array:
     return jnp.pad(x, ((0, pad), (0, 0))).reshape(n_blocks, col_block, k)
 
 
-def _default_interpret() -> bool:
-    # Pallas TPU kernels execute natively on TPU; everywhere else we run the
-    # kernel body in interpret mode (bit-accurate, Python-evaluated).
-    return jax.default_backend() != "tpu"
+def default_strategy() -> str:
+    """The kernel path for this backend, as the registry, the GNN trainer
+    and the solver operator resolve it: the fused Pallas kernel on TPU,
+    the batch-width-invariant ``"stable"`` jnp path elsewhere (off-TPU the
+    Pallas kernels only run interpreted)."""
+    return "fused" if jax.default_backend() == "tpu" else "stable"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The caller's choice, else: Pallas TPU kernels execute natively on
+    TPU and are never interpreted there unless the caller asks; elsewhere
+    interpret mode is their only way to run (bit-accurate, Python-evaluated)."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
 def stream_passes(k: int, strategy: str, k_tiling: str) -> int:
@@ -141,13 +144,15 @@ def modeled_launch_bytes(
     not a measurement: it assumes no cache reuse across passes (the
     pessimistic bound ``bench_memtraffic`` compares against) — useful for
     attributing relative traffic across strategies and k-tilings, which
-    is exactly what Gao et al. identify as the binding constraint.
+    is exactly what Gao et al. identify as the binding constraint.  For
+    the Pallas kernels the x term is an upper bound: they fetch a column
+    block's segment into VMEM once per run of tiles in that block and
+    gather from VMEM.
     """
     passes = stream_passes(k, strategy, k_tiling)
     stream = dt.data.nbytes + dt.cols.nbytes  # the packed tile arrays
     gathers = dt.data.size * 4  # one f32 x gather per tile slot
-    n_rowgroups, group = dt.visited.shape[0], dt.data.shape[1] if dt.data.ndim == 3 else 8
-    out = n_rowgroups * group * max(k, 1) * 4
+    out = dt.perm.size * max(k, 1) * 4  # one row per padded (hashed) row
     return int(passes * (stream + gathers) + out)
 
 
@@ -196,7 +201,6 @@ def _hbp_spmv_device(
             dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, x_blocked,
             n_rowgroups=n_rowgroups, interpret=interpret,
         )
-        y_hashed = jnp.where(dt.visited > 0, y_hashed, 0.0)
     elif strategy == "partials":
         # paper-faithful split: SpMV part (kernel) + combine part (XLA)
         contrib = _k.hbp_spmv_partials(
@@ -231,19 +235,16 @@ def _spmm_hashed_chunk(
 ) -> jax.Array:
     """One SpMM launch on the selected strategy, output in hashed row order.
 
-    The jnp strategies take any k; the Pallas strategies take k <= LANE_TILE
-    (one grid column) or a LANE_TILE multiple (the 2D k-tiled grid) — the
-    caller (``_hbp_spmm_device``) pads accordingly.  Under ``combine="max"``
-    empty rows carry the monoid identity ``-inf`` here; the caller maps it
-    to 0 once, after assembly."""
+    Every strategy takes any k (the Pallas wrappers pad it to their own
+    k-tiles and slice it back).  Under ``combine="max"`` empty rows carry
+    the monoid identity ``-inf`` here; the caller maps it to 0 once, after
+    assembly."""
     if combine == "max":
         if strategy == "fused":
-            y = _k.hbp_spmm_fused_max(
+            return _k.hbp_spmm_fused_max(
                 dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, x_blocked,
                 n_rowgroups=n_rowgroups, interpret=interpret,
             )
-            # never-visited output blocks are undefined memory, not -inf
-            return jnp.where(dt.visited[..., None] > 0, y, -jnp.inf)
         if strategy == "partials":
             contrib = _k.hbp_spmm_partials_max(
                 dt.colblock, dt.data, dt.cols, x_blocked, interpret=interpret
@@ -260,11 +261,10 @@ def _spmm_hashed_chunk(
     if combine != "sum":
         raise ValueError(f"unknown combine {combine!r} (expected 'sum' or 'max')")
     if strategy == "fused":
-        y = _k.hbp_spmm_fused(
+        return _k.hbp_spmm_fused(
             dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, x_blocked,
             n_rowgroups=n_rowgroups, interpret=interpret,
         )
-        return jnp.where(dt.visited[..., None] > 0, y, 0.0)
     if strategy == "partials":
         contrib = _k.hbp_spmm_partials(
             dt.colblock, dt.data, dt.cols, x_blocked, interpret=interpret
@@ -346,15 +346,11 @@ def _hbp_spmm_device(
             combine=combine, interpret=interpret,
         )
     elif k_tiling == "grid" and strategy != "stable":
-        xw = x_blocked
-        if strategy in ("fused", "partials") and k % LANE_TILE:
-            # the 2D grid tiles k in whole lane tiles; padded columns are
-            # zero, contribute nothing, and are sliced back off below
-            xw = jnp.pad(x_blocked, ((0, 0), (0, 0), (0, -k % LANE_TILE)))
+        # the Pallas wrappers pad k to whole lane tiles and slice it back
         y_hashed = _spmm_hashed_chunk(
-            dt, xw, n_rowgroups=n_rowgroups, strategy=strategy,
+            dt, x_blocked, n_rowgroups=n_rowgroups, strategy=strategy,
             combine=combine, interpret=interpret,
-        )[..., :k]
+        )
     else:
         chunks = [
             _spmm_hashed_chunk(
@@ -407,18 +403,37 @@ def hbp_spmv(
         raise ValueError(f"unknown k_tiling {k_tiling!r} (expected one of {K_TILINGS})")
     x = jnp.asarray(x, jnp.float32)
     dt, (n_rowgroups, n_rows, col_block) = _resolve(tiles, x, n_rowgroups, n_rows, col_block)
-    if interpret is None:
-        interpret = _default_interpret()
     _record_launch(dt, 1, op="spmv", strategy=strategy, k_tiling=k_tiling)
     x_blocked = blocked_vector(x, col_block)
-    return _hbp_spmv_device(
-        dt,
-        x_blocked,
-        n_rowgroups=n_rowgroups,
-        n_rows=n_rows,
-        strategy=strategy,
+    entry, kw = _entry(
+        x_blocked, n_rowgroups=n_rowgroups, n_rows=n_rows, strategy=strategy,
         interpret=interpret,
     )
+    return entry(dt, x_blocked, **kw)
+
+
+def _entry(x_blocked, *, n_rowgroups, n_rows, strategy, interpret,
+           combine="sum", k_tiling="grid"):
+    """The jitted entry a wrapper call dispatches, and its static arguments:
+    ``_hbp_spmv_device`` for a blocked vector, ``_hbp_spmm_device`` for a
+    blocked ``[.., k]`` matrix."""
+    kw = dict(n_rowgroups=n_rowgroups, n_rows=n_rows, strategy=strategy,
+              interpret=resolve_interpret(interpret))
+    if len(x_blocked.shape) == 2:
+        return _hbp_spmv_device, kw
+    return _hbp_spmm_device, dict(kw, combine=combine, k_tiling=k_tiling)
+
+
+def lower_launch(dt: DeviceTiles, x, *, col_block: int, **meta):
+    """Lower, without running, the jitted entry that ``hbp_spmv(dt, x,
+    **meta)`` (vector ``x``) or ``hbp_spmm(dt, x, **meta)`` (``[n, k]``
+    block) dispatches.  ``x`` may be a ``jax.ShapeDtypeStruct``; the
+    lowered text shows whether the launch holds the Pallas kernel
+    (``tpu_custom_call``)."""
+    block = blocked_vector if len(x.shape) == 1 else blocked_matrix
+    x_blocked = jax.eval_shape(functools.partial(block, col_block=col_block), x)
+    entry, kw = _entry(x_blocked, **meta)
+    return entry.lower(dt, x_blocked, **kw)
 
 
 def bucket_k(k: int, buckets: tuple = K_BUCKETS) -> int:
@@ -578,20 +593,13 @@ def hbp_spmm(
     """
     x = jnp.asarray(x, jnp.float32)
     dt, (n_rowgroups, n_rows, col_block) = _resolve(tiles, x, n_rowgroups, n_rows, col_block)
-    if interpret is None:
-        interpret = _default_interpret()
     _record_launch(
         dt, x.shape[1], op="spmm", strategy=strategy, k_tiling=k_tiling,
         combine=combine,
     )
     x_blocked = blocked_matrix(x, col_block)
-    return _hbp_spmm_device(
-        dt,
-        x_blocked,
-        n_rowgroups=n_rowgroups,
-        n_rows=n_rows,
-        strategy=strategy,
-        interpret=interpret,
-        combine=combine,
-        k_tiling=k_tiling,
+    entry, kw = _entry(
+        x_blocked, n_rowgroups=n_rowgroups, n_rows=n_rows, strategy=strategy,
+        interpret=interpret, combine=combine, k_tiling=k_tiling,
     )
+    return entry(dt, x_blocked, **kw)

@@ -211,8 +211,6 @@ def unrolled_cfg(cfg: ModelConfig, k: int) -> ModelConfig:
 
 def cost_dict(compiled) -> dict:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax < 0.5 wraps it per-computation
-        ca = ca[0] if ca else {}
     return {k: float(v) for k, v in ca.items() if isinstance(v, (int, float))}
 
 
@@ -319,6 +317,7 @@ def main() -> None:
     shapes = list(SHAPES) if (args.all or not args.shape) else [args.shape]
     meshes = ["single", "multipod"] if args.mesh == "both" else [args.mesh]
 
+    errors = 0
     for arch in archs:
         for shape in shapes:
             for mesh_name in meshes:
@@ -329,10 +328,13 @@ def main() -> None:
                             continue
                     except Exception:
                         pass
-                run_cell(
+                rec = run_cell(
                     arch, shape, mesh_name, out_dir,
                     roofline=(not args.no_roofline) and mesh_name == "single",
                 )
+                errors += rec["status"] == "error"
+    if errors:
+        raise SystemExit(f"[dryrun] {errors} cell(s) recorded status: error")
 
 
 if __name__ == "__main__":

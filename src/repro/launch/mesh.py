@@ -28,13 +28,19 @@ def mesh_devices(n: int):
     return np.array(devs[:n])
 
 
+def _auto_mesh(shape, axes, n):
+    # Auto axes: the models place arrays with with_sharding_constraint under
+    # logical rules, which explicit-axis meshes (make_mesh's default) refuse
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, devices=mesh_devices(n), axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=mesh_devices(n))
+    return _auto_mesh(shape, axes, int(np.prod(shape)))
 
 
 def make_host_mesh():
     """Degenerate 1×1 mesh for smoke tests / single-host examples."""
-    return jax.make_mesh((1, 1), ("data", "model"), devices=mesh_devices(1))
+    return _auto_mesh((1, 1), ("data", "model"), 1)

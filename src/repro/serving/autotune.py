@@ -41,6 +41,7 @@ __all__ = [
     "measure_k_tilings",
     "pick_k_tiling",
     "autotune_partition",
+    "device_kind",
     "DEFAULT_CACHE_DIR",
 ]
 
@@ -79,13 +80,22 @@ class AutotuneResult:
     trials: tuple = ()
 
 
+def device_kind() -> str:
+    """The device the measurements run on (``jax.devices()[0].device_kind``)."""
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
 class AutotuneCache:
     """On-disk partition-config cache: one JSON file per matrix hash.
 
     The directory (default ``.hbp_autotune/``, or ``$HBP_AUTOTUNE_DIR``) is
-    safe to persist across runs and machines of the same matrix corpus —
-    entries are keyed purely by matrix content.  Unreadable or
-    version-mismatched entries are treated as misses, never errors.
+    safe to persist across runs of the same matrix corpus.  Entries are
+    keyed by matrix content AND the device kind that measured them: a
+    geometry timed on one device (a CPU host, say) is a miss on another,
+    whose kernels it never ranked.  Unreadable, version-mismatched or
+    foreign-device entries are treated as misses, never errors.
     """
 
     def __init__(self, path: str | os.PathLike | None = None):
@@ -103,6 +113,8 @@ class AutotuneCache:
             return None
         if entry.get("version") != _CACHE_VERSION or "config" not in entry:
             return None
+        if entry.get("device_kind") != device_kind():
+            return None
         return entry
 
     def get_config(self, key: str) -> Optional[PartitionConfig]:
@@ -118,6 +130,7 @@ class AutotuneCache:
         self.path.mkdir(parents=True, exist_ok=True)
         entry = {
             "version": _CACHE_VERSION,
+            "device_kind": device_kind(),
             "config": dataclasses.asdict(cfg),
             **extra,
         }

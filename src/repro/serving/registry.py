@@ -235,9 +235,9 @@ class MatrixRegistry:
         hbm_budget_bytes: Optional[int] = None,
     ):
         if strategy is None:
-            import jax
+            from repro.kernels.ops import default_strategy
 
-            strategy = "fused" if jax.default_backend() == "tpu" else "stable"
+            strategy = default_strategy()
         if k_tiling not in ("grid", "loop", "auto"):
             raise ValueError(
                 f"unknown k_tiling {k_tiling!r} (expected grid, loop or auto)"

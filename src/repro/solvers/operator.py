@@ -7,10 +7,11 @@ CSR arrays, dense matrices), so a solver loop built on it stays inside one
 
 :func:`aslinearoperator` adapts every container in the library:
 
-* :class:`~repro.core.tile.HBPTiles` — the production path: the Pallas HBP
-  kernels (SpMV for single vectors, the multi-RHS SpMM kernel for ``[n, k]``
-  blocks).  The host tiles are staged to the device ONCE at operator
-  construction; solver iterations touch only :class:`DeviceTiles`.
+* :class:`~repro.core.tile.HBPTiles` — the production path: the HBP kernel
+  path the backend resolves (the fused Pallas kernels on TPU; SpMV for
+  single vectors, the multi-RHS SpMM kernel for ``[n, k]`` blocks).  The
+  host tiles are staged to the device ONCE at operator construction;
+  solver iterations touch only :class:`DeviceTiles`.
 * :class:`~repro.core.formats.CSRMatrix` — the segment-sum CSR baseline
   (Algorithm 1) for apples-to-apples workload benchmarks.
 * dense ``np.ndarray`` / ``jax.Array`` — ``jnp.dot``, the oracle solvers
@@ -37,6 +38,10 @@ class LinearOperator:
     ``matmat`` defaults to column-at-a-time matvec; format-aware adapters
     (HBP tiles) override it with the one-launch SpMM kernel.
     """
+
+    # HBP operators: the (DeviceTiles, keywords) every launch passes to the
+    # repro.kernels.ops wrappers, e.g. for ops.lower_launch
+    launch_args: tuple | None = None
 
     def __init__(
         self,
@@ -69,7 +74,7 @@ class LinearOperator:
 
 
 def _from_hbp_tiles(
-    tiles: HBPTiles, *, strategy: str = "fused", interpret: bool | None = None
+    tiles: HBPTiles, *, strategy: str | None = None, interpret: bool | None = None
 ) -> LinearOperator:
     from repro.kernels import ops
 
@@ -78,14 +83,16 @@ def _from_hbp_tiles(
         n_rowgroups=tiles.n_rowgroups,
         n_rows=tiles.shape[0],
         col_block=tiles.cfg.col_block,
-        strategy=strategy,
+        strategy=strategy or ops.default_strategy(),
         interpret=interpret,
     )
-    return LinearOperator(
+    op = LinearOperator(
         tiles.shape,
         matvec=lambda x: ops.hbp_spmv(dt, x, **meta),
         matmat=lambda x: ops.hbp_spmm(dt, x, **meta),
     )
+    op.launch_args = (dt, meta)
+    return op
 
 
 def _from_csr(csr: CSRMatrix) -> LinearOperator:
@@ -106,12 +113,13 @@ def _from_dense(a) -> LinearOperator:
 
 
 def aslinearoperator(
-    A, *, strategy: str = "fused", interpret: bool | None = None
+    A, *, strategy: str | None = None, interpret: bool | None = None
 ) -> LinearOperator:
     """Adapt any supported container to a :class:`LinearOperator`.
 
-    ``strategy`` / ``interpret`` configure the Pallas kernels and apply
-    only to :class:`HBPTiles` inputs.
+    ``strategy`` / ``interpret`` configure the HBP kernel path and apply
+    only to :class:`HBPTiles` inputs; ``strategy=None`` resolves it per
+    backend (:func:`repro.kernels.ops.default_strategy`).
     """
     if isinstance(A, LinearOperator):
         return A

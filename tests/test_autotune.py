@@ -22,6 +22,7 @@ from repro.serving import (
     matrix_hash,
     spmm_probe,
 )
+from repro.serving.autotune import device_kind
 
 # tiny geometries keep each measured build/launch in the milliseconds
 CANDIDATES = [
@@ -138,6 +139,24 @@ def test_corrupt_cache_entry_is_a_miss(tmp_path, csr):
     res = autotune_partition(csr, cache=cache, search=False)
     assert not res.cache_hit  # recomputed, rewritten
     assert autotune_partition(csr, cache=cache, search=False).cache_hit
+
+
+def test_foreign_device_entry_is_a_miss(tmp_path, csr):
+    """An entry measured on another device kind never picks this one's
+    geometry: it is a miss, and the re-measured entry replaces it."""
+    cache = AutotuneCache(tmp_path / "cache")
+    autotune_partition(csr, cache=cache, search=False)
+    path = tmp_path / "cache" / f"{matrix_hash(csr)}.json"
+    entry = json.loads(path.read_text())
+    assert entry["device_kind"] == device_kind()
+    entry["device_kind"] = "TPU v5 lite" if device_kind() == "cpu" else "cpu"
+    entry["config"]["lane"] = 64  # a geometry only the foreign device chose
+    path.write_text(json.dumps(entry))
+    assert cache.get(matrix_hash(csr)) is None
+    res = autotune_partition(csr, cache=cache, search=False)
+    assert not res.cache_hit
+    assert res.cfg == tuned_partition_config(csr)
+    assert json.loads(path.read_text())["device_kind"] == device_kind()
 
 
 def test_empty_candidates_uses_heuristic(tmp_path, csr):

@@ -41,6 +41,11 @@ FAMILIES = {
 }
 
 
+def hbp_op(tiles):
+    """The HBP operator on the fused Pallas kernels (interpreted off-TPU)."""
+    return aslinearoperator(tiles, strategy="fused", interpret=True)
+
+
 def spd_family(name):
     A = FAMILIES[name]().to_dense().astype(np.float64)
     n = A.shape[0]
@@ -65,7 +70,7 @@ def test_operator_adapts_every_container(spd64, rng):
     y_ref = spd64 @ x
     Y_ref = spd64 @ X
     for container in (spd64, csr, tiles):
-        op = aslinearoperator(container, interpret=True)
+        op = aslinearoperator(container, strategy="fused", interpret=True)
         np.testing.assert_allclose(np.asarray(op(x)), y_ref, rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(op(X)), Y_ref, rtol=1e-4, atol=1e-4)
     # matvec-only operators synthesize matmat column by column
@@ -103,7 +108,7 @@ def test_cg_converges_on_suite_families_hbp(family, rng):
     S = spd_family(family)
     tiles = build_tiles(csr_from_dense(S), CFG)
     b = rng.standard_normal(S.shape[0]).astype(np.float32)
-    res = cg(tiles, b, tol=1e-7, maxiter=800)
+    res = cg(hbp_op(tiles), b, tol=1e-7, maxiter=800)
     x_ref = np.linalg.solve(S.astype(np.float64), b)
     assert bool(res.converged)
     err = np.abs(np.asarray(res.x) - x_ref).max() / np.abs(x_ref).max()
@@ -113,13 +118,14 @@ def test_cg_converges_on_suite_families_hbp(family, rng):
 def test_cg_multirhs_matches_columnwise(spd64, rng):
     """Blocked-RHS CG (one SpMM per iteration) == k independent solves."""
     tiles = build_tiles(csr_from_dense(spd64), PartitionConfig(row_block=32, col_block=32, group=8, lane=8))
+    op = hbp_op(tiles)
     B = rng.standard_normal((64, 4)).astype(np.float32)
-    res = cg(tiles, B, tol=1e-7, maxiter=500)
+    res = cg(op, B, tol=1e-7, maxiter=500)
     assert bool(res.converged)
     X_ref = np.linalg.solve(spd64.astype(np.float64), B)
     np.testing.assert_allclose(np.asarray(res.x), X_ref, rtol=1e-4, atol=1e-5)
     for j in range(4):
-        single = cg(tiles, B[:, j], tol=1e-7, maxiter=500)
+        single = cg(op, B[:, j], tol=1e-7, maxiter=500)
         np.testing.assert_allclose(np.asarray(res.x)[:, j], np.asarray(single.x), atol=1e-5)
 
 
@@ -153,7 +159,7 @@ def test_bicgstab_hbp_path_multirhs(rng):
     N = (A + (np.abs(A).sum(axis=1).max() + 1) * np.eye(n, dtype=np.float32)).astype(np.float32)
     tiles = build_tiles(csr_from_dense(N), CFG)
     B = rng.standard_normal((n, 3)).astype(np.float32)
-    res = bicgstab(tiles, B, tol=1e-7, maxiter=1000)
+    res = bicgstab(hbp_op(tiles), B, tol=1e-7, maxiter=1000)
     assert bool(res.converged)
     X_ref = np.linalg.solve(N.astype(np.float64), B)
     err = np.abs(np.asarray(res.x) - X_ref).max() / np.abs(X_ref).max()
@@ -227,7 +233,7 @@ def test_jacobi_cg_through_hbp_plan_diagonal(rng):
     csr = csr_from_dense(A)
     tiles = build_tiles(csr, CFG)
     b = rng.standard_normal(96).astype(np.float32)
-    res = cg(tiles, b, tol=1e-6, maxiter=600, M=jacobi(csr.diagonal()))
+    res = cg(hbp_op(tiles), b, tol=1e-6, maxiter=600, M=jacobi(csr.diagonal()))
     assert bool(res.converged)
     x_ref = np.linalg.solve(A.astype(np.float64), b)
     assert np.abs(np.asarray(res.x) - x_ref).max() / np.abs(x_ref).max() < 1e-4
@@ -286,7 +292,7 @@ def test_block_jacobi_hash_group_partition(rng):
     flat = np.concatenate(blocks)
     assert np.array_equal(np.sort(flat), np.arange(n))
     assert all(len(b) <= tiles.cfg.group for b in blocks)
-    res = cg(tiles, rng.standard_normal(n).astype(np.float32), tol=1e-8,
+    res = cg(hbp_op(tiles), rng.standard_normal(n).astype(np.float32), tol=1e-8,
              maxiter=400, M=block_jacobi(csr, blocks=blocks))
     assert bool(res.converged)
 
@@ -363,7 +369,7 @@ def test_power_iteration_on_suite_families_hbp(family):
     dense dominant eigenvalue to 1e-5 on every family."""
     S = spd_family(family)
     tiles = build_tiles(csr_from_dense(S), CFG)
-    res = power_iteration(tiles, tol=1e-6, maxiter=3000)
+    res = power_iteration(hbp_op(tiles), tol=1e-6, maxiter=3000)
     lam_ref = float(np.linalg.eigvalsh(S.astype(np.float64))[-1])
     assert bool(res.converged)
     assert abs(float(res.eigenvalue) - lam_ref) / lam_ref < 1e-5
@@ -397,15 +403,15 @@ def test_pagerank_multi_personalization_spmm(rng):
     """k personalization vectors in one run (SpMM path) == k single runs."""
     adj = rmat(1 << 7, 600, seed=9, symmetric=False)
     M, dang = transition_matrix(adj)
-    tiles = build_tiles(M, CFG)
+    op = hbp_op(build_tiles(M, CFG))
     n = adj.n_rows
     P = rng.random((n, 3)).astype(np.float32) + 0.01
-    multi = pagerank(tiles, personalization=P, dangling=dang, tol=1e-10, maxiter=300)
+    multi = pagerank(op, personalization=P, dangling=dang, tol=1e-10, maxiter=300)
     assert bool(multi.converged)
     pm = np.asarray(multi.x)
     np.testing.assert_allclose(pm.sum(axis=0), np.ones(3), atol=1e-5)
     for j in range(3):
-        single = pagerank(tiles, personalization=P[:, j], dangling=dang, tol=1e-10, maxiter=300)
+        single = pagerank(op, personalization=P[:, j], dangling=dang, tol=1e-10, maxiter=300)
         np.testing.assert_allclose(pm[:, j], np.asarray(single.x), atol=1e-6)
 
 

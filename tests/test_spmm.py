@@ -228,6 +228,28 @@ def test_max_identity_never_leaks_on_empty_rows(strategy, rng):
     assert (Y[~empty] < 0).all()
 
 
+@pytest.mark.parametrize("k", [1, 6, 256])
+@pytest.mark.parametrize("combine", ["sum", "max"])
+def test_fused_row_groups_without_tiles_are_zero(combine, k, rng):
+    """Row groups no tile visits come back exactly 0: the fused kernels'
+    output starts at the monoid identity, which no launch overwrites."""
+    dense = np.zeros((64, 40), np.float32)
+    dense[::4] = rng.standard_normal((16, 40)) * (rng.random((16, 40)) < 0.5)
+    tiles = build_tiles(
+        csr_from_dense(dense), PartitionConfig(row_block=32, col_block=32, group=4, lane=4)
+    )
+    assert len(np.unique(tiles.rowgroup)) < tiles.n_rowgroups  # the hash clusters them
+    X = -1.0 - rng.random((40, k)).astype(np.float32)
+    if combine == "sum" and k == 1:
+        Y = np.asarray(hbp_spmv(tiles, X[:, 0], strategy="fused", interpret=True))[:, None]
+    else:
+        Y = np.asarray(hbp_spmm(tiles, X, strategy="fused", combine=combine, interpret=True))
+    empty = ~dense.any(axis=1)
+    assert (Y[empty] == 0).all()
+    expect = dense @ X if combine == "sum" else _max_oracle(dense, X)
+    np.testing.assert_allclose(Y, expect, rtol=1e-5, atol=1e-5)
+
+
 def test_max_combine_empty_matrix_is_zero():
     tiles = build_tiles(
         csr_from_dense(np.zeros((16, 16), np.float32)),
@@ -286,3 +308,37 @@ def test_end_to_end_equivalence(family):
     np.testing.assert_allclose(y_csr_jnp / scale, y_csr_np / scale, atol=2e-6)
     np.testing.assert_allclose(y_hbp_ref / scale, y_csr_np / scale, atol=2e-6)
     np.testing.assert_allclose(y_pallas / scale, y_csr_np / scale, atol=2e-6)
+
+
+@pytest.mark.parametrize("k", [1, 3, 256])
+@pytest.mark.parametrize("combine", ["sum", "max"])
+@pytest.mark.parametrize("strategy", ["fused", "partials"])
+def test_launches_split_into_tile_chunks_match_dense(strategy, combine, k, rng, monkeypatch):
+    """A tile stream longer than one launch's SMEM budget runs as several
+    launches over one output buffer; row-group runs that straddle a launch
+    boundary continue where the previous launch left off."""
+    import importlib
+
+    import jax
+
+    kernels = importlib.import_module("repro.kernels.hbp_spmv")
+    monkeypatch.setattr(kernels, "TILE_CHUNK", 5)
+    jax.clear_caches()  # the wrappers are jitted: retrace under the small chunk
+    # long rows: row groups span many tiles, so runs straddle chunks
+    dense = (rng.standard_normal((40, 120)) * (rng.random((40, 120)) < 0.4)).astype(
+        np.float32
+    )
+    tiles = build_tiles(
+        csr_from_dense(dense), PartitionConfig(row_block=16, col_block=64, group=8, lane=4)
+    )
+    assert tiles.n_tiles > 5 * 4 and (tiles.first[5::5] == 0).any()
+    X = rng.standard_normal((120, k)).astype(np.float32)
+    if combine == "sum" and k == 1:
+        Y = np.asarray(hbp_spmv(tiles, X[:, 0], strategy=strategy, interpret=True))[:, None]
+    else:
+        Y = np.asarray(
+            hbp_spmm(tiles, X, strategy=strategy, combine=combine, interpret=True)
+        )
+    expect = dense @ X if combine == "sum" else _max_oracle(dense, X)
+    jax.clear_caches()
+    np.testing.assert_allclose(Y, expect, rtol=1e-4, atol=1e-4)
