@@ -35,9 +35,9 @@ are reset afterwards so a ``--trace`` run's artifact is not polluted by
 benchmark-loop spans.
 
 Set ``REPRO_OBS_DUMP=PATH`` to write the full obs snapshot (including the
-serving engines' ``attr.*`` bandwidth-attribution counters) before the
-benchmark's registries go out of scope — the input
-``python -m repro.analysis.report --attribution PATH`` renders.
+serving engines' request log) before the benchmark's registries go out of
+scope — the input ``python -m repro.analysis.report --obs PATH`` (or
+``--requests PATH``) renders.
 """
 from __future__ import annotations
 
@@ -80,7 +80,7 @@ def _time_serving(csr, name: str, n_req: int, repeats: int, keep: list):
     vclock = [0.0]
     eng = ServingEngine(reg, max_wait_s=0.002, clock=lambda: vclock[0])
     # metric registries are weakly aggregated — keep the MatrixRegistry
-    # alive so a REPRO_OBS_DUMP snapshot still sees its attr.* counters
+    # alive so a REPRO_OBS_DUMP snapshot still sees its serving counters
     keep.append(reg)
     return timeit(lambda: _serve_cycle(eng, name, xs, vclock), repeats=repeats)
 

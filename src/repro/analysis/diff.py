@@ -7,7 +7,7 @@ Both arguments must be the *same kind* of artifact, either:
 * two ``repro.obs.dump()`` snapshots — the diff decomposes the change
   per phase (``admit.*`` vs ``kernels.*`` vs ``serve.*``), per span name,
   per request-log aggregate (queue wait vs compute share), and per
-  ``attr.*`` (matrix, strategy, k_tiling) attribution counter; or
+  labelled counter; or
 * two ``benchmarks.run --json`` artifacts — the diff is per bench record
   (gate metric: ``min_us``, falling back to ``median_us``) with the same
   phase rollup over the ``suite/name`` prefixes.
@@ -180,7 +180,7 @@ def _counter_rows(a: dict, b: dict) -> List[dict]:
     for key in sorted(set(ca) & set(cb)):
         base = key.split("{", 1)[0]
         if base.endswith("_s"):
-            # seconds-valued counters (attr.compute_s, attr.modeled_s,
+            # seconds-valued counters (serving.compute_s,
             # registry.preprocess_s ...) are time — they rank as culprits
             rows.append(_row(key, 1e3 * ca[key], 1e3 * cb[key], "ms", timed=True))
         else:

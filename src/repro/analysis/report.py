@@ -8,25 +8,18 @@ aggregates, amortized-preprocess ledger)::
 
     PYTHONPATH=src python -m repro.analysis.report --obs obs.json
 
-``--attribution PATH`` renders only the bandwidth-attribution join from a
-snapshot: achieved vs modeled bytes per (matrix, strategy, k_tiling),
-flagging plans below the modeled roofline
-(:mod:`repro.obs.attribution`)::
-
-    REPRO_OBS_DUMP=obs.json python benchmarks/bench_obs.py
-    PYTHONPATH=src python -m repro.analysis.report --attribution obs.json
-
 ``--requests PATH`` renders the slowest-N request waterfall from a
-snapshot's request log: per-request queue-wait vs compute-share
-decomposition, trace ids included so rows join to flight dumps and
-histogram exemplars (``--top`` bounds N)::
+snapshot's request log: per-request queue wait, dispatch and time in
+flight, and the queue-wait vs compute-share decomposition, trace ids
+included so rows join to flight dumps and histogram exemplars (``--top``
+bounds N)::
 
     PYTHONPATH=src python -m repro.analysis.report --requests obs.json --top 10
 
 ``--explain MATRIX`` renders the per-matrix explain report — partition
 quality (tile occupancy, competitive ratio, hash-group cohesion),
-autotune decision provenance, modeled-vs-measured bandwidth and the
-imbalance verdict — from the ``--obs`` snapshot (default
+autotune decision provenance, the served requests' dispatch / in-flight
+split and the imbalance verdict — from the ``--obs`` snapshot (default
 ``serve_obs.json``, the artifact ``examples/serve_spmv.py`` leaves)::
 
     PYTHONPATH=src python -m repro.analysis.report --explain circuit --obs serve_obs.json
@@ -143,18 +136,11 @@ def main() -> None:
         help="render the dashboard from a repro.obs.dump() snapshot instead",
     )
     ap.add_argument(
-        "--attribution",
-        default=None,
-        metavar="PATH",
-        help="render achieved-vs-modeled bandwidth per (matrix, strategy, "
-        "k_tiling) from a repro.obs.dump() snapshot",
-    )
-    ap.add_argument(
         "--requests",
         default=None,
         metavar="PATH",
-        help="render the slowest-N request waterfall (queue wait vs compute "
-        "share, trace ids) from a repro.obs.dump() snapshot",
+        help="render the slowest-N request waterfall (queue wait, dispatch, "
+        "in flight, compute share, trace ids) from a repro.obs.dump() snapshot",
     )
     ap.add_argument(
         "--top",
@@ -167,7 +153,7 @@ def main() -> None:
         default=None,
         metavar="MATRIX",
         help="render the per-matrix explain report (partition quality, "
-        "autotune provenance, modeled-vs-measured bandwidth, imbalance "
+        "autotune provenance, dispatch / in-flight split, imbalance "
         "verdict) from the --obs snapshot (default serve_obs.json)",
     )
     ap.add_argument(
@@ -199,12 +185,6 @@ def main() -> None:
 
         snapshot = json.loads(Path(args.requests).read_text())
         print(waterfall(snapshot, n=args.top))
-        return
-    if args.attribution:
-        from repro.obs.attribution import attribution_rows, render_attribution
-
-        snapshot = json.loads(Path(args.attribution).read_text())
-        print(render_attribution(attribution_rows(snapshot)))
         return
     if args.obs:
         from repro.obs.report import render

@@ -18,7 +18,9 @@ Two kernel strategies, both consuming the tile format of
   (``ops.hbp_spmv(..., strategy="partials")``).
 
 All six entry points are one kernel body (:func:`_tile_columns`) under two
-launch geometries; SpMV is the ``k = 1`` case of SpMM.
+launch geometries; SpMV is the ``k = 1`` case of SpMM.  Each passes its own
+name to ``pallas_call`` (``name=``, the entry point's name), so a profile
+tells the six kernels apart.
 
 **Layouts.**  Every block obeys the TPU's rule for the last two block
 dimensions (divisible by (8, 128) or equal to the array's):
@@ -216,7 +218,7 @@ def _chunks(T: int):
 
 
 def _fused(rowgroup, colblock, first, data, cols, x_blocked, *, n_rowgroups,
-           combine, interpret):
+           combine, interpret, name):
     T, group, lane = data.shape
     xs = _segments(x_blocked)
     _, k, seg = xs.shape
@@ -244,12 +246,13 @@ def _fused(rowgroup, colblock, first, data, cols, x_blocked, *, n_rowgroups,
             out_shape=jax.ShapeDtypeStruct(y.shape, jnp.float32),
             input_output_aliases={6: 0},
             interpret=interpret,
+            name=name,
         )(rowgroup[lo : lo + n], colblock[lo : lo + n], first[lo : lo + n],
           data, cols, xs, y)
     return y
 
 
-def _partials(colblock, data, cols, x_blocked, *, combine, interpret):
+def _partials(colblock, data, cols, x_blocked, *, combine, interpret, name):
     T, group, lane = data.shape
     xs = _segments(x_blocked)
     _, k, seg = xs.shape
@@ -274,6 +277,7 @@ def _partials(colblock, data, cols, x_blocked, *, combine, interpret):
             out_shape=jax.ShapeDtypeStruct(y.shape, jnp.float32),
             input_output_aliases={4: 0},
             interpret=interpret,
+            name=name,
         )(colblock[lo : lo + n], data, cols, xs, y)
     return y
 
@@ -284,7 +288,8 @@ def hbp_spmv_fused(rowgroup, colblock, first, data, cols, x_blocked, *,
     """Fused-combine HBP SpMV over ``x_blocked: f32[n_col_blocks, col_block]``.
     Returns y in hashed row order, shape [n_rowgroups, group]."""
     return _fused(rowgroup, colblock, first, data, cols, x_blocked,
-                  n_rowgroups=n_rowgroups, combine="sum", interpret=interpret)[..., 0]
+                  n_rowgroups=n_rowgroups, combine="sum", interpret=interpret,
+                  name="hbp_spmv_fused")[..., 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -293,7 +298,7 @@ def hbp_spmv_partials(colblock, data, cols, x_blocked, *,
     """SpMV part only (paper-faithful): per-tile partial vectors [T, group];
     the combine part reduces them by row group."""
     return _partials(colblock, data, cols, x_blocked, combine="sum",
-                     interpret=interpret)[..., 0]
+                     interpret=interpret, name="hbp_spmv_partials")[..., 0]
 
 
 @functools.partial(jax.jit, static_argnames=("n_rowgroups", "interpret"))
@@ -307,7 +312,8 @@ def hbp_spmm_fused(rowgroup, colblock, first, data, cols, x_blocked, *,
     ``k`` times.  Returns y in hashed row order, [n_rowgroups, group, k]."""
     k = x_blocked.shape[-1]
     return _fused(rowgroup, colblock, first, data, cols, x_blocked,
-                  n_rowgroups=n_rowgroups, combine="sum", interpret=interpret)[..., :k]
+                  n_rowgroups=n_rowgroups, combine="sum", interpret=interpret,
+                  name="hbp_spmm_fused")[..., :k]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -317,7 +323,7 @@ def hbp_spmm_partials(colblock, data, cols, x_blocked, *,
     [T, group, k]; the combine part reduces them by row group."""
     k = x_blocked.shape[-1]
     return _partials(colblock, data, cols, x_blocked, combine="sum",
-                     interpret=interpret)[..., :k]
+                     interpret=interpret, name="hbp_spmm_partials")[..., :k]
 
 
 @functools.partial(jax.jit, static_argnames=("n_rowgroups", "interpret"))
@@ -329,7 +335,8 @@ def hbp_spmm_fused_max(rowgroup, colblock, first, data, cols, x_blocked, *,
     back ``-inf`` for the caller to zero (``ops._hbp_spmm_device``)."""
     k = x_blocked.shape[-1]
     return _fused(rowgroup, colblock, first, data, cols, x_blocked,
-                  n_rowgroups=n_rowgroups, combine="max", interpret=interpret)[..., :k]
+                  n_rowgroups=n_rowgroups, combine="max", interpret=interpret,
+                  name="hbp_spmm_fused_max")[..., :k]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -339,4 +346,4 @@ def hbp_spmm_partials_max(colblock, data, cols, x_blocked, *,
     [T, group, k]; the combine part reduces them with ``segment_max``."""
     k = x_blocked.shape[-1]
     return _partials(colblock, data, cols, x_blocked, combine="max",
-                     interpret=interpret)[..., :k]
+                     interpret=interpret, name="hbp_spmm_partials_max")[..., :k]

@@ -6,11 +6,15 @@ steps all emit through this one surface, and the paper's amortization
 ledger (preprocessing cost vs traffic served) falls out of its counters.
 
 **Off by default.**  ``enable()`` (or ``REPRO_OBS=1`` in the environment)
-turns it on; while disabled, :func:`span`, :func:`counter`,
-:func:`gauge`, :func:`histogram` and :func:`series` all return one shared
-no-op object whose methods do nothing — a hot call site pays a module
-attribute read and a falsy check, nothing allocates, nothing locks.
-Call sites that want even that gone guard with ``if obs.enabled():``.
+turns it on; while disabled, :func:`counter`, :func:`gauge`,
+:func:`histogram` and :func:`series` all return one shared no-op object
+whose methods do nothing — a hot call site pays a module attribute read
+and a falsy check, nothing allocates, nothing locks.  Call sites that want
+even that gone guard with ``if obs.enabled():``.  :func:`span` is the one
+exception: enabled or not, it enters a ``jax.profiler.TraceAnnotation`` of
+its name, so the program's spans show in any captured profile next to the
+device's ops (about a microsecond a span with no profile active); only the
+in-process buffer is gated.
 
 Two kinds of state:
 
@@ -48,7 +52,7 @@ from .metrics import (  # noqa: F401  (re-exported surface)
 )
 from .flight import FlightRecorder, get_flight  # noqa: F401
 from .slo import SLO, SLOEngine, worst_status  # noqa: F401
-from .trace import Span, Tracer, get_tracer  # noqa: F401
+from .trace import Annotation, Span, Tracer, get_tracer  # noqa: F401
 from .requesttrace import (  # noqa: F401
     RequestContext,
     RequestLog,
@@ -85,6 +89,7 @@ __all__ = [
     "Histogram",
     "Series",
     "MetricRegistry",
+    "Annotation",
     "Span",
     "Tracer",
     "FlightRecorder",
@@ -172,13 +177,16 @@ def disable() -> None:
 
 
 def span(name: str, **args):
-    """Timed scope context manager (no-op while disabled)::
+    """Timed scope context manager::
 
         with obs.span("admit.build_tiles", matrix=name) as sp:
             tiles = build(...)
             sp.annotate(tiles=tiles.n_tiles)
+
+    Always a profiler annotation of ``name``; recorded in the tracer's
+    buffer, with its args, only while enabled.
     """
-    return get_tracer().span(name, **args) if _enabled else NOOP
+    return get_tracer().span(name, **args) if _enabled else Annotation(name)
 
 
 def flow(name: str, fid: str, phase: str = "s", **args) -> None:

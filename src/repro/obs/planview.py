@@ -31,9 +31,10 @@ scrape through the OpenMetrics exporter and land in every ``obs.dump()``),
 alongside the autotune **decision provenance** (which candidates were
 measured, what each cost, why the winner won, how ``k_tiling`` was
 picked).  :func:`explain_report` joins the static picture with the
-*measured* ``attr.*`` bandwidth-attribution counters into the per-matrix
-"why is this fast or slow" report ``python -m repro.analysis.report
---explain MATRIX`` renders.
+*measured* split of the matrix's served requests (queue wait, dispatch,
+time in flight, batches ahead at launch) into the per-matrix "why is this
+fast or slow" report ``python -m repro.analysis.report --explain MATRIX``
+renders.
 """
 from __future__ import annotations
 
@@ -209,7 +210,7 @@ def register_plan_metrics(
 
     ``metrics`` is the serving registry's shared
     :class:`~repro.obs.metrics.MetricRegistry`; gauges are labelled
-    ``matrix=name`` so they join the ``attr.*`` / ``serving.*`` families
+    ``matrix=name`` so they join the ``serving.*`` families
     in dumps and OpenMetrics scrapes.  Numeric quality keys become
     ``plan.<key>`` gauges; the bounded occupancy sample feeds the
     ``plan.tile_occupancy`` histogram; autotune provenance lands as
@@ -340,20 +341,45 @@ def _verdict(pm: dict) -> List[str]:
     return lines
 
 
-def explain_report(snapshot: dict, matrix: str, *, hw=None) -> str:
+def _request_split(snapshot: dict, matrix: str) -> List[str]:
+    """Lines of the matrix's served requests, split at flush and launch:
+    p50 / p95 of queue wait, dispatch and time in flight, and the mean
+    number of batches in flight ahead of each launch (the request log)."""
+    rows = [
+        r for r in snapshot.get("requests", [])
+        if r.get("matrix") == matrix and r.get("latency_s") is not None
+    ]
+    if not rows:
+        return ["  n/a — no completed requests for this matrix (serve traffic first)"]
+    lines = [f"  {len(rows)} requests in the log"]
+    for key, label in (
+        ("queue_wait_s", "queue wait"),
+        ("dispatch_s", "dispatch"),
+        ("inflight_s", "in flight"),
+        ("latency_s", "latency"),
+    ):
+        v = [r[key] for r in rows if r.get(key) is not None]
+        if v:
+            p50, p95 = np.percentile(v, [50, 95]) * 1e3
+            lines.append(f"  {label:<10} p50 {p50:.3f}ms  p95 {p95:.3f}ms")
+        else:
+            lines.append(f"  {label:<10} n/a")
+    ahead = [r["ahead"] for r in rows if r.get("ahead") is not None]
+    mean_ahead = _fmt(float(np.mean(ahead))) if ahead else "n/a"
+    lines.append(f"  batches in flight ahead of the launch: mean {mean_ahead}")
+    return lines
+
+
+def explain_report(snapshot: dict, matrix: str) -> str:
     """The per-matrix "why is this fast or slow" report.
 
     Joins three planes of one ``obs.dump()`` snapshot: the static
     partition-quality gauges, the autotune decision provenance, and the
-    measured ``attr.*`` bandwidth attribution vs the modeled roofline.
-    Every section renders "n/a" on missing data (a dump taken before any
-    traffic, or from a registry without plan introspection) and all rows
-    are deterministically ordered.
+    served requests' split into queue wait, dispatch and time in flight
+    (its request log).  Every section renders "n/a" on missing data (a
+    dump taken before any traffic, or from a registry without plan
+    introspection) and all rows are deterministically ordered.
     """
-    from repro.analysis.roofline import V5E
-    from repro.obs.attribution import attribution_rows
-
-    hw = hw or V5E
     pm = plan_metrics_from_snapshot(snapshot, matrix)
     lines = [f"== explain: {matrix} =="]
 
@@ -428,37 +454,20 @@ def explain_report(snapshot: dict, matrix: str, *, hw=None) -> str:
                 "(contracts coincide at the served width — no measurement needed)"
             )
 
-    # --- measured traffic vs model ----------------------------------------
-    lines.append("-- measured traffic (modeled vs measured bandwidth) --")
-    rows = [r for r in attribution_rows(snapshot, hw=hw) if r["matrix"] == matrix]
-    if not rows:
-        lines.append("  n/a — no attr.* counters for this matrix (serve traffic first)")
-    for r in rows:
-        ach = r["achieved_gbps"]
-        frac = r["roofline_fraction"]
-        lines.append(
-            f"  strategy={r['strategy']} k_tiling={r['k_tiling']}: "
-            f"launches={r['launches']} "
-            f"modeled={1e3 * r['modeled_s']:.3f}ms measured={1e3 * r['measured_s']:.3f}ms "
-            f"achieved={'n/a' if ach is None else f'{ach:.3f}'} GB/s"
-            + (
-                ""
-                if frac is None
-                else f" = {100 * frac:.1f}% of {hw.name} HBM"
-            )
-            + ("  [BELOW-ROOFLINE]" if r["below_roofline"] else "")
-        )
+    # --- served requests: queue / dispatch / in flight --------------------
+    lines.append("-- served requests (queue wait / dispatch / in flight) --")
+    lines.extend(_request_split(snapshot, matrix))
 
     # --- verdict -----------------------------------------------------------
     lines.extend(_verdict(pm))
     return "\n".join(lines) + "\n"
 
 
-def explain(matrix: str, snapshot: Optional[dict] = None, *, hw=None) -> str:
+def explain(matrix: str, snapshot: Optional[dict] = None) -> str:
     """Live convenience: explain ``matrix`` from the current process state
     (or a provided ``obs.dump()`` snapshot)."""
     if snapshot is None:
         from repro import obs
 
         snapshot = obs.collect()
-    return explain_report(snapshot, matrix, hw=hw)
+    return explain_report(snapshot, matrix)

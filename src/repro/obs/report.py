@@ -157,14 +157,6 @@ def render(snapshot: dict) -> str:
             for s in spans
         ],
     )
-    # bandwidth attribution, when the serving engine recorded attr.* counters
-    from .attribution import attribution_rows, render_attribution
-
-    attr = attribution_rows(snapshot)
-    if attr:
-        lines.append(render_attribution(attr).rstrip())
-        lines.append("")
-
     # per-request decomposition, when the snapshot carries a request log
     if snapshot.get("requests"):
         from .requesttrace import waterfall

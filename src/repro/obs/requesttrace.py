@@ -3,10 +3,11 @@
 The aggregate telemetry (histograms, burn rates, flight rings) answers
 "how is the fleet doing?"; this module answers the per-request question a
 QoS front-end has to ask: *for this specific request, how much of its
-latency was queue wait vs device compute, and which requests burned the
-SLO?*  Every :meth:`~repro.serving.engine.ServingEngine.submit` mints a
-:class:`RequestContext` — a trace id plus monotonic stamps at submit →
-enqueue → flush-start → dispatch → complete — that rides the request's
+latency was queue wait, dispatch or time in flight, and which requests
+burned the SLO?*  Every :meth:`~repro.serving.engine.ServingEngine.submit`
+mints a :class:`RequestContext` — a trace id plus monotonic stamps at
+submit → enqueue → flush-start → dispatch → launched → complete, and the
+number of batches already in flight at its launch — that rides the request's
 :class:`~repro.serving.batcher.SpMVRequest` through the batcher and the
 flush, and is pushed into the bounded process :class:`RequestLog` on
 completion.
@@ -15,9 +16,9 @@ Cost contract: this path is always live, so it must stay at
 flight-recorder overhead — the context object (one ``__slots__``
 instance + one short trace-id string) is the *only* per-request
 allocation; stamps are plain float attribute writes, and completion is a
-single bounded-deque append.  Everything derived (queue/compute
-decomposition, dict rendering) happens at snapshot time, not on the hot
-path.
+single bounded-deque append.  Everything derived (the queue / dispatch /
+in-flight decomposition, dict rendering) happens at snapshot time, not on
+the hot path.
 
 The trace id is the join key across the whole stack: it lands as the
 **exemplar** on the ``serving.latency_s`` histogram buckets
@@ -65,9 +66,16 @@ class RequestContext:
     """Per-request causality record: trace id + lifecycle stamps.
 
     Stamps are in the *engine clock* domain (injectable, virtual in
-    tests) so the queue/latency decomposition is deterministic wherever
-    latency accounting is; ``compute_s`` is the flushed batch's measured
-    wall compute, attributed to this request via ``batch_share``.
+    tests) so the decomposition is deterministic wherever latency
+    accounting is.  The latency splits at the batch's flush and launch::
+
+        latency_s = queue_wait_s + dispatch_s + inflight_s
+
+    ``t_launched`` is stamped when the batch's launch call has returned
+    (the host's part of the dispatch is over); ``ahead`` counts the
+    batches already in flight then (0 in synchronous mode).
+    ``compute_s`` is the flushed batch's measured wall time from launch
+    to harvest, attributed to this request via ``batch_share``.
     """
 
     __slots__ = (
@@ -77,7 +85,9 @@ class RequestContext:
         "t_enqueue",
         "t_flush_start",
         "t_dispatch",
+        "t_launched",
         "t_complete",
+        "ahead",
         "compute_s",
         "batch_share",
         "batch_k",
@@ -92,7 +102,9 @@ class RequestContext:
         self.t_enqueue: Optional[float] = None
         self.t_flush_start: Optional[float] = None
         self.t_dispatch: Optional[float] = None
+        self.t_launched: Optional[float] = None
         self.t_complete: Optional[float] = None
+        self.ahead: Optional[int] = None
         self.compute_s: Optional[float] = None
         self.batch_share: Optional[float] = None
         self.batch_k: Optional[int] = None
@@ -107,6 +119,22 @@ class RequestContext:
         if self.t_flush_start is None:
             return None
         return self.t_flush_start - self.t_submit
+
+    @property
+    def dispatch_s(self) -> Optional[float]:
+        """Flush-start → launched: batch take, stacking, bucket padding and
+        the launch call, on the host."""
+        if self.t_launched is None or self.t_flush_start is None:
+            return None
+        return self.t_launched - self.t_flush_start
+
+    @property
+    def inflight_s(self) -> Optional[float]:
+        """Launched → complete: the device queue and compute, plus however
+        late the host harvested."""
+        if self.t_complete is None or self.t_launched is None:
+            return None
+        return self.t_complete - self.t_launched
 
     @property
     def latency_s(self) -> Optional[float]:
@@ -134,8 +162,12 @@ class RequestContext:
             "t_enqueue": self.t_enqueue,
             "t_flush_start": self.t_flush_start,
             "t_dispatch": self.t_dispatch,
+            "t_launched": self.t_launched,
             "t_complete": self.t_complete,
             "queue_wait_s": self.queue_wait_s,
+            "dispatch_s": self.dispatch_s,
+            "inflight_s": self.inflight_s,
+            "ahead": self.ahead,
             "compute_s": self.compute_s,
             "compute_share_s": self.compute_share_s,
             "batch_share": self.batch_share,
@@ -222,7 +254,9 @@ def waterfall(
 
     Accepts either a ``repro.obs.dump()``/``collect()`` snapshot (reads
     its ``"requests"`` list) or the request-dict list directly.  Each row
-    shows the queue-vs-compute decomposition numerically and as a bar —
+    shows the latency's split at flush and launch (queue wait, dispatch,
+    in flight, and the batches ``ahead`` of its launch) and the
+    queue-vs-compute decomposition numerically and as a bar —
     ``░`` is queue wait, ``█`` the request's compute share — scaled so
     the slowest request spans ``width`` cells.
     """
@@ -241,8 +275,8 @@ def waterfall(
     rows = rows[:n]
     scale = max(r["latency_s"] for r in rows)
     header = [
-        "trace_id", "matrix", "latency_ms", "queue_ms", "compute_ms",
-        "share", "reason", "queue░ compute█",
+        "trace_id", "matrix", "latency_ms", "queue_ms", "dispatch_ms",
+        "inflight_ms", "ahead", "compute_ms", "share", "reason", "queue░ compute█",
     ]
     table = []
     for r in rows:
@@ -254,12 +288,16 @@ def waterfall(
         q_cells = min(q_cells, width)
         c_cells = min(c_cells, width - q_cells)
         share = r.get("batch_share")
+        ahead = r.get("ahead")
         table.append(
             [
                 str(r.get("trace_id", "?")),
                 str(r.get("matrix", "?")),
                 _ms(lat),
                 _ms(queue),
+                _ms(r.get("dispatch_s")),
+                _ms(r.get("inflight_s")),
+                "n/a" if ahead is None else str(ahead),
                 _ms(comp),
                 "n/a" if share is None else f"1/{round(1 / share)}" if share else "0",
                 str(r.get("flush_reason") or "n/a"),

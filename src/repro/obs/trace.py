@@ -16,6 +16,14 @@ the device is drained inside the measurement::
     with obs.span("serve.flush", matrix=key) as sp:
         y = sp.sync(plan.matmat(X))   # compute lands inside the span
 
+Every span is also a ``jax.profiler.TraceAnnotation`` of its name, so its
+host interval lands in any profile being captured (``jax.profiler.trace``,
+the benchmark's ``--trace 1``), on the same timeline as the device's ops.
+That sink is live whether or not ``repro.obs`` is enabled; the in-process
+buffer below records only while it is (:func:`repro.obs.span` hands out an
+:class:`Annotation` otherwise).  The annotation carries the name alone: the
+span's args stay in the buffer.
+
 The event buffer is bounded (default 1M events); past the cap events are
 dropped and counted, never silently lost.
 """
@@ -27,7 +35,9 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "get_tracer"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Annotation", "Span", "Tracer", "get_tracer"]
 
 
 class Tracer:
@@ -175,11 +185,26 @@ def _jsonable(v):
         return repr(v)
 
 
+class Annotation(TraceAnnotation):
+    """A span while ``repro.obs`` is disabled: the profiler annotation alone.
+
+    Nothing reaches the tracer's buffer; ``annotate`` and ``sync`` behave
+    as on ``repro.obs.NOOP`` (``sync`` does not block)."""
+
+    __slots__ = ()
+
+    def annotate(self, **kw) -> "Annotation":
+        return self
+
+    def sync(self, value):
+        return value
+
+
 class Span:
     """One timed scope.  Use as a context manager; re-entrant per instance
     is not supported (make a new span instead)."""
 
-    __slots__ = ("tracer", "name", "args", "t0", "depth")
+    __slots__ = ("tracer", "name", "args", "t0", "depth", "_annotation")
 
     def __init__(self, tracer: Tracer, name: str, args: Dict[str, object]):
         self.tracer = tracer
@@ -187,12 +212,15 @@ class Span:
         self.args = args
 
     def __enter__(self) -> "Span":
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.depth = self.tracer._enter()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
         self.tracer._exit()
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)

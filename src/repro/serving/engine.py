@@ -40,18 +40,18 @@ backing store is the registry's shared
 :class:`~repro.obs.metrics.MetricRegistry` (one ledger for admission and
 traffic; ``stats()`` is a view over it — including the new ``qos.*``
 shed/virtual-work state), and with ``repro.obs`` enabled the hot loop
-additionally emits flush spans, flush-reason counters, queue-depth gauges
-and deadline-miss counts.
+additionally emits flush-reason counters, queue-depth gauges and
+deadline-miss counts.
 
 Three always-on layers ride the same loop regardless of the obs flag:
 
+* ``serve.submit``, ``serve.flush`` (batch take through the launch call)
+  and ``serve.harvest`` (one completed batch) are :func:`repro.obs.span`
+  scopes: profiler annotations in any captured profile, on the device
+  ops' timeline, and tracer events while obs is enabled;
 * every flush lands in the process **flight recorder** ring, and a
   deadline miss / latency anomaly / queue saturation / load shed triggers
   a Perfetto-loadable post-mortem dump (:mod:`repro.obs.flight`);
-* per-flush **attribution counters** (``attr.launches`` /
-  ``attr.bytes_modeled`` / ``attr.compute_s``, labeled by matrix,
-  strategy and k_tiling) feed the achieved-vs-modeled bandwidth report
-  (:mod:`repro.obs.attribution`);
 * every completed request feeds the **SLO engine**, and
   :meth:`ServingEngine.health` classifies per-matrix burn rates — the
   same classifications the weighted-fair scheduler consumes
@@ -66,7 +66,7 @@ from typing import Dict, Iterable, List, Optional
 import numpy as np
 
 from repro import obs
-from repro.kernels.ops import K_BUCKETS, bucket_k, modeled_launch_bytes
+from repro.kernels.ops import K_BUCKETS, bucket_k
 from repro.obs.flight import FlightRecorder, get_flight
 from repro.obs.requesttrace import RequestContext, RequestLog, get_request_log, new_context
 from repro.obs.slo import SLO, SLOEngine, worst_status
@@ -140,9 +140,11 @@ _SLO_EVAL_EVERY = 32
 class _InFlight:
     """One dispatched-but-unharvested batch (overlap mode)."""
 
-    __slots__ = ("key", "batch", "Y", "k", "reason", "t_dispatch", "t0_wall")
+    __slots__ = (
+        "key", "batch", "Y", "k", "reason", "t_dispatch", "t_launched", "ahead", "t0_wall",
+    )
 
-    def __init__(self, key, batch, Y, k, reason, t_dispatch, t0_wall):
+    def __init__(self, key, batch, Y, k, reason, t_dispatch, t_launched, ahead, t0_wall):
         """Record the dispatched batch and its launch stamps."""
         self.key = key
         self.batch = batch
@@ -150,6 +152,8 @@ class _InFlight:
         self.k = k
         self.reason = reason
         self.t_dispatch = t_dispatch  # engine clock domain
+        self.t_launched = t_launched  # engine clock: the launch call returned
+        self.ahead = ahead  # batches in flight when this one launched
         self.t0_wall = t0_wall  # wall clock, for compute attribution
 
 
@@ -264,47 +268,48 @@ class ServingEngine:
         that deep — the request is shed *before* it holds a queue slot,
         never silently dropped after.
         """
-        plan = self.registry.get(key)
-        x = np.asarray(x, np.float32)
-        if x.shape != (plan.shape[1],):
-            raise ValueError(
-                f"x has shape {x.shape}, matrix {key!r} expects ({plan.shape[1]},)"
+        with obs.span("serve.submit", matrix=key):
+            plan = self.registry.get(key)
+            x = np.asarray(x, np.float32)
+            if x.shape != (plan.shape[1],):
+                raise ValueError(
+                    f"x has shape {x.shape}, matrix {key!r} expects ({plan.shape[1]},)"
+                )
+            q = self.qos_of(key)
+            depth = self.batcher.pending(key)
+            if q.max_queue is not None and depth >= q.max_queue:
+                # typed shedding: counted on the always-live ledger, flight-
+                # dumped (rate-limited, so the first shed of an overload burst
+                # leaves a post-mortem), then surfaced to the caller
+                self.metrics.counter("qos.shed", matrix=key, qos=q.name).inc()
+                self.flight.trigger(
+                    "load_shed", matrix=key, qos=q.name, depth=depth, limit=q.max_queue
+                )
+                raise BackpressureError(key, q.name, depth, q.max_queue)
+            if q.max_wait_s is not None:
+                self.batcher.set_wait(key, q.max_wait_s)
+            t_submit = self.clock()
+            # the context is the request's one record of its lifecycle; every
+            # later stamp is a plain attribute write on it
+            req = SpMVRequest(
+                key=key,
+                x=x,
+                req_id=self._next_id,
+                t_submit=t_submit,
+                ctx=new_context(key, t_submit),
             )
-        q = self.qos_of(key)
-        depth = self.batcher.pending(key)
-        if q.max_queue is not None and depth >= q.max_queue:
-            # typed shedding: counted on the always-live ledger, flight-
-            # dumped (rate-limited, so the first shed of an overload burst
-            # leaves a post-mortem), then surfaced to the caller
-            self.metrics.counter("qos.shed", matrix=key, qos=q.name).inc()
-            self.flight.trigger(
-                "load_shed", matrix=key, qos=q.name, depth=depth, limit=q.max_queue
-            )
-            raise BackpressureError(key, q.name, depth, q.max_queue)
-        if q.max_wait_s is not None:
-            self.batcher.set_wait(key, q.max_wait_s)
-        t_submit = self.clock()
-        # the context is the single per-request allocation this path makes;
-        # every later lifecycle stamp is a plain attribute write on it
-        req = SpMVRequest(
-            key=key,
-            x=x,
-            req_id=self._next_id,
-            t_submit=t_submit,
-            ctx=new_context(key, t_submit),
-        )
-        self._next_id += 1
-        self.batcher.add(req)
-        req.ctx.t_enqueue = self.clock()
-        depth = self.batcher.pending(key)
-        if obs.enabled():
-            obs.gauge("serving.queue_depth", matrix=key).set(depth)
-            # flow start: the submit end of the Perfetto submit→flush arrow
-            obs.flow("request", req.ctx.trace_id, "s", matrix=key)
-        # always-on saturation watch: an int compare until the queue blows
-        # past the limit, then a flight-recorder post-mortem dump
-        self.flight.observe_queue_depth(key, depth, self.queue_limit)
-        return Ticket(self, req)
+            self._next_id += 1
+            self.batcher.add(req)
+            req.ctx.t_enqueue = self.clock()
+            depth = self.batcher.pending(key)
+            if obs.enabled():
+                obs.gauge("serving.queue_depth", matrix=key).set(depth)
+                # flow start: the submit end of the Perfetto submit→flush arrow
+                obs.flow("request", req.ctx.trace_id, "s", matrix=key)
+            # always-on saturation watch: an int compare until the queue blows
+            # past the limit, then a flight-recorder post-mortem dump
+            self.flight.observe_queue_depth(key, depth, self.queue_limit)
+            return Ticket(self, req)
 
     def poll(self, now: Optional[float] = None) -> int:
         """Serve every batch whose policy fired; returns requests completed.
@@ -353,26 +358,30 @@ class ServingEngine:
         Synchronous mode blocks on the device result and completes the
         batch inline (return value = batch size); overlap mode queues the
         launch, parks the batch in flight and returns 0 — completion
-        happens at harvest.
+        happens at harvest.  The ``serve.flush`` span covers the whole
+        dispatch: batch take, stacking, bucket padding and the launch call
+        (and, in synchronous mode, the wait for the result).
         """
-        batch = self.batcher.take(key)
-        if not batch:
-            return 0
-        plan = self.registry.get(key)
-        t_flush = self.clock()
-        for req in batch:
-            if req.ctx is not None:
-                req.ctx.t_flush_start = t_flush
-                req.ctx.flush_reason = reason
-        X = MicroBatcher.stack(batch)  # [n, k]
-        k = X.shape[1]
         sync_compute_s = None
-        with obs.span("serve.flush", matrix=key, reason=reason, k=k):
+        with obs.span("serve.flush", matrix=key, reason=reason) as sp:
+            batch = self.batcher.take(key)
+            if not batch:
+                return 0
+            plan = self.registry.get(key)
+            t_flush = self.clock()
+            for req in batch:
+                if req.ctx is not None:
+                    req.ctx.t_flush_start = t_flush
+                    req.ctx.flush_reason = reason
+            X = MicroBatcher.stack(batch)  # [n, k]
+            k = X.shape[1]
+            sp.annotate(k=k)
             t_dispatch = self.clock()
             t0 = time.perf_counter()
             # JAX async dispatch: this enqueues the SpMM and returns; only
             # materializing the array blocks on the device
             Y = plan.matmat(X, bucketed=True, buckets=self.buckets)
+            t_launched = self.clock()
             if not self.overlap:
                 Y = np.asarray(Y)  # block inside the span, as before
                 sync_compute_s = time.perf_counter() - t0
@@ -387,9 +396,11 @@ class ServingEngine:
         self.metrics.gauge("qos.vwork", matrix=key).set(
             self.scheduler.charge(key, k)
         )
-        infl = _InFlight(key, batch, Y, k, reason, t_dispatch, t0)
+        ahead = len(self._inflight)  # always 0 in synchronous mode
+        infl = _InFlight(key, batch, Y, k, reason, t_dispatch, t_launched, ahead, t0)
         if not self.overlap:
-            return self._complete(infl, compute_s=sync_compute_s)
+            with obs.span("serve.harvest", matrix=key, k=k):
+                return self._complete(infl, compute_s=sync_compute_s)
         self._inflight.append(infl)
         self.metrics.gauge("serving.inflight").set(len(self._inflight))
         return 0
@@ -411,7 +422,8 @@ class ServingEngine:
         for infl in self._inflight:
             in_scope = key is None or infl.key == key
             if in_scope and (block or _device_ready(infl.Y)):
-                served += self._complete(infl)
+                with obs.span("serve.harvest", matrix=infl.key, k=infl.k):
+                    served += self._complete(infl)
             else:
                 keep.append(infl)
         self._inflight = keep
@@ -422,14 +434,15 @@ class ServingEngine:
         """Materialize one batch's results and run the completion accounting.
 
         ``compute_s`` is the measured blocking time in synchronous mode;
-        in overlap mode it is derived here as dispatch-to-harvest wall
-        time (an upper bound on device time — the host may harvest late).
+        in overlap mode it is derived here as wall time from the launch
+        call to harvest: the batch's time in flight, the device queue ahead
+        of it included, plus however late the host harvested — not a device
+        time.  Callers run it inside a ``serve.harvest`` span.
         """
         Y = np.asarray(infl.Y)  # blocks iff not ready yet
         if compute_s is None:
             compute_s = time.perf_counter() - infl.t0_wall
         key, batch, reason, k = infl.key, infl.batch, infl.reason, infl.k
-        plan = self.registry.get(key)
         done = self.clock()
         trace_ids = [r.ctx.trace_id for r in batch if r.ctx is not None]
         # the flush lands in the always-on flight ring *before* any trigger
@@ -450,17 +463,6 @@ class ServingEngine:
         m.counter("serving.columns", matrix=key).inc(k)
         m.counter("serving.padded_columns", matrix=key).inc(launched_k - k)
         m.counter("serving.compute_s", matrix=key).inc(compute_s)
-        # bandwidth attribution: modeled bytes of the launch actually issued
-        # (at the padded bucket width) joined with the measured seconds —
-        # always live, labeled so attribution_rows() can group the join
-        attr_labels = dict(
-            matrix=key, strategy=plan.strategy, k_tiling=plan.k_tiling
-        )
-        m.counter("attr.launches", **attr_labels).inc()
-        m.counter("attr.bytes_modeled", **attr_labels).inc(
-            modeled_launch_bytes(plan.device, launched_k, plan.strategy, plan.k_tiling)
-        )
-        m.counter("attr.compute_s", **attr_labels).inc(compute_s)
         lat = m.histogram("serving.latency_s", window=_LATENCY_WINDOW, matrix=key)
         share = 1.0 / len(batch)
         deadline_s = self.qos_of(key).deadline_s
@@ -476,6 +478,8 @@ class ServingEngine:
             ctx = req.ctx
             if ctx is not None:
                 ctx.t_dispatch = infl.t_dispatch
+                ctx.t_launched = infl.t_launched
+                ctx.ahead = infl.ahead
                 ctx.t_complete = done
                 ctx.compute_s = compute_s
                 ctx.batch_share = share

@@ -72,6 +72,10 @@ class MatrixPlan:
     provenance: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False
     )
+    # host seconds of the admission's phases, read at the boundaries of
+    # their spans: "key" (content hash), "tiles" (build_tiles), "stage"
+    # (device staging), "quality" (partition_quality)
+    phases_s: dict = dataclasses.field(default_factory=dict, repr=False, compare=False)
     # A <-> A^T link, set by MatrixRegistry.admit_pair: the transpose
     # plan's name plus a direct reference (a symmetric matrix links to
     # itself — one residency serves both directions for free)
@@ -270,33 +274,40 @@ class MatrixRegistry:
         Fresh content with a warm on-disk cache → tile build only (the
         measured search is skipped).  ``cfg`` pins the geometry explicitly
         and bypasses autotuning altogether.
-        """
-        key = matrix_hash(csr)
-        if key in self._by_hash:
-            plan = self._plans[self._by_hash[key]]
-            if cfg is not None and cfg != plan.cfg:
-                raise ValueError(
-                    f"matrix {key[:12]} is already resident as {plan.name!r} "
-                    f"with config {plan.cfg}; re-admission pinned {cfg} — "
-                    "evict the plan first to rebuild under a different geometry"
-                )
-            self.metrics.counter("registry.hits", matrix=plan.name).inc()
-            self.metrics.counter("registry.admissions", matrix=plan.name).inc()
-            self._ensure_staged(plan)
-            return plan
-        if name is not None and name in self._plans:
-            raise ValueError(
-                f"name {name!r} is already bound to matrix "
-                f"{self._plans[name].matrix_hash[:12]}"
-            )
 
+        The phases run in spans under ``serve.admit`` (``admit.key``,
+        ``admit.build_tiles``, ``serve.stage_device``,
+        ``admit.plan_quality``); their host seconds land in the plan's
+        ``phases_s``.
+        """
         from repro.kernels import ops
 
-        # admissions get trace ids too (kind "a"): the one-time preprocess
-        # cost is attributable in dumps the same way requests are
-        admit_id = mint_trace_id("a")
-        with obs.span("serve.admit", matrix=name, nnz=csr.nnz, trace_id=admit_id) as sp:
+        with obs.span("serve.admit", matrix=name, nnz=csr.nnz) as sp:
+            t_key = time.perf_counter()
+            with obs.span("admit.key", matrix=name):
+                key = matrix_hash(csr)
             t0 = time.perf_counter()
+            if key in self._by_hash:
+                plan = self._plans[self._by_hash[key]]
+                if cfg is not None and cfg != plan.cfg:
+                    raise ValueError(
+                        f"matrix {key[:12]} is already resident as {plan.name!r} "
+                        f"with config {plan.cfg}; re-admission pinned {cfg} — "
+                        "evict the plan first to rebuild under a different geometry"
+                    )
+                self.metrics.counter("registry.hits", matrix=plan.name).inc()
+                self.metrics.counter("registry.admissions", matrix=plan.name).inc()
+                self._ensure_staged(plan)
+                return plan
+            if name is not None and name in self._plans:
+                raise ValueError(
+                    f"name {name!r} is already bound to matrix "
+                    f"{self._plans[name].matrix_hash[:12]}"
+                )
+            # admissions get trace ids too (kind "a"): the one-time preprocess
+            # cost is attributable in dumps the same way requests are
+            admit_id = mint_trace_id("a")
+            sp.annotate(trace_id=admit_id)
             # the measured search ranks candidates under the served contract;
             # "auto" ranks under the default grid, then picks per matrix below
             served_tiling = self.k_tiling if self.k_tiling != "auto" else "grid"
@@ -327,9 +338,12 @@ class MatrixRegistry:
                 k_tiling_us = measure_k_tilings(csr, cfg, strategy=self.strategy)
                 if k_tiling_us:
                     served_tiling = min(k_tiling_us, key=k_tiling_us.get)
+            t_tiles = time.perf_counter()
             tiles = build_tiles(csr, cfg)
+            t_stage = time.perf_counter()
             with obs.span("serve.stage_device", matrix=name):
                 device = ops.device_tiles(tiles)
+            t_staged = time.perf_counter()
             diag = csr.diagonal()
             row_nnz = csr.row_nnz().astype(np.int64)
             preprocess_s = time.perf_counter() - t0
@@ -338,8 +352,15 @@ class MatrixRegistry:
             # partition-quality introspection runs once per admission,
             # after the preprocess clock stops: it describes the plan, it
             # is not part of the amortizable build cost
+            t_quality = time.perf_counter()
             with obs.span("admit.plan_quality", matrix=name, tiles=tiles.n_tiles):
                 quality = planview.partition_quality(tiles, csr)
+            phases_s = {
+                "key": t0 - t_key,
+                "tiles": t_stage - t_tiles,
+                "stage": t_staged - t_stage,
+                "quality": time.perf_counter() - t_quality,
+            }
         provenance = {
             "searched": tune_searched,
             "cache_hit": tune_hit,
@@ -370,6 +391,7 @@ class MatrixRegistry:
             k_tiling=served_tiling,
             quality=quality,
             provenance=provenance,
+            phases_s=phases_s,
             _metrics=self.metrics,
         )
         self._plans[name] = plan

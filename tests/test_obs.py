@@ -8,8 +8,8 @@ contract — disabled mode retains exactly nothing.
 
 The always-on layers get their own sections: the flight recorder's ring
 wraparound, trigger dumps and concurrency; the SLO engine's burn-rate
-math and multi-window classification; and the bandwidth-attribution join
-rendered by ``analysis/report.py --attribution``.
+math and multi-window classification; and the request log's renderings
+in ``analysis/report.py``.
 """
 import json
 import threading
@@ -18,7 +18,6 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.obs.attribution import attribution_rows, render_attribution
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import Histogram, MetricRegistry
 from repro.obs.report import amortization_ledger, render
@@ -610,71 +609,6 @@ def test_slo_latency_objective_and_worst_status():
     assert worst_status([]) == "ok"
 
 
-# --- bandwidth attribution --------------------------------------------------
-
-
-def _attr_snapshot(bytes_modeled, measured_s):
-    labels = {"matrix": "A", "strategy": "fused", "k_tiling": "grid"}
-    return {
-        "registries": [
-            {
-                "registry": "serving",
-                "metrics": [
-                    {"name": "attr.launches", "labels": labels, "type": "counter",
-                     "value": 4},
-                    {"name": "attr.bytes_modeled", "labels": labels,
-                     "type": "counter", "value": bytes_modeled},
-                    {"name": "attr.compute_s", "labels": labels, "type": "counter",
-                     "value": measured_s},
-                    {"name": "serving.requests", "labels": {"matrix": "A"},
-                     "type": "counter", "value": 9},  # non-attr metrics ignored
-                ],
-            }
-        ]
-    }
-
-
-def test_attribution_rows_join_and_flag():
-    from repro.analysis.roofline import V5E
-
-    # runs at exactly half the modeled roofline: 0.5 fraction, not flagged
-    # at the default 0.5 threshold boundary? strictly-below flags, so equal
-    # fraction stays unflagged
-    snap = _attr_snapshot(bytes_modeled=V5E.hbm_bw, measured_s=2.0)
-    (row,) = attribution_rows(snap)
-    assert row["matrix"] == "A" and row["strategy"] == "fused"
-    assert row["launches"] == 4
-    assert row["achieved_gbps"] == pytest.approx(V5E.hbm_bw / 2 / 1e9)
-    assert row["roofline_fraction"] == pytest.approx(0.5)
-    assert not row["below_roofline"]
-    # 10x slower than modeled: flagged
-    (slow,) = attribution_rows(_attr_snapshot(V5E.hbm_bw, 10.0))
-    assert slow["below_roofline"]
-    text = render_attribution([slow])
-    assert "BELOW-ROOFLINE" in text and "re-evaluate" in text
-    assert "matrix" in text and "achieved_GB/s" in text
-
-
-def test_attribution_handles_empty_and_zero_time():
-    assert attribution_rows({"registries": []}) == []
-    assert "no attribution counters" in render_attribution([])
-    (row,) = attribution_rows(_attr_snapshot(1e9, 0.0))
-    assert row["achieved_gbps"] is None and not row["below_roofline"]
-
-
-def test_attribution_cli_mode(tmp_path, capsys, monkeypatch):
-    from repro.analysis import report as analysis_report
-
-    snap_path = tmp_path / "obs.json"
-    snap_path.write_text(json.dumps(_attr_snapshot(1e9, 10.0)))
-    monkeypatch.setattr(
-        "sys.argv", ["report", "--attribution", str(snap_path)]
-    )
-    analysis_report.main()
-    out = capsys.readouterr().out
-    assert "bandwidth attribution" in out and "BELOW-ROOFLINE" in out
-
-
 # --- serving integration: flight + SLO + gating -----------------------------
 
 
@@ -751,23 +685,7 @@ def test_hot_loop_gating_is_consistent_when_disabled(tmp_path, monkeypatch):
     assert obs.registry().metrics() == []  # nothing leaked into the registry
     # the always-live ledgers still worked
     assert eng.metrics.value("serving.requests", matrix="A") == 4
-    assert eng.metrics.value("attr.launches", matrix="A", strategy=reg.strategy,
-                             k_tiling="grid") > 0
-
-
-def test_engine_attribution_counters_flow_to_report(tmp_path):
-    reg, A, eng, vclock = _serve_matrix(tmp_path, max_wait_s=1e9, max_batch=8)
-    rng = np.random.default_rng(0)
-    for _ in range(4):
-        eng.submit("A", rng.standard_normal(A.shape[1]).astype(np.float32))
-    eng.flush()
-    snap = obs.collect()
-    # rows group by (matrix, strategy, k_tiling) even across registries, so
-    # a not-yet-collected registry from an earlier test can't split the row
-    (row,) = [r for r in attribution_rows(snap) if r["matrix"] == "A"]
-    assert row["launches"] >= 1
-    assert row["bytes_modeled"] > 0 and row["measured_s"] > 0
-    assert "bandwidth attribution" in render(snap)
+    assert eng.metrics.value("serving.batches", matrix="A") > 0
 
 
 # --- request-scoped tracing: contexts, exemplars, flows, waterfall ----------
@@ -985,7 +903,7 @@ def test_report_renders_requests_section_and_na(tmp_path):
 
 
 def test_analysis_report_cli_round_trips_dump(tmp_path, capsys, monkeypatch):
-    """Satellite: --obs / --attribution / --requests must all re-render a
+    """Satellite: --obs / --explain / --requests must all re-render a
     real repro.obs.dump() snapshot file."""
     from repro.analysis import report as analysis_report
 
@@ -1007,9 +925,17 @@ def test_analysis_report_cli_round_trips_dump(tmp_path, capsys, monkeypatch):
     assert "serving.requests{matrix=A}" in out
     assert "slowest 3 requests" in out  # dump carries the request log
 
-    monkeypatch.setattr("sys.argv", ["report", "--attribution", str(snap_path)])
+    monkeypatch.setattr(
+        "sys.argv", ["report", "--explain", "A", "--obs", str(snap_path)]
+    )
     analysis_report.main()
-    assert "bandwidth attribution" in capsys.readouterr().out
+    out = capsys.readouterr().out
+    # the served requests' split at flush and launch, from the request log
+    assert "served requests (queue wait / dispatch / in flight)" in out
+    assert "3 requests in the log" in out
+    for label in ("queue wait", "dispatch", "in flight"):
+        assert f"  {label}" in out and "p95" in out
+    assert "batches in flight ahead of the launch: mean 0.000" in out
 
     monkeypatch.setattr(
         "sys.argv", ["report", "--requests", str(snap_path), "--top", "2"]
