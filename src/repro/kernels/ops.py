@@ -70,16 +70,24 @@ class DeviceTiles(NamedTuple):
     data: jax.Array  # f32[T, group, lane]
     cols: jax.Array  # i32[T, group, lane]
     perm: jax.Array  # i32[padded_rows] = i32[n_rowgroups * group]
+    width: jax.Array  # i32[T]: the row gather's loop bound (kernels.tile_widths)
+
+
+_tile_widths = jax.jit(_k.tile_widths)
 
 
 def device_tiles(tiles: HBPTiles) -> DeviceTiles:
+    """Stage the host tiles; each tile's live width is reduced on the
+    device from the staged values, once per plan."""
+    data = jnp.asarray(tiles.data, jnp.float32)
     return DeviceTiles(
         rowgroup=jnp.asarray(tiles.rowgroup, jnp.int32),
         colblock=jnp.asarray(tiles.colblock, jnp.int32),
         first=jnp.asarray(tiles.first, jnp.int32),
-        data=jnp.asarray(tiles.data, jnp.float32),
+        data=data,
         cols=jnp.asarray(tiles.cols, jnp.int32),
         perm=jnp.asarray(tiles.perm, jnp.int32),
+        width=_tile_widths(data),
     )
 
 
@@ -156,22 +164,34 @@ def modeled_launch_bytes(
     return int(passes * (stream + gathers) + out)
 
 
+def _gather(op: str, strategy: str, k: int, x_rows: int) -> str:
+    """The tile body a launch runs: the fused SpMM's choice
+    (:func:`repro.kernels.hbp_spmv.gather_body`), the lane gather on the
+    other Pallas kernels, ``"none"`` on the jnp strategies."""
+    if strategy not in ("fused", "partials"):
+        return "none"
+    if op == "spmm" and strategy == "fused":
+        return _k.gather_body(k, x_rows)
+    return "lane"
+
+
 def _record_launch(
     dt: DeviceTiles, k: int, *, op: str, strategy: str, k_tiling: str,
-    combine: str = "sum", passes: int | None = None,
+    combine: str = "sum", passes: int | None = None, x_rows: int = 0,
 ) -> None:
     """Gated kernel-traffic accounting: one bump per *Python-level* launch.
 
     Calls traced inside an outer ``jit`` (e.g. the solver ``while_loop``
     body) are counted once per trace, not once per device execution — the
     counters see what Python dispatches, which is the honest observable
-    from this layer.
+    from this layer.  ``x_rows`` (the blocked RHS's rows) feeds the
+    ``gather`` label.
     """
     if not obs.enabled():
         return
     obs.counter(
         "kernels.launches", op=op, strategy=strategy, k_tiling=k_tiling,
-        combine=combine,
+        combine=combine, gather=_gather(op, strategy, k, x_rows),
     ).inc()
     n_passes = stream_passes(k, strategy, k_tiling) if passes is None else passes
     obs.counter("kernels.traversals").inc(n_passes)
@@ -242,7 +262,7 @@ def _spmm_hashed_chunk(
     if combine == "max":
         if strategy == "fused":
             return _k.hbp_spmm_fused_max(
-                dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, x_blocked,
+                dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, x_blocked, dt.width,
                 n_rowgroups=n_rowgroups, interpret=interpret,
             )
         if strategy == "partials":
@@ -262,7 +282,7 @@ def _spmm_hashed_chunk(
         raise ValueError(f"unknown combine {combine!r} (expected 'sum' or 'max')")
     if strategy == "fused":
         return _k.hbp_spmm_fused(
-            dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, x_blocked,
+            dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, x_blocked, dt.width,
             n_rowgroups=n_rowgroups, interpret=interpret,
         )
     if strategy == "partials":
@@ -593,11 +613,11 @@ def hbp_spmm(
     """
     x = jnp.asarray(x, jnp.float32)
     dt, (n_rowgroups, n_rows, col_block) = _resolve(tiles, x, n_rowgroups, n_rows, col_block)
+    x_blocked = blocked_matrix(x, col_block)
     _record_launch(
         dt, x.shape[1], op="spmm", strategy=strategy, k_tiling=k_tiling,
-        combine=combine,
+        combine=combine, x_rows=x_blocked.shape[0] * col_block,
     )
-    x_blocked = blocked_matrix(x, col_block)
     entry, kw = _entry(
         x_blocked, n_rowgroups=n_rowgroups, n_rows=n_rows, strategy=strategy,
         interpret=interpret, combine=combine, k_tiling=k_tiling,

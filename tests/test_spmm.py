@@ -342,3 +342,117 @@ def test_launches_split_into_tile_chunks_match_dense(strategy, combine, k, rng, 
     expect = dense @ X if combine == "sum" else _max_oracle(dense, X)
     jax.clear_caches()
     np.testing.assert_allclose(Y, expect, rtol=1e-4, atol=1e-4)
+
+
+# --- the fused SpMM's row gather -------------------------------------------
+
+
+def _row_gather_problem(rng, lane):
+    """A matrix with every kind of row the row gather meets: scattered rows,
+    rows of one entry (row groups whose tiles are one slot wide), a row
+    longer than a tile (full-width tiles) and empty rows."""
+    m, n = 64, 300
+    dense = np.zeros((m, n), np.float32)
+    dense[:16] = rng.standard_normal((16, n)) * (rng.random((16, n)) < 0.05)
+    dense[16:32, rng.integers(0, n, 16)] = np.diag(rng.standard_normal(16))
+    dense[32, :200] = rng.standard_normal(200)
+    dense[33:40] = rng.standard_normal((7, n)) * (rng.random((7, n)) < 0.2)
+    tiles = build_tiles(
+        csr_from_dense(dense), PartitionConfig(row_block=64, col_block=128, group=8, lane=lane)
+    )
+    return dense, tiles
+
+
+ROW_GATHER_CASES = [
+    (lane, k, combine, False)
+    for lane in (8, 128) for k in (8, 16, 128, 256) for combine in ("sum", "max")
+] + [(8, 16, "sum", True), (8, 256, "max", True), (128, 128, "sum", True), (128, 8, "max", True)]
+
+
+@pytest.mark.parametrize("lane,k,combine,split", ROW_GATHER_CASES)
+def test_row_gather_matches_reference(lane, k, combine, split, rng, monkeypatch):
+    """The row body against ``ref.py``: rows of no entry come back as the
+    monoid identity from the kernel (0, or -inf under max) and 0 once
+    assembled; tiles one slot wide and full width; with ``split``, launches
+    short enough that a row group's run crosses a launch boundary."""
+    import importlib
+
+    from repro.kernels import ops, ref
+
+    kernels = importlib.import_module("repro.kernels.hbp_spmv")
+    dense, tiles = _row_gather_problem(rng, lane)
+    dt = ops.device_tiles(tiles)
+    width = np.asarray(dt.width)
+    assert width.min() == 1 and width.max() == lane
+    fn = kernels.hbp_spmm_fused if combine == "sum" else kernels.hbp_spmm_fused_max
+    if split:
+        # the first launch ends inside a run: its last tile's group goes on
+        monkeypatch.setattr(kernels, "TILE_CHUNK", int(np.flatnonzero(tiles.first == 0)[-1]))
+        fn.clear_cache()  # the wrapper is jitted: retrace under the small chunk
+    X = rng.standard_normal((dense.shape[1], k)).astype(np.float32)
+    xb = ops.blocked_matrix(jnp.asarray(X), tiles.cfg.col_block)
+    assert kernels.gather_body(k, xb.shape[0] * xb.shape[1]) == "row"
+    hashed = fn(dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, xb, dt.width,
+                n_rowgroups=tiles.n_rowgroups, interpret=True)
+    empty = ~dense.any(axis=1)
+    # hashed slots of empty rows and of padding (perm maps slots to rows)
+    empty_slots = ~np.isin(np.asarray(dt.perm), np.flatnonzero(~empty))
+    identity = 0.0 if combine == "sum" else -np.inf
+    assert (np.asarray(hashed).reshape(-1, k)[empty_slots] == identity).all()
+    Y = np.asarray(ref.unpermute(jnp.where(jnp.isneginf(hashed), 0.0, hashed), dt.perm,
+                                 dense.shape[0]))
+    assert (Y[empty] == 0).all()
+    want = np.asarray(hbp_spmm(tiles, X, strategy="reference", combine=combine))
+    if combine == "max":
+        np.testing.assert_array_equal(Y, want)  # products are the same, max is exact
+    else:
+        np.testing.assert_allclose(Y, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("k,x_rows,body", [
+    (1, 65_536, "lane"),
+    (4, 65_536, "lane"),  # below K_ROW: the lane gather is the cheaper body
+    (8, 65_536, "row"),
+    (128, 65_536, "row"),
+    (256, 65_536, "row"),
+    (8, 196_608, "row"),  # X of 96 MiB: exactly the budget
+    (8, 196_616, "lane"),  # one sublane group over it
+    (128, 327_680, "lane"),
+])
+def test_gather_body_choice(k, x_rows, body):
+    import importlib
+
+    kernels = importlib.import_module("repro.kernels.hbp_spmv")
+    assert kernels.gather_body(k, x_rows) == body
+
+
+@pytest.mark.parametrize("op,strategy,k,gather", [
+    ("spmm", "fused", 8, "row"),
+    ("spmm", "fused", 4, "lane"),
+    ("spmv", "fused", 1, "lane"),
+    ("spmm", "partials", 8, "lane"),
+    ("spmm", "stable", 8, "none"),
+])
+def test_launch_counter_labels_gather(op, strategy, k, gather, rng):
+    """``kernels.launches`` names the tile body each launch runs."""
+    from repro import obs
+
+    dense = (rng.standard_normal((40, 50)) * (rng.random((40, 50)) < 0.2)).astype(np.float32)
+    tiles = build_tiles(
+        csr_from_dense(dense), PartitionConfig(row_block=64, col_block=128, lane=16)
+    )
+    X = rng.standard_normal((50, k)).astype(np.float32)
+    obs.reset()
+    obs.enable()
+    try:
+        if op == "spmv":
+            hbp_spmv(tiles, X[:, 0], strategy=strategy, interpret=True)
+        else:
+            hbp_spmm(tiles, X, strategy=strategy, interpret=True)
+        assert obs.registry().value(
+            "kernels.launches", op=op, strategy=strategy, k_tiling="grid", combine="sum",
+            gather=gather,
+        ) == 1
+    finally:
+        obs.disable()
+        obs.reset()

@@ -30,6 +30,8 @@ from repro.kernels.hbp_spmv import (
 
 T = 4096  # tiles per launch
 T_KRON16 = 178_466  # m4_kron16's tile count at lane 8: several SMEM-sized launches
+# the benchmark's kron16 at its pinned geometry (col_block 4096, lane 128)
+KRON16 = dict(tiles=103_372, cols=65_536, rowgroups=8192)
 GROUP = 8
 N_COL_BLOCKS = 16
 N_ROWGROUPS = 2048
@@ -78,10 +80,11 @@ def _shapes(sharding, lane, k, col_block=4096, tiles=T):
     )
 
 
-def _fused(fn):
+def _fused(fn, spmm=False):
     def call(a):
+        width = (a["scalars"],) if spmm else ()  # the fused SpMM's per-tile widths
         return fn.lower(
-            a["scalars"], a["scalars"], a["scalars"], a["data"], a["cols"], a["x"],
+            a["scalars"], a["scalars"], a["scalars"], a["data"], a["cols"], a["x"], *width,
             n_rowgroups=N_ROWGROUPS,
         )
     return call
@@ -96,9 +99,9 @@ def _partials(fn):
 LAUNCHES = {
     "spmv_fused": _fused(hbp_spmv_fused),
     "spmv_partials": _partials(hbp_spmv_partials),
-    "spmm_fused": _fused(hbp_spmm_fused),
+    "spmm_fused": _fused(hbp_spmm_fused, spmm=True),
     "spmm_partials": _partials(hbp_spmm_partials),
-    "spmm_fused_max": _fused(hbp_spmm_fused_max),
+    "spmm_fused_max": _fused(hbp_spmm_fused_max, spmm=True),
     "spmm_partials_max": _partials(hbp_spmm_partials_max),
 }
 
@@ -144,6 +147,7 @@ def _device_tiles(sharding, lane, n_rows):
         rowgroup=a["scalars"], colblock=a["scalars"], first=a["scalars"],
         data=a["data"], cols=a["cols"],
         perm=jax.ShapeDtypeStruct((n_rows,), jnp.int32, sharding=sharding),
+        width=a["scalars"],
     )
 
 
@@ -158,3 +162,27 @@ def test_serving_entry_runs_the_kernel_on_v5e(one_chip, k):
     entry = ops._hbp_spmv_device if k == 1 else ops._hbp_spmm_device
     compiled = entry.lower(dt, x, **meta).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["spmm_fused", "spmm_fused_max"])
+@pytest.mark.parametrize("k,n_cols,body", [
+    (8, KRON16["cols"], "row"),
+    (16, KRON16["cols"], "row"),
+    (128, KRON16["cols"], "row"),
+    (256, KRON16["cols"], "row"),
+    (128, 327_680, "lane"),  # X of 160 MiB: over the budget, lane gather
+])
+def test_fused_spmm_gather_compiles_at_kron16_size(one_chip, kernel, k, n_cols, body):
+    """The fused SpMM at kron16's geometry: 103,372 tiles in four launches,
+    the body the choice rule picks (a resident X under the row gather)."""
+    from repro.kernels.hbp_spmv import gather_body
+
+    assert gather_body(k, n_cols) == body
+    a = _shapes(one_chip, 128, k, tiles=KRON16["tiles"])
+    a["x"] = jax.ShapeDtypeStruct((n_cols // 4096, 4096, k), jnp.float32, sharding=one_chip)
+    fn = hbp_spmm_fused if kernel == "spmm_fused" else hbp_spmm_fused_max
+    compiled = fn.lower(
+        a["scalars"], a["scalars"], a["scalars"], a["data"], a["cols"], a["x"], a["scalars"],
+        n_rowgroups=KRON16["rowgroups"],
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 4
