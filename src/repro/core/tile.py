@@ -38,7 +38,17 @@ from .hash import sample_params
 from .partition import Partition2D, PartitionConfig
 from .reorder import REORDER_METHODS
 
-__all__ = ["HBPTiles", "build_tiles", "tuned_partition_config"]
+__all__ = ["HBPTiles", "build_tiles", "staged_tile_count", "tuned_partition_config"]
+
+
+def staged_tile_count(n_tiles: int) -> int:
+    """``n_tiles`` rounded up to a multiple of ``2 ** (bit_length - 7)``, at
+    most 1/64 more: the tile count of ``build_tiles(bucket_tiles=True)``,
+    which the serving registry builds.  Every launch compiles for its tile
+    count, so matrices of about the same size (the tenants of one service)
+    share their compiled kernels; the extra tiles are empty."""
+    quantum = 1 << max(n_tiles.bit_length() - 7, 0)
+    return -(-n_tiles // quantum) * quantum
 
 
 @dataclasses.dataclass
@@ -126,6 +136,7 @@ def build_tiles(
     cfg: PartitionConfig | None = None,
     *,
     method: str = "hash",
+    bucket_tiles: bool = False,
 ) -> HBPTiles:
     """CSR → TPU tile format.
 
@@ -135,12 +146,16 @@ def build_tiles(
     ``ceil(max_nnz / lane)`` tiles of ``group × lane``, gather column ids
     local to the column block.  Padded slots carry ``col=0, data=0`` so the
     kernel's gather-multiply contributes nothing.
+
+    ``bucket_tiles`` appends empty tiles up to :func:`staged_tile_count`:
+    they continue the last tile's row group and column block, never first
+    of a run, and add nothing (max skips their zero slots).
     """
     cfg = cfg or PartitionConfig()
     with obs.span(
         "admit.build_tiles", method=method, n_rows=csr.shape[0], nnz=csr.nnz
     ) as sp:
-        tiles = _build_tiles_impl(csr, cfg, method)
+        tiles = _build_tiles_impl(csr, cfg, method, bucket_tiles)
         sp.annotate(
             tiles=tiles.n_tiles, nnz_utilization=round(tiles.nnz_utilization(), 4)
         )
@@ -156,7 +171,16 @@ def build_tiles(
     return tiles
 
 
-def _build_tiles_impl(csr: CSRMatrix, cfg: PartitionConfig, method: str) -> HBPTiles:
+def _take_padded(a: np.ndarray, order: np.ndarray, n: int) -> np.ndarray:
+    """``a[order]`` followed by zero rows up to ``n``, in one allocation."""
+    out = np.zeros((n,) + a.shape[1:], a.dtype)
+    np.take(a, order, axis=0, out=out[: order.size], mode="clip")
+    return out
+
+
+def _build_tiles_impl(
+    csr: CSRMatrix, cfg: PartitionConfig, method: str, bucket_tiles: bool
+) -> HBPTiles:
     part = Partition2D.build(csr, cfg)
     nbr, nbc = part.grid
     R, G, LANE = cfg.row_block, cfg.group, cfg.lane
@@ -239,10 +263,16 @@ def _build_tiles_impl(csr: CSRMatrix, cfg: PartitionConfig, method: str) -> HBPT
 
     # Grid order: by (rowgroup, colblock) so output runs are consecutive.
     order = np.lexsort((colblock, rowgroup))
-    data, cols = data[order], cols[order]
+    n = staged_tile_count(order.size) if bucket_tiles else order.size
+    data, cols = _take_padded(data, order, n), _take_padded(cols, order, n)
     rowgroup, colblock = rowgroup[order], colblock[order]
     first = np.ones(rowgroup.size, dtype=np.int32)
     first[1:] = (rowgroup[1:] != rowgroup[:-1]).astype(np.int32)
+    if n > order.size:  # empty tiles carry the last row group's run on
+        pad = n - order.size
+        rowgroup = np.concatenate([rowgroup, np.full(pad, rowgroup[-1])])
+        colblock = np.concatenate([colblock, np.full(pad, colblock[-1])])
+        first = np.concatenate([first, np.zeros(pad, np.int32)])
 
     return HBPTiles(
         data=data,

@@ -76,6 +76,9 @@ class RequestContext:
     batches already in flight then (0 in synchronous mode).
     ``compute_s`` is the flushed batch's measured wall time from launch
     to harvest, attributed to this request via ``batch_share``.
+    ``restage_s`` is the host seconds its batch's launch spent re-staging
+    the plan under an HBM budget (0.0 when the plan was resident), a part
+    of ``dispatch_s``.
     """
 
     __slots__ = (
@@ -93,6 +96,7 @@ class RequestContext:
         "batch_k",
         "flush_reason",
         "deadline_hit",
+        "restage_s",
     )
 
     def __init__(self, key: str, t_submit: float):
@@ -110,6 +114,7 @@ class RequestContext:
         self.batch_k: Optional[int] = None
         self.flush_reason: Optional[str] = None
         self.deadline_hit: Optional[bool] = None
+        self.restage_s: Optional[float] = None
 
     # --- derived decomposition (computed at read time, never stored) -------
 
@@ -174,6 +179,7 @@ class RequestContext:
             "batch_k": self.batch_k,
             "flush_reason": self.flush_reason,
             "deadline_hit": self.deadline_hit,
+            "restage_s": self.restage_s,
             "latency_s": self.latency_s,
         }
 

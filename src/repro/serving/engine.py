@@ -31,6 +31,11 @@ admission-control depth).  Submit sheds with a typed
 saturated, and poll flushes due tenants in weighted-fair order — the
 scheduler reads the SLO burn-rate classifications and head-of-line queue
 waits, so a paging tenant is boosted and a starving queue breaks ties.
+Under the registry's HBM budget, residency is decided once per launch:
+``submit`` only looks the plan up, ``_run_batch`` re-stages an unstaged
+plan (``RequestContext.restage_s``), and every request pins its plan from
+submit to harvest, so a plan with work queued or on the device is never
+unstaged.
 
 Instrumentation is part of the contract: per matrix the engine counts
 requests, batches, k-bucket occupancy and padding, p50/p99 request
@@ -246,6 +251,8 @@ class ServingEngine:
         self._inflight: deque = deque()
         self._next_id = 0
         self._batches = 0
+        # under an HBM budget each queued or in-flight request pins its plan
+        self._pinning = registry.evictor is not None
 
     # --- QoS ---------------------------------------------------------------
 
@@ -269,7 +276,8 @@ class ServingEngine:
         never silently dropped after.
         """
         with obs.span("serve.submit", matrix=key):
-            plan = self.registry.get(key)
+            # validation only: the plan is staged when its batch launches
+            plan = self.registry.peek(key)
             x = np.asarray(x, np.float32)
             if x.shape != (plan.shape[1],):
                 raise ValueError(
@@ -299,6 +307,8 @@ class ServingEngine:
                 ctx=new_context(key, t_submit),
             )
             self._next_id += 1
+            if self._pinning:  # held staged until this request is harvested
+                self.registry.pin(key)
             self.batcher.add(req)
             req.ctx.t_enqueue = self.clock()
             depth = self.batcher.pending(key)
@@ -367,20 +377,28 @@ class ServingEngine:
             batch = self.batcher.take(key)
             if not batch:
                 return 0
-            plan = self.registry.get(key)
             t_flush = self.clock()
-            for req in batch:
-                if req.ctx is not None:
-                    req.ctx.t_flush_start = t_flush
-                    req.ctx.flush_reason = reason
-            X = MicroBatcher.stack(batch)  # [n, k]
-            k = X.shape[1]
-            sp.annotate(k=k)
-            t_dispatch = self.clock()
-            t0 = time.perf_counter()
-            # JAX async dispatch: this enqueues the SpMM and returns; only
-            # materializing the array blocks on the device
-            Y = plan.matmat(X, bucketed=True, buckets=self.buckets)
+            try:
+                # residency is decided here, once per launch: an unstaged
+                # plan is re-staged inside this span (``serve.restage``)
+                plan, restage_s = self.registry.stage(key)
+                for req in batch:
+                    if req.ctx is not None:
+                        req.ctx.t_flush_start = t_flush
+                        req.ctx.flush_reason = reason
+                        req.ctx.restage_s = restage_s
+                X = MicroBatcher.stack(batch)  # [n, k]
+                k = X.shape[1]
+                sp.annotate(k=k)
+                t_dispatch = self.clock()
+                t0 = time.perf_counter()
+                # JAX async dispatch: this enqueues the SpMM and returns; only
+                # materializing the array blocks on the device
+                Y = plan.matmat(X, bucketed=True, buckets=self.buckets)
+            except BaseException:
+                if self._pinning:  # the batch is lost: release its pins
+                    self.registry.unpin(key, len(batch))
+                raise
             t_launched = self.clock()
             if not self.overlap:
                 Y = np.asarray(Y)  # block inside the span, as before
@@ -443,6 +461,8 @@ class ServingEngine:
         if compute_s is None:
             compute_s = time.perf_counter() - infl.t0_wall
         key, batch, reason, k = infl.key, infl.batch, infl.reason, infl.k
+        if self._pinning:  # the device is done with this batch's plan
+            self.registry.unpin(key, len(batch))
         done = self.clock()
         trace_ids = [r.ctx.trace_id for r in batch if r.ctx is not None]
         # the flush lands in the always-on flight ring *before* any trigger

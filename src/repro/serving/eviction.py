@@ -15,6 +15,14 @@ Transpose pairs linked by ``admit_pair`` are evicted as a unit — a
 forward plan without its backward partner would silently re-stage the
 partner on the first training step, defeating the budget accounting.
 
+A plan with work queued or on the device is **pinned** (:meth:`LRUEvictor.pin`,
+counted per request by the serving engine): unstaging it would drop the
+registry's reference while the runtime still holds its buffers for the
+launched batches, so the charged bytes would understate what HBM holds.
+Victims come only from unpinned units; when every other unit is pinned the
+admission goes ahead over the budget and :meth:`LRUEvictor.over_budget`
+reports by how much.
+
 The policy is pure bookkeeping (names and byte counts); the registry owns
 the actual staging/unstaging side effects.
 """
@@ -51,7 +59,8 @@ class LRUEvictor:
     and the evictor reports the overshoot via :meth:`over_budget`).
     ``touch(name)`` refreshes recency on every registry ``get``;
     ``drop(name)`` removes a plan the registry unstaged or fully evicted
-    for its own reasons (pair partners, explicit evicts).
+    for its own reasons (pair partners, explicit evicts); ``pin(name)`` /
+    ``unpin(name)`` count the work that holds a plan's unit resident.
     """
 
     def __init__(self, budget_bytes: int):
@@ -63,6 +72,7 @@ class LRUEvictor:
         # values are the charged device bytes
         self._resident: Dict[str, int] = {}
         self._pair: Dict[str, str] = {}
+        self._pins: Dict[str, int] = {}  # name -> requests queued or in flight
 
     # --- bookkeeping -------------------------------------------------------
 
@@ -78,8 +88,9 @@ class LRUEvictor:
     def over_budget(self) -> int:
         """Bytes past the budget, 0 when under.
 
-        Positive only when a single resident unit exceeds the whole
-        budget (such a unit stays resident rather than thrashing).
+        Positive only when what could not be unstaged exceeds the budget:
+        a single unit larger than the whole budget (it stays resident
+        rather than thrashing), or the admitted unit beside pinned ones.
         """
         return max(0, self.resident_bytes - self.budget_bytes)
 
@@ -100,6 +111,24 @@ class LRUEvictor:
         """Forget ``name`` (registry unstaged or evicted it out of band)."""
         self._resident.pop(name, None)
 
+    def pin(self, name: str, count: int = 1) -> None:
+        """Hold ``name``'s unit resident for ``count`` more pieces of work."""
+        self._pins[name] = self._pins.get(name, 0) + count
+
+    def unpin(self, name: str, count: int = 1) -> None:
+        """Release ``count`` pieces of work pinned by :meth:`pin`."""
+        left = self._pins.get(name, 0) - count
+        if left < 0:
+            raise ValueError(f"{name!r} unpinned {-left} more times than pinned")
+        if left:
+            self._pins[name] = left
+        else:
+            del self._pins[name]
+
+    def pinned(self, name: str) -> bool:
+        """Whether ``name`` or its pair partner has work pinned."""
+        return any(n in self._pins for n in self._unit(name))
+
     def unlink(self, name: str) -> None:
         """Dissolve ``name``'s pair link (full eviction of one side)."""
         partner = self._pair.pop(name, None)
@@ -111,21 +140,22 @@ class LRUEvictor:
     def admit(self, name: str, nbytes: int) -> List[str]:
         """Charge ``name`` at ``nbytes`` and return the victims to unstage.
 
-        The admitted plan (and its pair partner, if resident) is pinned
-        for this decision; victims come least recently used first, each
-        expanded to its full pair unit, until the total fits the budget
-        or nothing evictable remains.
+        The admitted plan (and its pair partner, if resident) is held
+        for this decision, as is every pinned unit; victims come least
+        recently used first, each expanded to its full pair unit, until
+        the total fits the budget or nothing evictable remains.
         """
         self._resident.pop(name, None)
         self._resident[name] = int(nbytes)
-        pinned = set(self._unit(name))
+        held = set(self._unit(name))
         victims: List[str] = []
         while self.resident_bytes > self.budget_bytes:
             candidate = next(
-                (n for n in self._resident if n not in pinned), None
+                (n for n in self._resident if n not in held and not self.pinned(n)),
+                None,
             )
             if candidate is None:
-                break  # only the pinned unit remains: allow the overshoot
+                break  # only held units remain: allow the overshoot
             for n in self._unit(candidate):
                 if n in self._resident:
                     del self._resident[n]
@@ -144,4 +174,5 @@ class LRUEvictor:
             "resident_bytes": self.resident_bytes,
             "resident": list(self._resident),
             "over_budget": self.over_budget(),
+            "pinned": dict(self._pins),
         }

@@ -12,7 +12,9 @@ resident tiles.  :class:`MatrixRegistry` owns that lifecycle:
   :func:`repro.serving.autotune.autotune_partition` (measured search with a
   persistent on-disk cache), unless the caller pins an explicit config;
 * **device residency** — tiles are staged to the device once at admission
-  (:func:`repro.kernels.ops.device_tiles`); requests only launch kernels;
+  (:func:`repro.kernels.ops.device_tiles`); requests only launch kernels.
+  Under an HBM budget a plan unstaged for others is re-staged when a
+  batch of it is launched;
 * **amortization bookkeeping** — the one-time preprocessing cost is
   recorded so :meth:`MatrixRegistry.stats` can report how far traffic has
   amortized it (the paper's Fig. 7 cost, divided by requests served).
@@ -216,12 +218,22 @@ class MatrixRegistry:
     ``hbm_budget_bytes`` caps the **device** footprint of staged tiles:
     when admissions (or re-stages) push past the budget, the least-
     recently-used plans are *unstaged* — device arrays dropped, host
-    tiles and autotuned geometry kept — and the next :meth:`get` against
-    an unstaged plan transparently re-stages it in one ``device_tiles``
-    call (zero re-preprocessing; a full re-admission would hit the
-    ``.hbp_autotune/`` disk cache by content hash anyway).  Transpose
-    pairs are evicted and re-staged as a unit.  ``None`` (default)
-    disables the budget — every admitted plan stays device-resident.
+    tiles and autotuned geometry kept — and the next :meth:`get` (or
+    :meth:`stage`) against an unstaged plan re-stages it in one
+    ``device_tiles`` call (zero re-preprocessing; a full re-admission
+    would hit the ``.hbp_autotune/`` disk cache by content hash anyway).
+    The serving engine stages only when it launches a batch, so residency
+    is decided once per launch; :meth:`peek` looks a plan up without
+    staging.  Transpose pairs are evicted and re-staged as a unit.
+
+    A plan with requests queued or batches in flight is *pinned*
+    (:meth:`pin`, :meth:`unpin`; the engine pins each request at submit and
+    unpins it at harvest): it is never unstaged, since the runtime would
+    keep its buffers alive for the launched work anyway.  When every other
+    unit is pinned a re-stage goes ahead over the budget, and the
+    ``evict.overshoot_bytes`` gauge records by how much.  ``None``
+    (default) disables the budget — every admitted plan stays
+    device-resident, and nothing is pinned.
     """
 
     def __init__(
@@ -339,7 +351,9 @@ class MatrixRegistry:
                 if k_tiling_us:
                     served_tiling = min(k_tiling_us, key=k_tiling_us.get)
             t_tiles = time.perf_counter()
-            tiles = build_tiles(csr, cfg)
+            # bucketed tile counts: tenants of about the same size share
+            # their compiled kernels
+            tiles = build_tiles(csr, cfg, bucket_tiles=True)
             t_stage = time.perf_counter()
             with obs.span("serve.stage_device", matrix=name):
                 device = ops.device_tiles(tiles)
@@ -472,16 +486,38 @@ class MatrixRegistry:
         return plan._transpose
 
     def get(self, name: str) -> MatrixPlan:
-        """The resident plan for ``name`` (raises ``KeyError`` if absent).
+        """The plan for ``name``, staged on the device (raises ``KeyError``
+        if absent).
 
         Under an HBM budget this is also the re-admission path: an
-        unstaged plan is transparently re-staged to the device here (and
-        its recency refreshed), so callers never observe eviction beyond
-        the one-time ``device_tiles`` cost.
+        unstaged plan is re-staged here (and its recency refreshed), at
+        the cost of one ``device_tiles`` call; :meth:`stage` also returns
+        that cost.  The serving engine stages through :meth:`stage` when
+        it launches a batch, never at submit.
         """
+        return self.stage(name)[0]
+
+    def stage(self, name: str):
+        """``(plan, restage_s)``: the plan for ``name`` staged on the device,
+        and the host seconds its unit's re-stage took (0.0 when resident)."""
         plan = self._plans[name]
-        self._ensure_staged(plan)
-        return plan
+        return plan, self._ensure_staged(plan)
+
+    def peek(self, name: str) -> MatrixPlan:
+        """The plan for ``name`` as it is: no staging, recency untouched
+        (raises ``KeyError`` if absent)."""
+        return self._plans[name]
+
+    def pin(self, name: str, count: int = 1) -> None:
+        """Hold ``name``'s unit staged for ``count`` requests (no-op
+        without a budget)."""
+        if self.evictor is not None:
+            self.evictor.pin(name, count)
+
+    def unpin(self, name: str, count: int = 1) -> None:
+        """Release ``count`` requests pinned by :meth:`pin`."""
+        if self.evictor is not None:
+            self.evictor.unpin(name, count)
 
     def __contains__(self, name: str) -> bool:
         return name in self._plans
@@ -522,6 +558,7 @@ class MatrixRegistry:
         for victim in victims:
             self._unstage(victim)
         self.metrics.gauge("evict.resident_bytes").set(self.evictor.resident_bytes)
+        self.metrics.gauge("evict.overshoot_bytes").set(self.evictor.over_budget())
 
     def _unstage(self, name: str) -> None:
         """Drop ``name``'s device arrays (host tiles and geometry stay)."""
@@ -535,16 +572,18 @@ class MatrixRegistry:
         if obs.enabled():
             obs.counter("evict.unstaged", matrix=name).inc()
 
-    def _ensure_staged(self, plan: MatrixPlan) -> None:
-        """Refresh recency; re-stage the plan's unit if budget-evicted."""
+    def _ensure_staged(self, plan: MatrixPlan) -> float:
+        """Refresh recency; re-stage the plan's unit if budget-evicted.
+        Returns the host seconds the re-stage took (0.0 when resident)."""
         if self.evictor is None:
-            return
+            return 0.0
         self.evictor.touch(plan.name)
         # the pair is one unit: restage both sides together so a training
         # step never finds half of its forward/backward residency missing
         unit = [plan]
         if plan._transpose is not None and plan._transpose is not plan:
             unit.append(plan._transpose)
+        total_s = 0.0
         for p in unit:
             if p.device is not None:
                 continue
@@ -554,13 +593,18 @@ class MatrixRegistry:
             with obs.span("serve.restage", matrix=p.name):
                 p.device = ops.device_tiles(p.tiles)
             restage_s = time.perf_counter() - t0
+            total_s += restage_s
             m = self.metrics
             m.counter("evict.restages", matrix=p.name).inc()
             m.counter("evict.restage_s", matrix=p.name).inc(restage_s)
+            m.counter("evict.restage_bytes", matrix=p.name).inc(
+                plan_device_bytes(p.tiles)
+            )
             get_flight().record(
                 "evict.restage", matrix=p.name, restage_s=round(restage_s, 6)
             )
             self._charge(p)
+        return total_s
 
     def stats(self) -> dict:
         """Per-matrix admission/preprocessing snapshot (engine adds traffic).

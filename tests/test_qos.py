@@ -263,7 +263,7 @@ def test_budget_eviction_is_transparent_to_engine(tmp_path, two_matrices):
     reg.admit(B, "b")  # "a" unstaged before any traffic
     vt = [0.0]
     eng = ServingEngine(reg, clock=lambda: vt[0])
-    t = eng.submit("a", _xs(A.shape[1], 1)[0])  # submit's get() re-stages
+    t = eng.submit("a", _xs(A.shape[1], 1)[0])  # re-staged at the launch
     y = t.result()
     assert y.shape == (A.shape[0],)
     assert reg.metrics.value("evict.restages", matrix="a") == 1

@@ -344,6 +344,51 @@ def test_launches_split_into_tile_chunks_match_dense(strategy, combine, k, rng, 
     np.testing.assert_allclose(Y, expect, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("split", [False, True])
+@pytest.mark.parametrize("k,combine", [(1, "sum"), (1, "max"), (16, "sum"), (16, "max")])
+def test_staged_padding_tiles_change_nothing(k, combine, split, monkeypatch):
+    """``build_tiles(bucket_tiles=True)`` pads the tile stream with empty
+    tiles up to ``staged_tile_count``: they continue the last row group and
+    add nothing, on the lane gather (k=1) and the row gather (k=16); with
+    ``split`` the padding is a launch of its own, which carries the last
+    row group on."""
+    import importlib
+
+    import jax
+
+    from repro.core.tile import staged_tile_count
+    from repro.kernels import ops
+
+    kernels = importlib.import_module("repro.kernels.hbp_spmv")
+    rng = np.random.default_rng(0)
+    dense = (rng.standard_normal((48, 256)) * (rng.random((48, 256)) < 0.3)).astype(
+        np.float32
+    )
+    cfg = PartitionConfig(row_block=16, col_block=64, group=8, lane=4)
+    plain = build_tiles(csr_from_dense(dense), cfg)
+    tiles = build_tiles(csr_from_dense(dense), cfg, bucket_tiles=True)
+    T, n = plain.n_tiles, staged_tile_count(plain.n_tiles)
+    assert n > T and tiles.n_tiles == n and tiles.first[T] == 0
+    for field in ("data", "cols", "rowgroup", "colblock", "first"):
+        np.testing.assert_array_equal(getattr(tiles, field)[:T], getattr(plain, field))
+    assert not tiles.data[T:].any() and not tiles.first[T:].any()
+    assert (tiles.rowgroup[T:] == plain.rowgroup[-1]).all()
+    dt = ops.device_tiles(tiles)
+    assert (np.asarray(dt.width)[T:] == 0).all()
+    if split:
+        monkeypatch.setattr(kernels, "TILE_CHUNK", T)
+    jax.clear_caches()  # the wrappers are jitted: retrace under the chunk
+    X = rng.standard_normal((256, k)).astype(np.float32)
+    meta = dict(n_rowgroups=tiles.n_rowgroups, n_rows=48, col_block=64, interpret=True)
+    if combine == "sum" and k == 1:
+        Y = np.asarray(ops.hbp_spmv(dt, X[:, 0], **meta))[:, None]
+    else:
+        Y = np.asarray(ops.hbp_spmm(dt, X, combine=combine, **meta))
+    jax.clear_caches()
+    expect = dense @ X if combine == "sum" else _max_oracle(dense, X)
+    np.testing.assert_allclose(Y, expect, rtol=1e-4, atol=1e-4)
+
+
 # --- the fused SpMM's row gather -------------------------------------------
 
 
