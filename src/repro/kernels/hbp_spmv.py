@@ -4,49 +4,73 @@ Two kernel strategies, both consuming the tile format of
 :mod:`repro.core.tile`:
 
 * **fused combine** (:func:`hbp_spmv_fused`, :func:`hbp_spmm_fused`,
-  :func:`hbp_spmm_fused_max`) — beyond-paper.  The grid walks tiles sorted
-  by (row-group, col-block); consecutive tiles of the same row group
-  accumulate into the same output block, so the "combine part" of Fig. 1
-  disappears into the SpMV pass.  On the GPU the paper tried this fusion
-  and found atomics too expensive (Discussion section); the TPU's
-  sequential grid gives it for free.
+  :func:`hbp_spmm_fused_max`) — beyond-paper.  Tiles come sorted by
+  (row-group, col-block); each tile folds its rows straight into the
+  output, so the "combine part" of Fig. 1 disappears into the SpMV pass.
+  On the GPU the paper tried this fusion and found atomics too expensive
+  (Discussion section); the TPU's sequential grid gives it for free.
 
 * **two-phase partials** (:func:`hbp_spmv_partials`,
   :func:`hbp_spmm_partials`, :func:`hbp_spmm_partials_max`) — faithful to
   the paper's SpMV-part/combine-part split: each tile writes its own
-  partial block; the combine is a separate segment reduction in XLA
-  (``ops.hbp_spmv(..., strategy="partials")``).
+  partial block, one tile a grid step; the combine is a separate segment
+  reduction in XLA (``ops.hbp_spmv(..., strategy="partials")``).
 
-All six entry points share the lane gather body (:func:`_tile_columns`)
-under two launch geometries; SpMV is the ``k = 1`` case of SpMM.  The two
-fused SpMM entry points also have a second body, the row gather
-(:func:`_row_kernel`), chosen per launch by :func:`gather_body`.  Each entry
-point passes its own name to ``pallas_call`` (``name=``, the entry point's
-name), so a profile tells the six kernels apart.
+All six entry points share the lane gather body (:func:`_tile_columns`);
+SpMV is the ``k = 1`` case of SpMM.  The two fused SpMM entry points also
+have a second body, the row gather (:func:`_row_kernel`), chosen per launch
+by :func:`gather_body`.  Each entry point passes its own name to
+``pallas_call`` (``name=``, the entry point's name), so a profile tells
+the six kernels apart.
+
+**Blocked grid (fused).**  A fused launch runs the grid ``(n_k_tiles,
+cdiv(tiles, B))``: each step carries a block of ``B`` consecutive tiles
+and walks them in a ``fori_loop``; the last block may be short, and the
+loop stops at the launch's tile count (nothing is padded in HBM).  Each
+tile reads its row group and column block from scalar prefetch.  ``B`` is
+a constant per body (:func:`tiles_per_step`): :data:`BLOCK_LANE` tiles
+under the lane gather, :data:`BLOCK_ROW` under the row gather, 1 where the
+lane gather's X cannot be resident.  A grid step has a fixed cost of about
+a quarter of a microsecond on a v5e (PERF.md), which a block shares among
+its tiles; ``benchmarks/gather_sweep.py`` times every ``B`` of both
+bodies on ``kron16``'s tiles.
 
 **Layouts.**  Every block obeys the TPU's rule for the last two block
 dimensions (divisible by (8, 128) or equal to the array's):
 
-* tile stream ``data``/``cols``: ``[T, group, lane]``, block
-  ``(1, group, lane)`` — one tile per grid step; in VMEM under the lane
-  gather, in SMEM (slot values and column ids as scalars) under the row
-  gather;
+* tile stream ``data``/``cols``: ``[T, group, lane]``, block ``(B, group,
+  lane)``; in VMEM under the lane gather, in SMEM (slot values and column
+  ids as scalars) under the row gather;
 * x under the lane gather: segments ``[n_col_blocks, k, seg]``
   (:func:`_segments`), RHS columns on sublanes and the segment's columns on
   lanes, ``seg`` = ``col_block`` rounded up to whole 128-lane chunks; block
-  ``(1, k_tile, seg)``, index ``colblock[t]``;
+  ``(n_col_blocks, k_tile, seg)``, index ``(0, j, 0)``: the whole k-tile,
+  resident for the launch, each tile reading segment ``colblock[t]``.
+  When that exceeds :data:`X_BUDGET` the block is ``(1, k_tile, seg)`` at
+  ``colblock[t]`` and a step carries one tile;
 * x under the row gather: ``[n_col_blocks * col_block, k]``, matrix columns
   on sublanes and RHS columns on lanes; block ``(n_col_blocks * col_block,
   k_tile)``, index ``(0, j)``: the whole column range of k-tile ``j``;
-* output: ``[rows, group, k]``, block ``(1, group, k_tile)``.
+* output: ``[rows, group, 128 * n_k_tiles]`` in HBM, aliased across the
+  launches, sliced to ``k`` after the last.  A launch holds a window of
+  it in VMEM (:func:`_blocked`).
 
-Under the lane gather the x block's index depends only on ``colblock[t]``,
-so Pallas skips the copy while consecutive tiles stay in one column block
-(the VMEM analogue of the paper's shared-memory vector-segment reuse).
-Power-law matrices switch column block at almost every step, so the
-segment is re-fetched nearly every step there; the row gather's X changes
-only at k-tile boundaries (the fused grid is k-tile-major) and is copied
-once per launch and k-tile.
+**Output window (the launch-cut rule).**  Tiles of one step span several
+row groups, and Pallas keeps an output block only while its index stays
+put, so the output is not a pipelined block: a launch accumulates into a
+VMEM window of ``window`` row groups of one k-tile (:func:`_window`: as
+many as fit :data:`WINDOW_BYTES`, at most the output's), and copies it in
+and out of HBM itself.  The rows of the output are cut into windows
+``[w * window, (w + 1) * window)``; the sorted tile stream visits them in
+order, so a launch copies in the window of its first tile, moves to the
+next window (copy back, copy in) when a tile's row group leaves the
+current one, and copies the last one back at its last step.  The cut
+follows from shapes alone (``group``, ``n_rowgroups``), and every matrix,
+whatever its row count, runs the same way: ``kron16`` (8,192 row groups)
+in 8 windows, a matrix of 321,821 rows in 40.  A launch that starts
+inside a row group's run continues from what the previous launch copied
+back; launches are cut by :data:`TILE_CHUNK` alone (SMEM), at whole
+blocks (:func:`_chunks`).
 
 **Gather.**  Two bodies, one result:
 
@@ -67,38 +91,37 @@ once per launch and k-tile.
   not grow with k up to one lane tile.
 
 *Choice* (:func:`gather_body`): the row gather runs when the k-tile is at
-least :data:`K_ROW` wide and one k-tile of X fits :data:`ROW_X_BUDGET`
-whole; otherwise the lane gather.  ``K_ROW`` = 8 comes from a chip sweep
-of both bodies over ``K_BUCKETS`` on ``kron16``'s tiles
-(``benchmarks/gather_sweep.py``; PERF.md): the lane gather is faster at
-k ≤ 4, the row gather from k = 8.  SpMV and the partials kernels always
-run the lane gather.
+least :data:`K_ROW` wide and one k-tile of X fits :data:`X_BUDGET` whole;
+otherwise the lane gather.  ``K_ROW`` = 8 comes from a chip sweep of both
+bodies over ``K_BUCKETS`` on ``kron16``'s tiles (``benchmarks/
+gather_sweep.py``; PERF.md).  SpMV and the partials kernels always run the
+lane gather.
 
-**VMEM per grid step** (group 8, lane 128, col_block 4096).  Lane gather:
-data and cols 4 KiB each, the id scratch 4 KiB, the x segment ``k_tile ×
-16 KiB`` (2 MiB at ``k_tile`` = 128), the output block ``32 × k_tile`` B —
-double-buffered, inside the default scoped-VMEM limit.  Row gather: the
+**VMEM and SMEM** (group 8, lane 128, col_block 4096).  Lane gather, per
+launch: the resident X, ``n_col_blocks × 8 × 16 KiB`` for a k-tile of up
+to 8 (2 MiB on 65,536 columns), ``n_col_blocks × 2 MiB`` at ``k_tile`` =
+128, one buffer; the window, 4 KiB a row group (``[8, k_tile]`` takes a
+whole (8, 128) tile), :data:`WINDOW_BYTES` at most; data and cols ``B ×
+4 KiB`` each, double-buffered; the id scratch 4 KiB.  Row gather: the
 resident X, ``n_col_blocks * col_block × 128 lanes × 4 B`` whatever
-``k_tile`` (32 MiB for 65,536 columns), held in one buffer
-(``pl.Buffered(1)``), plus the output windows; the launch raises
-``vmem_limit_bytes`` to the X tile plus :data:`ROW_VMEM_SLACK`.  data and
-cols sit in SMEM, 4 KiB each a step, beside the four scalar-prefetch arrays
-(4 × 128 KiB at :data:`TILE_CHUNK` tiles).  The compile tests
-(``tests/test_tpu_compile.py``) check both against a described v5e.
+``k_tile`` (32 MiB for 65,536 columns), one buffer, and the window; data
+and cols sit in SMEM, ``B × 4 KiB`` each, double-buffered, beside the
+three scalar-prefetch arrays (3 × 128 KiB at :data:`TILE_CHUNK` tiles).
+Each launch asks for X, the window and its VMEM tile blocks plus
+:data:`VMEM_SLACK`.  The compile tests (``tests/test_tpu_compile.py``)
+check both bodies against a described v5e at ``kron16``'s size.
 
 **2D k-tiled grid.**  One grid step carries at most :data:`LANE_TILE` RHS
 columns; wider blocks are padded to a LANE_TILE multiple and run a 2D grid
-in one launch.  The two families tile k differently, because Pallas TPU
-only preserves an output block across *consecutive* grid steps:
+in one launch:
 
 * **partials** — grid ``(T, k // LANE_TILE)``, tile-major.  Every step
   writes its own output block ``(t, j)``; the (data, cols) block maps
   depend only on ``t``, so each tile is fetched ONCE — the stream is read
   once in total.
-* **fused** — grid ``(k // LANE_TILE, T)``, k-tile-major.  The fused
-  combine accumulates into output block ``(rg[t], j)``, which is only
-  well-defined while revisits are consecutive — so ``t`` is innermost and
-  each k-tile re-reads the stream.
+* **fused** — grid ``(k // LANE_TILE, cdiv(T, B))``, k-tile-major: the
+  window and the resident X belong to one k-tile, so each k-tile re-reads
+  the stream.
 
 Kernels are validated against ``ref.py`` in ``interpret=True`` mode on CPU
 and compiled for a described v5e by ``tests/test_tpu_compile.py``.
@@ -130,8 +153,8 @@ __all__ = [
 # grid step carries.  Wider k runs the 2D k-tiled grid.
 LANE_TILE = 128
 _SUBLANES = 8  # rows of one VREG: RHS columns are read 8 at a time
-# Most tiles one launch carries (see _chunks): 3 (lane gather) or 4 (row
-# gather) x 4 B x 32768 = 384 or 512 KiB of scalar prefetch, inside the
+# Most tiles one launch carries (see _chunks): 2 (lane gather) or 3 (row
+# gather) x 4 B x 32768 = 256 or 384 KiB of scalar prefetch, inside the
 # v5e's 1 MiB of SMEM.
 TILE_CHUNK = 32768
 # Narrowest k-tile the fused SpMM runs under the row gather: below it the
@@ -139,11 +162,20 @@ TILE_CHUNK = 32768
 # kron16's tiles: lane 43.8 / 66.2 ms a call at k = 4 / 8, row 56.3 ms at
 # any k; PERF.md).
 K_ROW = 8
-# Most VMEM a resident X k-tile may take under the row gather (the v5e has
-# 128 MiB); wider X keeps the lane gather.  The launch asks for the X tile
-# plus ROW_VMEM_SLACK for its windows and Mosaic's internal scratch.
-ROW_X_BUDGET = 96 * 2**20
-ROW_VMEM_SLACK = 16 * 2**20
+# Tiles one fused grid step carries, per body; benchmarks/gather_sweep.py
+# times every B on the chip (PERF.md).  The row gather's tiles sit in SMEM
+# beside the scalar prefetch, which bounds it: 32 fits a v5e, 64 does not.
+BLOCK_LANE = 128
+BLOCK_ROW = 16
+# Most VMEM a resident X k-tile may take (the v5e has 128 MiB): wider X
+# keeps the lane gather, and under the lane gather streams one segment a
+# step, one tile a step.
+X_BUDGET = 96 * 2**20
+# VMEM of the output window a fused launch accumulates in.
+WINDOW_BYTES = 4 * 2**20
+# VMEM asked for beyond X, the window and the tile blocks: Mosaic's
+# internal scratch and the body's temporaries.
+VMEM_SLACK = 16 * 2**20
 
 
 def _padded_k(k: int) -> int:
@@ -154,6 +186,10 @@ def _padded_k(k: int) -> int:
     if k <= LANE_TILE:
         return -(-k // _SUBLANES) * _SUBLANES
     return -(-k // LANE_TILE) * LANE_TILE
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _segments(x_blocked: jax.Array) -> jax.Array:
@@ -169,8 +205,9 @@ def _segments(x_blocked: jax.Array) -> jax.Array:
     return jnp.swapaxes(x, 1, 2)
 
 
-def _tile_columns(data_ref, cols_ref, x_ref, idx_ref, *, combine: str):
-    """One tile against ``k_tile`` RHS columns: ``[group, k_tile]`` with
+def _tile_columns(data_ref, cols_ref, x_ref, idx_ref, *, combine: str, tile=0, segment=0):
+    """Tile ``tile`` of the data/cols block against segment ``segment`` of
+    the x block, ``k_tile`` RHS columns: ``[group, k_tile]`` with
     ``out[g, j] = reduce_l data[g, l] * x[j, cols[g, l]]``.
 
     ``combine="sum"`` reduces with ``+``; ``"max"`` with ``maximum`` over
@@ -179,7 +216,7 @@ def _tile_columns(data_ref, cols_ref, x_ref, idx_ref, *, combine: str):
     k_tile, seg = x_ref.shape[1], x_ref.shape[2]
     if lane > LANE_TILE:
         raise ValueError(f"lane = {lane} exceeds one lane tile ({LANE_TILE})")
-    cols = cols_ref[0]
+    cols = cols_ref[tile]
     if lane < LANE_TILE:
         # the gather needs 128-lane ids; lanes >= `lane` hold junk that the
         # mask below keeps in range and the [:, :lane] slice drops
@@ -187,14 +224,14 @@ def _tile_columns(data_ref, cols_ref, x_ref, idx_ref, *, combine: str):
         cols = idx_ref[...]
     chunk = lax.shift_right_logical(cols, LANE_TILE.bit_length() - 1)
     offset = cols & (LANE_TILE - 1)
-    data = data_ref[0]
+    data = data_ref[tile]
     col_id = lax.broadcasted_iota(jnp.int32, (group, k_tile), 1)
     rows = min(k_tile, _SUBLANES)
 
     def row_group(base, out):
         gathered = [jnp.zeros(cols.shape, jnp.float32)] * rows
         for c in range(seg // LANE_TILE):
-            block = x_ref[0, pl.ds(base, rows), pl.ds(c * LANE_TILE, LANE_TILE)]
+            block = x_ref[segment, pl.ds(base, rows), pl.ds(c * LANE_TILE, LANE_TILE)]
             hit = chunk == c
             for r in range(rows):
                 src = jnp.broadcast_to(block[r : r + 1], cols.shape)
@@ -217,66 +254,105 @@ def _tile_columns(data_ref, cols_ref, x_ref, idx_ref, *, combine: str):
     )
 
 
-def _accumulate(t, first_ref, y_prev_ref, y_ref, tile_part, *, combine):
-    """y[rowgroup[t]] (+|max)= tile_part(), the tile body's ``[group,
-    k_tile]`` block.  t is the LAST grid dim: the accumulation revisits its
-    output block, and Pallas TPU preserves an output block only across
-    consecutive grid steps."""
+def _blocked(rowgroup_ref, y_ref, acc_ref, cur_ref, tile_part, *, n, block,
+             n_rowgroups, combine):
+    """One grid step of a fused launch: its block of up to ``block`` tiles
+    (``n`` tiles in the launch; the last block may be short), each folded
+    into row ``rowgroup[t]`` of the output window in ``acc_ref``.
 
-    @pl.when(first_ref[t] == 1)
-    def _init():
-        identity = 0.0 if combine == "sum" else -jnp.inf
-        y_ref[...] = jnp.full(y_ref.shape, identity, jnp.float32)
+    The window holds ``window`` row groups of the k-tile in VMEM; window
+    ``w`` covers row groups ``[w * window, (w + 1) * window)``, its copy
+    starting at ``min(w * window, n_rowgroups - window)``.  Tiles come
+    sorted by row group, so the launch walks the windows in order: the
+    first step copies in the window of the launch's first tile, a tile of
+    a later window copies the current one back and the next one in, and
+    the last step copies the current one back.  A launch that starts
+    inside a row group's run therefore continues from what the previous
+    launch wrote back."""
+    j, b = pl.program_id(0), pl.program_id(1)
+    window = acc_ref.shape[0]
+    # the k-tile's lane tile of y; the whole width when it is one k-tile
+    lanes = slice(None) if y_ref.shape[2] == LANE_TILE else pl.ds(j * LANE_TILE, LANE_TILE)
 
-    @pl.when(jnp.logical_and(t == 0, first_ref[0] == 0))
-    def _carry():
-        # this launch starts inside a row group's run that the previous
-        # launch began: continue from what that launch wrote back
-        y_ref[...] = y_prev_ref[...]
+    def hbm(w):
+        start = jnp.minimum(w * window, n_rowgroups - window)
+        return y_ref.at[pl.ds(start, window), :, lanes], start
 
-    part = tile_part()
-    if combine == "sum":
-        y_ref[0] += part
-    else:
-        y_ref[0] = jnp.maximum(y_ref[0], part)
+    @pl.when(b == 0)
+    def _load():
+        cur_ref[0] = rowgroup_ref[0] // window
+        pltpu.sync_copy(hbm(cur_ref[0])[0], acc_ref)
+
+    def tile(i, carry):
+        t = b * block + i
+        rg = rowgroup_ref[t]
+        w = rg // window
+
+        @pl.when(w != cur_ref[0])
+        def _move():
+            pltpu.sync_copy(acc_ref, hbm(cur_ref[0])[0])
+            pltpu.sync_copy(hbm(w)[0], acc_ref)
+            cur_ref[0] = w
+
+        r = rg - hbm(w)[1]
+        part = tile_part(i, t)
+        cols = slice(None, part.shape[1])
+        if combine == "sum":
+            acc_ref[r, :, cols] += part
+        else:
+            acc_ref[r, :, cols] = jnp.maximum(acc_ref[r, :, cols], part)
+        return carry
+
+    lax.fori_loop(0, jnp.minimum(block, n - b * block), tile, 0)
+
+    @pl.when(b == pl.num_programs(1) - 1)
+    def _store():
+        pltpu.sync_copy(acc_ref, hbm(cur_ref[0])[0])
 
 
-def _fused_kernel(rowgroup_ref, colblock_ref, first_ref, data_ref, cols_ref, x_ref,
-                  y_prev_ref, y_ref, idx_ref, *, combine):
-    """The lane gather body: one tile against its x segment."""
-    _accumulate(pl.program_id(1), first_ref, y_prev_ref, y_ref,
-                lambda: _tile_columns(data_ref, cols_ref, x_ref, idx_ref, combine=combine),
-                combine=combine)
+def _lane_kernel(rowgroup_ref, colblock_ref, data_ref, cols_ref, x_ref, y_in_ref, y_ref,
+                 acc_ref, cur_ref, idx_ref, *, resident, **kw):
+    """The lane gather body: each tile against its x segment, read from the
+    resident X at ``colblock[t]`` (or, streamed, the step's one segment)."""
+    del y_in_ref  # aliased to y_ref
+
+    def tile_part(i, t):
+        segment = colblock_ref[t] if resident else 0
+        return _tile_columns(data_ref, cols_ref, x_ref, idx_ref, combine=kw["combine"],
+                             tile=i, segment=segment)
+
+    _blocked(rowgroup_ref, y_ref, acc_ref, cur_ref, tile_part, **kw)
 
 
-def _row_kernel(rowgroup_ref, colblock_ref, first_ref, width_ref, data_ref, cols_ref,
-                x_ref, y_prev_ref, y_ref, *, combine, col_block):
-    """The row gather body: for each live slot ``s < width[t]`` of the
-    tile, row ``g`` reads one X row (all ``k_tile`` RHS columns on its
-    lanes) from the VMEM-resident X and folds ``data[g, s] * row`` into
-    its own accumulator.  Padded slots of shorter rows hold data 0 and
-    column 0: they add 0, and under max the ``data != 0`` rule drops them."""
-    t = pl.program_id(1)
+def _row_kernel(rowgroup_ref, colblock_ref, width_ref, data_ref, cols_ref, x_ref, y_in_ref,
+                y_ref, acc_ref, cur_ref, *, col_block, **kw):
+    """The row gather body: for each live slot ``s < width[t]`` of a tile,
+    row ``g`` reads one X row (all ``k_tile`` RHS columns on its lanes)
+    from the VMEM-resident X and folds ``data[g, s] * row`` into its own
+    accumulator.  Padded slots of shorter rows hold data 0 and column 0:
+    they add 0, and under max the ``data != 0`` rule drops them."""
+    del y_in_ref  # aliased to y_ref
     group, kt = data_ref.shape[1], x_ref.shape[1]
-    base = colblock_ref[t] * col_block
-    identity = 0.0 if combine == "sum" else -jnp.inf
+    identity = 0.0 if kw["combine"] == "sum" else -jnp.inf
 
-    def slot(s, acc):
-        out = []
-        for g in range(group):  # unrolled: independent accumulator chains
-            a = data_ref[0, g, s]
-            prod = a * x_ref[pl.ds(base + cols_ref[0, g, s], 1), :]
-            if combine == "sum":
-                out.append(acc[g] + prod)
-            else:
-                out.append(jnp.maximum(acc[g], jnp.where(a != 0, prod, -jnp.inf)))
-        return tuple(out)
+    def tile_part(i, t):
+        base = colblock_ref[t] * col_block
 
-    def tile_part():
+        def slot(s, acc):
+            out = []
+            for g in range(group):  # unrolled: independent accumulator chains
+                a = data_ref[i, g, s]
+                prod = a * x_ref[pl.ds(base + cols_ref[i, g, s], 1), :]
+                if kw["combine"] == "sum":
+                    out.append(acc[g] + prod)
+                else:
+                    out.append(jnp.maximum(acc[g], jnp.where(a != 0, prod, -jnp.inf)))
+            return tuple(out)
+
         init = (jnp.full((1, kt), identity, jnp.float32),) * group
         return jnp.concatenate(lax.fori_loop(0, width_ref[t], slot, init), axis=0)
 
-    _accumulate(t, first_ref, y_prev_ref, y_ref, tile_part, combine=combine)
+    _blocked(rowgroup_ref, y_ref, acc_ref, cur_ref, tile_part, **kw)
 
 
 def _partials_kernel(colblock_ref, data_ref, cols_ref, x_ref, y_prev_ref, y_ref,
@@ -292,13 +368,15 @@ def _k_grid(k: int):
     return LANE_TILE, k // LANE_TILE
 
 
-def _chunks(T: int):
+def _chunks(T: int, block: int = 1):
     """(first tile, tiles) of each launch: the per-tile scalars a launch
     prefetches live in SMEM (1 MiB on v5e), so a launch carries at most
-    :data:`TILE_CHUNK` tiles.  Launches share one output buffer
-    (``input_output_aliases``): blocks a launch never visits keep what the
+    :data:`TILE_CHUNK` tiles, a whole number of ``block``-tile grid steps
+    (one block at least).  Launches share one output buffer
+    (``input_output_aliases``): rows a launch never writes keep what the
     earlier ones wrote."""
-    return [(lo, min(TILE_CHUNK, T - lo)) for lo in range(0, T, TILE_CHUNK)]
+    chunk = max(block, TILE_CHUNK - TILE_CHUNK % block)
+    return [(lo, min(chunk, T - lo)) for lo in range(0, T, chunk)]
 
 
 def tile_widths(data: jax.Array) -> jax.Array:
@@ -309,106 +387,147 @@ def tile_widths(data: jax.Array) -> jax.Array:
     return jnp.max(jnp.where(data != 0, pos, 0), axis=(1, 2))
 
 
-def _resident_x_bytes(x_rows: int) -> int:
-    """VMEM of one k-tile of X held whole: ``[x_rows, k_tile]`` f32, rows
-    padded to whole sublane groups and k_tile to a full lane tile."""
-    return -(-x_rows // _SUBLANES) * _SUBLANES * LANE_TILE * 4
+def _row_x_bytes(x_rows: int) -> int:
+    """VMEM of one k-tile of X held whole under the row gather: ``[x_rows,
+    k_tile]`` f32, rows padded to whole sublane groups and k_tile to a full
+    lane tile."""
+    return _round_up(x_rows, _SUBLANES) * LANE_TILE * 4
+
+
+def _lane_x_bytes(n_cb: int, kt: int, seg: int) -> int:
+    """VMEM of one k-tile of the segments ``[n_cb, kt, seg]`` held whole."""
+    return n_cb * _round_up(kt, _SUBLANES) * seg * 4
+
+
+def _window(group: int, n_rowgroups: int) -> int:
+    """Row groups of the output window: as many ``[group, k_tile]`` blocks
+    (a whole ``(8, 128)`` VMEM tile at least) as fit :data:`WINDOW_BYTES`,
+    at most the output's."""
+    per = _round_up(group, _SUBLANES) * LANE_TILE * 4
+    return max(1, min(n_rowgroups, WINDOW_BYTES // per))
 
 
 def gather_body(k: int, x_rows: int) -> str:
     """Which tile body a fused SpMM launch over ``k`` RHS columns and an X
     of ``x_rows`` (padded) rows runs: ``"row"`` when the k-tile is at
-    least :data:`K_ROW` wide and X fits :data:`ROW_X_BUDGET` whole,
+    least :data:`K_ROW` wide and X fits :data:`X_BUDGET` whole,
     ``"lane"`` otherwise."""
     kt, _ = _k_grid(_padded_k(k))
-    if kt >= K_ROW and _resident_x_bytes(x_rows) <= ROW_X_BUDGET:
+    if kt >= K_ROW and _row_x_bytes(x_rows) <= X_BUDGET:
         return "row"
     return "lane"
 
 
-def _fused_spmm(rowgroup, colblock, first, data, cols, x_blocked, width, **kw):
+def tiles_per_step(k: int, x_rows: int, col_block: int) -> int:
+    """Tiles one grid step of a fused launch carries: :data:`BLOCK_ROW`
+    under the row gather, :data:`BLOCK_LANE` under the lane gather with X
+    resident, 1 when the lane gather's X exceeds :data:`X_BUDGET` and
+    streams one segment a step."""
+    if gather_body(k, x_rows) == "row":
+        return BLOCK_ROW
+    kt, _ = _k_grid(_padded_k(k))
+    seg = _round_up(col_block, LANE_TILE)
+    return BLOCK_LANE if _lane_x_bytes(x_rows // col_block, kt, seg) <= X_BUDGET else 1
+
+
+def _fused_spmm(rowgroup, colblock, data, cols, x_blocked, width, **kw):
     """The fused SpMM launches under the body :func:`gather_body` picks."""
     n_cb, col_block, k = x_blocked.shape
     if gather_body(k, n_cb * col_block) == "lane":
-        return _fused(rowgroup, colblock, first, data, cols, x_blocked, **kw)
-    return _fused_rows(rowgroup, colblock, first, width, data, cols, x_blocked, **kw)
+        return _fused(rowgroup, colblock, data, cols, x_blocked, **kw)
+    return _fused_rows(rowgroup, colblock, width, data, cols, x_blocked, **kw)
 
 
-def _fused(rowgroup, colblock, first, data, cols, x_blocked, *, n_rowgroups,
-           combine, interpret, name):
+def _launches(kernel, scalars, data, cols, x, x_spec, *, x_vmem, tile_space, block, kp,
+              n_rowgroups, combine, scratch, interpret, name, **kernel_kw):
+    """The fused launches over the tile stream: grid ``(n_k_tiles,
+    cdiv(tiles, block))`` each, the data/cols blocks ``(block, group,
+    lane)`` in ``tile_space``, the output in HBM (aliased across launches)
+    and its window in VMEM.  Each asks for ``x_vmem`` (X's buffers), the
+    window and the VMEM tile blocks, plus :data:`VMEM_SLACK`."""
     T, group, lane = data.shape
-    xs = _segments(x_blocked)
-    _, k, seg = xs.shape
-    kt, n_kt = _k_grid(k)
+    _, n_kt = _k_grid(kp)
+    window = _window(group, n_rowgroups)
+    tile_bytes = _round_up(group, _SUBLANES) * LANE_TILE * 4  # one (8, 128) tile or more
+    vmem = x_vmem + window * tile_bytes + VMEM_SLACK
+    if tile_space == pltpu.VMEM:
+        vmem += 2 * 2 * block * tile_bytes  # data and cols, double-buffered
     identity = 0.0 if combine == "sum" else -jnp.inf
-    # row groups no tile visits keep the monoid identity
-    y = jnp.full((n_rowgroups, group, k), identity, jnp.float32)
-    for lo, n in _chunks(T):
+    # row groups no tile visits keep the monoid identity; each k-tile takes
+    # a whole lane tile of y (Mosaic slices HBM refs by whole lane tiles)
+    y = jnp.full((n_rowgroups, group, n_kt * LANE_TILE), identity, jnp.float32)
+    tiles = lambda j, b, *s, base: (base + b, 0, 0)  # noqa: E731
+    for lo, n in _chunks(T, block):
+        tile_spec = pl.BlockSpec((block, group, lane),
+                                 functools.partial(tiles, base=lo // block),
+                                 memory_space=tile_space)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(n_kt, n),
-            in_specs=[
-                pl.BlockSpec((1, group, lane), lambda j, t, rg, cb, fs, lo=lo: (t + lo, 0, 0)),
-                pl.BlockSpec((1, group, lane), lambda j, t, rg, cb, fs, lo=lo: (t + lo, 0, 0)),
-                pl.BlockSpec((1, kt, seg), lambda j, t, rg, cb, fs: (cb[t], j, 0)),
-                # the block of the launch's first row group, fetched once
-                pl.BlockSpec((1, group, kt), lambda j, t, rg, cb, fs: (rg[0], 0, j)),
-            ],
-            out_specs=pl.BlockSpec((1, group, kt), lambda j, t, rg, cb, fs: (rg[t], 0, j)),
-            scratch_shapes=[pltpu.VMEM((group, LANE_TILE), jnp.int32)],
+            num_scalar_prefetch=len(scalars),
+            grid=(n_kt, pl.cdiv(n, block)),
+            in_specs=[tile_spec, tile_spec, x_spec, pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
+            scratch_shapes=[pltpu.VMEM((window, group, LANE_TILE), jnp.float32),
+                            pltpu.SMEM((1,), jnp.int32), *scratch],
         )
         y = pl.pallas_call(
-            functools.partial(_fused_kernel, combine=combine),
+            functools.partial(kernel, n=n, block=block, n_rowgroups=n_rowgroups,
+                              combine=combine, **kernel_kw),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(y.shape, jnp.float32),
-            input_output_aliases={6: 0},
-            interpret=interpret,
-            name=name,
-        )(rowgroup[lo : lo + n], colblock[lo : lo + n], first[lo : lo + n],
-          data, cols, xs, y)
-    return y
-
-
-def _fused_rows(rowgroup, colblock, first, width, data, cols, x_blocked, *,
-                n_rowgroups, combine, interpret, name):
-    """The fused launches under the row gather: X is ``[n_cb * col_block,
-    k]`` (matrix columns on sublanes, RHS columns on lanes), one k-tile of
-    it resident in VMEM for the launch; tiles sit in SMEM."""
-    T, group, lane = data.shape
-    n_cb, col_block, k = x_blocked.shape
-    kp = _padded_k(k)
-    x = jnp.pad(x_blocked.reshape(n_cb * col_block, k), ((0, 0), (0, kp - k)))
-    kt, n_kt = _k_grid(kp)
-    identity = 0.0 if combine == "sum" else -jnp.inf
-    y = jnp.full((n_rowgroups, group, kp), identity, jnp.float32)
-    vmem = _resident_x_bytes(x.shape[0]) + ROW_VMEM_SLACK
-    for lo, n in _chunks(T):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(n_kt, n),
-            in_specs=[
-                pl.BlockSpec((1, group, lane), lambda j, t, rg, cb, fs, w, lo=lo: (t + lo, 0, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, group, lane), lambda j, t, rg, cb, fs, w, lo=lo: (t + lo, 0, 0),
-                             memory_space=pltpu.SMEM),
-                # the whole k-tile of X, copied once per k-tile, one buffer
-                pl.BlockSpec((x.shape[0], kt), lambda j, t, rg, cb, fs, w: (0, j),
-                             pipeline_mode=pl.Buffered(1)),
-                pl.BlockSpec((1, group, kt), lambda j, t, rg, cb, fs, w: (rg[0], 0, j)),
-            ],
-            out_specs=pl.BlockSpec((1, group, kt), lambda j, t, rg, cb, fs, w: (rg[t], 0, j)),
-        )
-        y = pl.pallas_call(
-            functools.partial(_row_kernel, combine=combine, col_block=col_block),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(y.shape, jnp.float32),
-            input_output_aliases={7: 0},
+            input_output_aliases={len(scalars) + 3: 0},
             compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
             interpret=interpret,
             name=name,
-        )(rowgroup[lo : lo + n], colblock[lo : lo + n], first[lo : lo + n],
-          width[lo : lo + n], data, cols, x, y)
-    return y
+        )(*(s[lo : lo + n] for s in scalars), data, cols, x, y)
+    return y[..., :kp]
+
+
+def _fused(rowgroup, colblock, data, cols, x_blocked, *, n_rowgroups, combine,
+           interpret, name, block=None):
+    """The fused launches under the lane gather.  X, ``[n_cb, k, seg]``
+    segments, is resident in VMEM for the launch, one k-tile of it, when it
+    fits :data:`X_BUDGET`; otherwise a step carries one tile and streams
+    its segment.  ``block`` overrides :data:`BLOCK_LANE` (the sweep)."""
+    xs = _segments(x_blocked)
+    n_cb, k, seg = xs.shape
+    kt, _ = _k_grid(k)
+    x_vmem = _lane_x_bytes(n_cb, kt, seg)
+    resident = x_vmem <= X_BUDGET
+    if resident:
+        block = block or BLOCK_LANE
+        # the whole k-tile of X, copied once per k-tile, one buffer
+        x_spec = pl.BlockSpec((n_cb, kt, seg), lambda j, b, rg, cb: (0, j, 0),
+                              pipeline_mode=pl.Buffered(1))
+    else:
+        block, x_vmem = 1, 2 * x_vmem // n_cb  # one segment, double-buffered
+        x_spec = pl.BlockSpec((1, kt, seg), lambda j, b, rg, cb: (cb[b], j, 0))
+    return _launches(
+        _lane_kernel, (rowgroup, colblock), data, cols, xs, x_spec, x_vmem=x_vmem,
+        tile_space=pltpu.VMEM, block=block, kp=k, n_rowgroups=n_rowgroups, combine=combine,
+        scratch=[pltpu.VMEM((data.shape[1], LANE_TILE), jnp.int32)], interpret=interpret,
+        name=name, resident=resident,
+    )
+
+
+def _fused_rows(rowgroup, colblock, width, data, cols, x_blocked, *, n_rowgroups,
+                combine, interpret, name, block=None):
+    """The fused launches under the row gather: X is ``[n_cb * col_block,
+    k]`` (matrix columns on sublanes, RHS columns on lanes), one k-tile of
+    it resident in VMEM for the launch; tiles sit in SMEM.  ``block``
+    overrides :data:`BLOCK_ROW` (the sweep)."""
+    n_cb, col_block, k = x_blocked.shape
+    kp = _padded_k(k)
+    x = jnp.pad(x_blocked.reshape(n_cb * col_block, k), ((0, 0), (0, kp - k)))
+    kt, _ = _k_grid(kp)
+    # the whole k-tile of X, copied once per k-tile, one buffer
+    x_spec = pl.BlockSpec((x.shape[0], kt), lambda j, b, rg, cb, w: (0, j),
+                          pipeline_mode=pl.Buffered(1))
+    return _launches(
+        _row_kernel, (rowgroup, colblock, width), data, cols, x, x_spec,
+        x_vmem=_row_x_bytes(x.shape[0]), tile_space=pltpu.SMEM, block=block or BLOCK_ROW,
+        kp=kp, n_rowgroups=n_rowgroups, combine=combine, scratch=[], interpret=interpret,
+        name=name, col_block=col_block,
+    )
 
 
 def _partials(colblock, data, cols, x_blocked, *, combine, interpret, name):
@@ -442,11 +561,11 @@ def _partials(colblock, data, cols, x_blocked, *, combine, interpret, name):
 
 
 @functools.partial(jax.jit, static_argnames=("n_rowgroups", "interpret"))
-def hbp_spmv_fused(rowgroup, colblock, first, data, cols, x_blocked, *,
+def hbp_spmv_fused(rowgroup, colblock, data, cols, x_blocked, *,
                    n_rowgroups: int, interpret: bool = False) -> jax.Array:
     """Fused-combine HBP SpMV over ``x_blocked: f32[n_col_blocks, col_block]``.
     Returns y in hashed row order, shape [n_rowgroups, group]."""
-    return _fused(rowgroup, colblock, first, data, cols, x_blocked,
+    return _fused(rowgroup, colblock, data, cols, x_blocked,
                   n_rowgroups=n_rowgroups, combine="sum", interpret=interpret,
                   name="hbp_spmv_fused")[..., 0]
 
@@ -461,7 +580,7 @@ def hbp_spmv_partials(colblock, data, cols, x_blocked, *,
 
 
 @functools.partial(jax.jit, static_argnames=("n_rowgroups", "interpret"))
-def hbp_spmm_fused(rowgroup, colblock, first, data, cols, x_blocked, width, *,
+def hbp_spmm_fused(rowgroup, colblock, data, cols, x_blocked, width, *,
                    n_rowgroups: int, interpret: bool = False) -> jax.Array:
     """Fused-combine HBP SpMM: ``Y = A @ X`` over
     ``x_blocked: f32[n_col_blocks, col_block, k]``.
@@ -472,7 +591,7 @@ def hbp_spmm_fused(rowgroup, colblock, first, data, cols, x_blocked, width, *,
     the plan (``DeviceTiles.width``).  Returns y in hashed row order,
     [n_rowgroups, group, k]."""
     k = x_blocked.shape[-1]
-    return _fused_spmm(rowgroup, colblock, first, data, cols, x_blocked, width,
+    return _fused_spmm(rowgroup, colblock, data, cols, x_blocked, width,
                        n_rowgroups=n_rowgroups, combine="sum", interpret=interpret,
                        name="hbp_spmm_fused")[..., :k]
 
@@ -488,14 +607,14 @@ def hbp_spmm_partials(colblock, data, cols, x_blocked, *,
 
 
 @functools.partial(jax.jit, static_argnames=("n_rowgroups", "interpret"))
-def hbp_spmm_fused_max(rowgroup, colblock, first, data, cols, x_blocked, width, *,
+def hbp_spmm_fused_max(rowgroup, colblock, data, cols, x_blocked, width, *,
                        n_rowgroups: int, interpret: bool = False) -> jax.Array:
     """Fused-combine HBP SpMM under the max monoid (GNN max-aggregation):
     ``y[i, c] = max_j a_ij * x_jc`` over stored entries.  Padded slots are
     masked to ``-inf``, the max identity, so rows with no live entry come
     back ``-inf`` for the caller to zero (``ops._hbp_spmm_device``)."""
     k = x_blocked.shape[-1]
-    return _fused_spmm(rowgroup, colblock, first, data, cols, x_blocked, width,
+    return _fused_spmm(rowgroup, colblock, data, cols, x_blocked, width,
                        n_rowgroups=n_rowgroups, combine="max", interpret=interpret,
                        name="hbp_spmm_fused_max")[..., :k]
 

@@ -66,7 +66,6 @@ class DeviceTiles(NamedTuple):
 
     rowgroup: jax.Array  # i32[T]
     colblock: jax.Array  # i32[T]
-    first: jax.Array  # i32[T]
     data: jax.Array  # f32[T, group, lane]
     cols: jax.Array  # i32[T, group, lane]
     perm: jax.Array  # i32[padded_rows] = i32[n_rowgroups * group]
@@ -83,7 +82,6 @@ def device_tiles(tiles: HBPTiles) -> DeviceTiles:
     return DeviceTiles(
         rowgroup=jnp.asarray(tiles.rowgroup, jnp.int32),
         colblock=jnp.asarray(tiles.colblock, jnp.int32),
-        first=jnp.asarray(tiles.first, jnp.int32),
         data=data,
         cols=jnp.asarray(tiles.cols, jnp.int32),
         perm=jnp.asarray(tiles.perm, jnp.int32),
@@ -175,23 +173,34 @@ def _gather(op: str, strategy: str, k: int, x_rows: int) -> str:
     return "lane"
 
 
+def _block(op: str, strategy: str, k: int, x_rows: int, col_block: int) -> int:
+    """Tiles one grid step of the launch carries: the fused kernels'
+    (:func:`repro.kernels.hbp_spmv.tiles_per_step`), 1 on the partials
+    kernels, 0 on the jnp strategies."""
+    if strategy == "fused":
+        return _k.tiles_per_step(k if op == "spmm" else 1, x_rows, col_block)
+    return 1 if strategy == "partials" else 0
+
+
 def _record_launch(
     dt: DeviceTiles, k: int, *, op: str, strategy: str, k_tiling: str,
     combine: str = "sum", passes: int | None = None, x_rows: int = 0,
+    col_block: int = 1,
 ) -> None:
     """Gated kernel-traffic accounting: one bump per *Python-level* launch.
 
     Calls traced inside an outer ``jit`` (e.g. the solver ``while_loop``
     body) are counted once per trace, not once per device execution — the
     counters see what Python dispatches, which is the honest observable
-    from this layer.  ``x_rows`` (the blocked RHS's rows) feeds the
-    ``gather`` label.
+    from this layer.  ``x_rows`` (the blocked RHS's rows) and
+    ``col_block`` feed the ``gather`` and ``block`` labels.
     """
     if not obs.enabled():
         return
     obs.counter(
         "kernels.launches", op=op, strategy=strategy, k_tiling=k_tiling,
         combine=combine, gather=_gather(op, strategy, k, x_rows),
+        block=_block(op, strategy, k, x_rows, col_block),
     ).inc()
     n_passes = stream_passes(k, strategy, k_tiling) if passes is None else passes
     obs.counter("kernels.traversals").inc(n_passes)
@@ -218,7 +227,7 @@ def _hbp_spmv_device(
         return jnp.zeros((n_rows,), jnp.float32)
     if strategy == "fused":
         y_hashed = _k.hbp_spmv_fused(
-            dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, x_blocked,
+            dt.rowgroup, dt.colblock, dt.data, dt.cols, x_blocked,
             n_rowgroups=n_rowgroups, interpret=interpret,
         )
     elif strategy == "partials":
@@ -262,7 +271,7 @@ def _spmm_hashed_chunk(
     if combine == "max":
         if strategy == "fused":
             return _k.hbp_spmm_fused_max(
-                dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, x_blocked, dt.width,
+                dt.rowgroup, dt.colblock, dt.data, dt.cols, x_blocked, dt.width,
                 n_rowgroups=n_rowgroups, interpret=interpret,
             )
         if strategy == "partials":
@@ -282,7 +291,7 @@ def _spmm_hashed_chunk(
         raise ValueError(f"unknown combine {combine!r} (expected 'sum' or 'max')")
     if strategy == "fused":
         return _k.hbp_spmm_fused(
-            dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, x_blocked, dt.width,
+            dt.rowgroup, dt.colblock, dt.data, dt.cols, x_blocked, dt.width,
             n_rowgroups=n_rowgroups, interpret=interpret,
         )
     if strategy == "partials":
@@ -423,8 +432,9 @@ def hbp_spmv(
         raise ValueError(f"unknown k_tiling {k_tiling!r} (expected one of {K_TILINGS})")
     x = jnp.asarray(x, jnp.float32)
     dt, (n_rowgroups, n_rows, col_block) = _resolve(tiles, x, n_rowgroups, n_rows, col_block)
-    _record_launch(dt, 1, op="spmv", strategy=strategy, k_tiling=k_tiling)
     x_blocked = blocked_vector(x, col_block)
+    _record_launch(dt, 1, op="spmv", strategy=strategy, k_tiling=k_tiling,
+                   x_rows=x_blocked.size, col_block=col_block)
     entry, kw = _entry(
         x_blocked, n_rowgroups=n_rowgroups, n_rows=n_rows, strategy=strategy,
         interpret=interpret,
@@ -616,7 +626,7 @@ def hbp_spmm(
     x_blocked = blocked_matrix(x, col_block)
     _record_launch(
         dt, x.shape[1], op="spmm", strategy=strategy, k_tiling=k_tiling,
-        combine=combine, x_rows=x_blocked.shape[0] * col_block,
+        combine=combine, x_rows=x_blocked.shape[0] * col_block, col_block=col_block,
     )
     entry, kw = _entry(
         x_blocked, n_rowgroups=n_rowgroups, n_rows=n_rows, strategy=strategy,

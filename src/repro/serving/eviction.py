@@ -38,13 +38,13 @@ def plan_device_bytes(tiles) -> int:
 
     Computed from the host :class:`~repro.core.tile.HBPTiles` mirror —
     the staged pytree holds the same arrays (data f32, cols/rowgroup/
-    colblock/first i32, perm) at the dtypes ``device_tiles`` casts to, and
-    one i32 width per tile.
+    colblock i32, perm) at the dtypes ``device_tiles`` casts to, and one
+    i32 width per tile.
     """
     return int(
         tiles.data.size * 4  # f32 payloads
         + tiles.cols.size * 4  # i32 local columns
-        + tiles.rowgroup.size * 4 * 4  # i32 rowgroup, colblock, first, width
+        + tiles.rowgroup.size * 4 * 3  # i32 rowgroup, colblock, width
         + tiles.perm.size * 4  # staged as i32
     )
 

@@ -267,9 +267,9 @@ def test_kernel_launch_counters(obs_on):
     ops.hbp_spmv(tiles, rng.standard_normal(50).astype(np.float32), strategy="stable")
     reg = obs.registry()
     assert reg.value("kernels.launches", op="spmm", strategy="stable",
-                     k_tiling="grid", combine="sum", gather="none") == 1
+                     k_tiling="grid", combine="sum", gather="none", block=0) == 1
     assert reg.value("kernels.launches", op="spmv", strategy="stable",
-                     k_tiling="grid", combine="sum", gather="none") == 1
+                     k_tiling="grid", combine="sum", gather="none", block=0) == 1
     assert reg.value("kernels.traversals") == 2  # both k <= LANE_TILE: 1 pass each
     assert reg.value("kernels.bytes_modeled") > 0
 

@@ -323,6 +323,9 @@ def test_launches_split_into_tile_chunks_match_dense(strategy, combine, k, rng, 
 
     kernels = importlib.import_module("repro.kernels.hbp_spmv")
     monkeypatch.setattr(kernels, "TILE_CHUNK", 5)
+    # fused grid steps of 5 tiles: each launch is one step over several row groups
+    monkeypatch.setattr(kernels, "BLOCK_LANE", 5)
+    monkeypatch.setattr(kernels, "BLOCK_ROW", 5)
     jax.clear_caches()  # the wrappers are jitted: retrace under the small chunk
     # long rows: row groups span many tiles, so runs straddle chunks
     dense = (rng.standard_normal((40, 120)) * (rng.random((40, 120)) < 0.4)).astype(
@@ -377,6 +380,8 @@ def test_staged_padding_tiles_change_nothing(k, combine, split, monkeypatch):
     assert (np.asarray(dt.width)[T:] == 0).all()
     if split:
         monkeypatch.setattr(kernels, "TILE_CHUNK", T)
+        monkeypatch.setattr(kernels, "BLOCK_LANE", T)
+        monkeypatch.setattr(kernels, "BLOCK_ROW", T)
     jax.clear_caches()  # the wrappers are jitted: retrace under the chunk
     X = rng.standard_normal((256, k)).astype(np.float32)
     meta = dict(n_rowgroups=tiles.n_rowgroups, n_rows=48, col_block=64, interpret=True)
@@ -433,11 +438,12 @@ def test_row_gather_matches_reference(lane, k, combine, split, rng, monkeypatch)
     if split:
         # the first launch ends inside a run: its last tile's group goes on
         monkeypatch.setattr(kernels, "TILE_CHUNK", int(np.flatnonzero(tiles.first == 0)[-1]))
+        monkeypatch.setattr(kernels, "BLOCK_ROW", 1)  # launches of exactly TILE_CHUNK tiles
         fn.clear_cache()  # the wrapper is jitted: retrace under the small chunk
     X = rng.standard_normal((dense.shape[1], k)).astype(np.float32)
     xb = ops.blocked_matrix(jnp.asarray(X), tiles.cfg.col_block)
     assert kernels.gather_body(k, xb.shape[0] * xb.shape[1]) == "row"
-    hashed = fn(dt.rowgroup, dt.colblock, dt.first, dt.data, dt.cols, xb, dt.width,
+    hashed = fn(dt.rowgroup, dt.colblock, dt.data, dt.cols, xb, dt.width,
                 n_rowgroups=tiles.n_rowgroups, interpret=True)
     empty = ~dense.any(axis=1)
     # hashed slots of empty rows and of padding (perm maps slots to rows)
@@ -450,6 +456,62 @@ def test_row_gather_matches_reference(lane, k, combine, split, rng, monkeypatch)
     want = np.asarray(hbp_spmm(tiles, X, strategy="reference", combine=combine))
     if combine == "max":
         np.testing.assert_array_equal(Y, want)  # products are the same, max is exact
+    else:
+        np.testing.assert_allclose(Y, want, rtol=1e-5, atol=1e-5)
+
+
+BLOCKED_CASES = [
+    (body, k, combine, split)
+    for body in ("lane", "row") for k in (1, 4, 8, 16, 128, 256)
+    for combine in ("sum", "max") for split in (False, True)
+]
+
+
+@pytest.mark.parametrize("body,k,combine,split", BLOCKED_CASES)
+def test_blocked_grid_matches_reference(body, k, combine, split, rng, monkeypatch):
+    """Either tile body on the blocked grid, at any k, against ``ref.py``.
+    The 47 tiles are no whole number of blocks; a block spans several row
+    groups, and one row group's run of 27 tiles spans several blocks (or,
+    under the lane gather, one short block holds all 47); two row groups
+    have no tile, so they keep the identity.  With ``split``:
+    blocks of 3, launches of 12 tiles and a window of 2 row groups, so runs
+    cross launch boundaries and windows: a launch that did not continue
+    from what the previous one wrote back would fail here."""
+    import importlib
+
+    from repro.kernels import ops, ref
+
+    kernels = importlib.import_module("repro.kernels.hbp_spmv")
+    dense, tiles = _row_gather_problem(rng, 8)
+    dt = ops.device_tiles(tiles)
+    block = 3 if split else {"lane": kernels.BLOCK_LANE, "row": kernels.BLOCK_ROW}[body]
+    assert tiles.n_tiles % block != 0
+    if block < tiles.n_tiles:  # else one short block holds every tile
+        runs = np.diff(np.append(np.flatnonzero(tiles.first), tiles.n_tiles))
+        assert (runs > block).any()  # a run spans blocks
+    if split:
+        monkeypatch.setattr(kernels, "TILE_CHUNK", 12)
+        monkeypatch.setattr(kernels, "WINDOW_BYTES", 2 * 8 * kernels.LANE_TILE * 4)
+        assert kernels._window(8, tiles.n_rowgroups) == 2
+        assert (tiles.first[12::12] == 0).any()  # a run crosses a launch boundary
+    X = rng.standard_normal((dense.shape[1], k)).astype(np.float32)
+    xb = ops.blocked_matrix(jnp.asarray(X), tiles.cfg.col_block)
+    kw = dict(n_rowgroups=tiles.n_rowgroups, combine=combine, interpret=True,
+              name="hbp_spmm_fused", block=block)
+    if body == "lane":
+        hashed = kernels._fused(dt.rowgroup, dt.colblock, dt.data, dt.cols, xb, **kw)
+    else:
+        hashed = kernels._fused_rows(dt.rowgroup, dt.colblock, dt.width, dt.data, dt.cols,
+                                     xb, **kw)
+    hashed = np.asarray(hashed[..., :k])
+    empty_slots = ~np.isin(np.asarray(dt.perm), np.flatnonzero(dense.any(axis=1)))
+    identity = 0.0 if combine == "sum" else -np.inf
+    assert (hashed.reshape(-1, k)[empty_slots] == identity).all()
+    Y = np.asarray(ref.unpermute(jnp.where(jnp.isneginf(hashed), 0.0, hashed), dt.perm,
+                                 dense.shape[0]))
+    want = np.asarray(hbp_spmm(tiles, X, strategy="reference", combine=combine))
+    if combine == "max":
+        np.testing.assert_array_equal(Y, want)
     else:
         np.testing.assert_allclose(Y, want, rtol=1e-5, atol=1e-5)
 
@@ -471,16 +533,23 @@ def test_gather_body_choice(k, x_rows, body):
     assert kernels.gather_body(k, x_rows) == body
 
 
-@pytest.mark.parametrize("op,strategy,k,gather", [
-    ("spmm", "fused", 8, "row"),
-    ("spmm", "fused", 4, "lane"),
-    ("spmv", "fused", 1, "lane"),
-    ("spmm", "partials", 8, "lane"),
-    ("spmm", "stable", 8, "none"),
+@pytest.mark.parametrize("op,strategy,k,gather,block", [
+    ("spmm", "fused", 8, "row", "BLOCK_ROW"),
+    ("spmm", "fused", 4, "lane", "BLOCK_LANE"),
+    ("spmv", "fused", 1, "lane", "BLOCK_LANE"),
+    ("spmm", "partials", 8, "lane", 1),
+    ("spmm", "stable", 8, "none", 0),
 ])
-def test_launch_counter_labels_gather(op, strategy, k, gather, rng):
-    """``kernels.launches`` names the tile body each launch runs."""
+def test_launch_counter_labels_gather(op, strategy, k, gather, block, rng):
+    """``kernels.launches`` names the tile body each launch runs and the
+    tiles each of its grid steps carries."""
+    import importlib
+
     from repro import obs
+
+    if isinstance(block, str):
+        block = getattr(importlib.import_module("repro.kernels.hbp_spmv"), block)
+        assert block > 1
 
     dense = (rng.standard_normal((40, 50)) * (rng.random((40, 50)) < 0.2)).astype(np.float32)
     tiles = build_tiles(
@@ -496,7 +565,7 @@ def test_launch_counter_labels_gather(op, strategy, k, gather, rng):
             hbp_spmm(tiles, X, strategy=strategy, interpret=True)
         assert obs.registry().value(
             "kernels.launches", op=op, strategy=strategy, k_tiling="grid", combine="sum",
-            gather=gather,
+            gather=gather, block=block,
         ) == 1
     finally:
         obs.disable()

@@ -84,7 +84,7 @@ def _fused(fn, spmm=False):
     def call(a):
         width = (a["scalars"],) if spmm else ()  # the fused SpMM's per-tile widths
         return fn.lower(
-            a["scalars"], a["scalars"], a["scalars"], a["data"], a["cols"], a["x"], *width,
+            a["scalars"], a["scalars"], a["data"], a["cols"], a["x"], *width,
             n_rowgroups=N_ROWGROUPS,
         )
     return call
@@ -144,7 +144,7 @@ def test_published_size_stream_fits_smem(one_chip, kernel, k):
 def _device_tiles(sharding, lane, n_rows):
     a = _shapes(sharding, lane, 1)
     return ops.DeviceTiles(
-        rowgroup=a["scalars"], colblock=a["scalars"], first=a["scalars"],
+        rowgroup=a["scalars"], colblock=a["scalars"],
         data=a["data"], cols=a["cols"],
         perm=jax.ShapeDtypeStruct((n_rows,), jnp.int32, sharding=sharding),
         width=a["scalars"],
@@ -182,7 +182,65 @@ def test_fused_spmm_gather_compiles_at_kron16_size(one_chip, kernel, k, n_cols, 
     a["x"] = jax.ShapeDtypeStruct((n_cols // 4096, 4096, k), jnp.float32, sharding=one_chip)
     fn = hbp_spmm_fused if kernel == "spmm_fused" else hbp_spmm_fused_max
     compiled = fn.lower(
-        a["scalars"], a["scalars"], a["scalars"], a["data"], a["cols"], a["x"], a["scalars"],
+        a["scalars"], a["scalars"], a["data"], a["cols"], a["x"], a["scalars"],
         n_rowgroups=KRON16["rowgroups"],
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") == 4
+
+
+def _kernels():
+    import importlib
+
+    return importlib.import_module("repro.kernels.hbp_spmv")
+
+
+@pytest.mark.parametrize("tiles", [KRON16["tiles"], 103_424])  # as built, and bucketed
+@pytest.mark.parametrize("body", ["lane", "row"])
+@pytest.mark.parametrize("k", [1, 8, 128, 256])
+def test_blocked_launches_compile_at_kron16_size(one_chip, tiles, body, k):
+    """Either body's blocked launches at kron16's size, at any k: a whole
+    k-tile of X resident, the output window and ``B`` tiles a step fit the
+    v5e's VMEM, and the scalar prefetch and, under the row gather, the
+    tile blocks its SMEM (the compiler refuses an allocation over either)."""
+    from functools import partial
+
+    K = _kernels()
+    a = _shapes(one_chip, 128, k, tiles=tiles)
+    x = jax.ShapeDtypeStruct((KRON16["cols"] // 4096, 4096, k), jnp.float32, sharding=one_chip)
+    kw = dict(n_rowgroups=KRON16["rowgroups"], combine="sum", interpret=False,
+              name="hbp_spmm_fused")
+    s = a["scalars"]
+    if body == "lane":
+        assert K._lane_x_bytes(16, min(k, K.LANE_TILE), 4096) <= K.X_BUDGET  # resident
+        lowered = jax.jit(partial(K._fused, **kw)).lower(s, s, a["data"], a["cols"], x)
+    else:
+        lowered = jax.jit(partial(K._fused_rows, **kw)).lower(s, s, s, a["data"], a["cols"], x)
+    assert lowered.compile().as_text().count("tpu_custom_call") == 4
+
+
+# an output of 40,228 row groups (321,821 rows, ASIC_320k's) over 327,680
+# columns: 157 MiB of VMEM whole, so its launches walk 40 windows
+WIDE = dict(rowgroups=40_228, cols=327_680)
+
+
+@pytest.mark.parametrize("k,body,block", [
+    (1, "lane", "BLOCK_LANE"),
+    (8, "lane", "BLOCK_LANE"),  # X over the row gather's budget
+    (128, "lane", 1),  # X over every budget: one segment, one tile a step
+])
+def test_fused_output_in_windows_compiles(one_chip, k, body, block):
+    """A matrix whose output cannot be resident whole runs its launches
+    over several output windows."""
+    K = _kernels()
+    block = getattr(K, block) if isinstance(block, str) else block
+    assert K._window(GROUP, WIDE["rowgroups"]) * 8 < WIDE["rowgroups"]
+    assert K.gather_body(k, WIDE["cols"]) == body
+    assert K.tiles_per_step(k, WIDE["cols"], 4096) == block
+    a = _shapes(one_chip, 128, k, tiles=KRON16["tiles"])
+    x = jax.ShapeDtypeStruct((WIDE["cols"] // 4096, 4096, k), jnp.float32, sharding=one_chip)
+    compiled = hbp_spmm_fused.lower(
+        a["scalars"], a["scalars"], a["data"], a["cols"], x, a["scalars"],
+        n_rowgroups=WIDE["rowgroups"],
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 4
+
