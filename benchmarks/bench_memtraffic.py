@@ -25,12 +25,9 @@ def main(full: bool = False) -> None:
         def fmt(tiles):
             tile_stream = tiles.n_tiles * tiles.cfg.group * tiles.cfg.lane * 8
             switches = int(np.count_nonzero(np.diff(tiles.colblock)) + 1)
-            n_cb = -(-csr.n_cols // tiles.cfg.col_block)
             y_bytes = tiles.padded_rows() * 4
             fused = tile_stream + switches * tiles.cfg.col_block * 4 + y_bytes
-            partials = (tile_stream + n_cb * tiles.cfg.col_block * 4
-                        + tiles.n_tiles * tiles.cfg.group * 8 + y_bytes)
-            return min(fused, partials), tiles.nnz_utilization()
+            return fused, tiles.nnz_utilization()
 
         hbp_total, util = fmt(build_tiles(csr, cfg, method="hash"))
         tuned_total, tuned_util = fmt(

@@ -7,15 +7,12 @@ The two numbers the CI regression gate watches (with bench_preprocess):
   coalesced request block pays;
 * ``spmm/argmax_onepass`` — the max-aggregation forward with winner
   tracking.  Its derived column also reports the structural tile-stream
-  traversal count of the one-pass paired-payload recovery vs the legacy
-  three-monoid-pass oracle (1 vs 3, counted by ``ref.count_traversals``)
-  and asserts the one-pass form stays ≤ 1.
+  traversal count of the paired-payload recovery (counted by
+  ``ref.count_traversals``) and asserts it stays ≤ 1.
 
-``k_tiling="grid"`` vs ``"loop"`` is compared on the ``"reference"``
-strategy, where the two contracts genuinely differ off-TPU (one
-full-width traversal vs ceil(k/128) chunked ones); ``--full`` sweeps all
-four kernel strategies (the Pallas pair in interpret mode, on a smaller
-matrix — interpret timings are correctness smoke, not performance).
+``--full`` sweeps the three kernel strategies (the fused kernel in
+interpret mode, on a smaller matrix — interpret timings are correctness
+smoke, not performance).
 """
 from __future__ import annotations
 
@@ -43,8 +40,8 @@ def _setup(n: int, nnz_target: int, cfg: PartitionConfig, k: int, seed: int = 0)
     return csr, tiles, dt, x, meta
 
 
-def _traversal_counts(dt, x, col_block, n_rowgroups):
-    """Structural tile-stream traversals of each argmax form (eager refs)."""
+def _traversal_count(dt, x, col_block, n_rowgroups):
+    """Structural tile-stream traversals of the argmax (eager reference)."""
     import jax.numpy as jnp
 
     xb = ops.blocked_matrix(jnp.asarray(x[:, :8]), col_block)
@@ -52,11 +49,7 @@ def _traversal_counts(dt, x, col_block, n_rowgroups):
         ref.hbp_spmm_hashed_argmax_onepass(
             dt.rowgroup, dt.colblock, dt.data, dt.cols, xb, n_rowgroups=n_rowgroups
         )
-    with ref.count_traversals() as three:
-        ref.hbp_spmm_hashed_argmax(
-            dt.rowgroup, dt.colblock, dt.data, dt.cols, xb, n_rowgroups=n_rowgroups
-        )
-    return one[0], three[0]
+    return one[0]
 
 
 def main(full: bool = False) -> None:
@@ -76,45 +69,25 @@ def main(full: bool = False) -> None:
         config={"n": csr.n_rows, "nnz": nnz, "k": K_WIDE, "strategy": "stable"},
     )
 
-    # --- one-pass 2D-grid contract vs the legacy chunk loop (jnp oracle)
-    for k_tiling in ops.K_TILINGS:
-        t = timeit(
-            lambda kt=k_tiling: ops.hbp_spmm(
-                dt, x, strategy="reference", k_tiling=kt, **meta
-            ),
-            repeats=9, warmup=2,
-        )
-        emit(
-            f"spmm/reference_k256_{k_tiling}",
-            t,
-            f"tile stream read {'once' if k_tiling == 'grid' else 'per 128-chunk'}",
-            config={"n": csr.n_rows, "nnz": nnz, "k": K_WIDE, "k_tiling": k_tiling},
-        )
-
-    # --- paired-payload argmax vs the three-pass oracle
-    one, three = _traversal_counts(dt, x, cfg.col_block, tiles.n_rowgroups)
+    # --- paired-payload argmax
+    one = _traversal_count(dt, x, cfg.col_block, tiles.n_rowgroups)
     assert one <= 1, f"one-pass argmax traversed the tile stream {one}x"
     k_arg = 8
-    for passes, label in ((1, "onepass"), (3, "threepass")):
-        t = timeit(
-            lambda p=passes: ops.hbp_spmm_argmax(dt, x[:, :k_arg], passes=p, **meta),
-            repeats=9, warmup=2,
-        )
-        emit(
-            f"spmm/argmax_{label}",
-            t,
-            f"traversals={one if passes == 1 else three} "
-            f"(one-pass {one} vs three-pass {three})",
-            config={"n": csr.n_rows, "nnz": nnz, "k": k_arg, "passes": passes},
-        )
+    t = timeit(lambda: ops.hbp_spmm_argmax(dt, x[:, :k_arg], **meta), repeats=9, warmup=2)
+    emit(
+        "spmm/argmax_onepass",
+        t,
+        f"traversals={one}",
+        config={"n": csr.n_rows, "nnz": nnz, "k": k_arg},
+    )
 
     if full:
-        # all four kernel strategies on a small matrix (Pallas pair in
-        # interpret mode: correctness smoke, timings not comparable)
+        # the three kernel strategies on a small matrix (the fused kernel
+        # in interpret mode: correctness smoke, timings not comparable)
         cfg_s = PartitionConfig(row_block=64, col_block=128, group=8, lane=16)
         csr_s, tiles_s, dt_s, x_s, meta_s = _setup(1 << 8, 2_000, cfg_s, K_WIDE, seed=1)
-        for strategy in ("fused", "partials", "reference", "stable"):
-            interpret = strategy in ("fused", "partials")
+        for strategy in ("fused", "reference", "stable"):
+            interpret = strategy == "fused"
             t = timeit(
                 lambda s=strategy: ops.hbp_spmm(
                     dt_s, x_s, strategy=s, interpret=True, **meta_s

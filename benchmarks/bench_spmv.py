@@ -12,10 +12,8 @@ Two views are reported per matrix:
   CSR's per-nnz random x read is charged one 64 B transaction — the
   effect the paper's Table II measures directly (0.15% mem-busy,
   2.85 GB/s effective CSR throughput vs 145 GB/s for HBP's staged
-  streams).  HBP staging is modelled for BOTH kernel strategies (fused
-  combine re-stages x per row-group/col-block run; the paper-faithful
-  partials stages x once per column block but pays the combine pass) and
-  the better one is reported — the system picks the strategy per matrix.
+  streams).  HBP staging is modelled for the fused-combine kernel, which
+  re-stages x per row-group/col-block run.
 """
 from __future__ import annotations
 
@@ -71,18 +69,12 @@ def main(full: bool = False) -> None:
 
             jrun = jax.jit(run)
             t = timeit(lambda: jrun().block_until_ready())
-            # v5e traffic model: tiles stream (data f32 + cols i32); x
-            # staging and combine depend on the kernel strategy — take the
-            # better of fused (x per colblock run) vs partials (x once per
-            # colblock + partial vectors written and re-read)
+            # v5e traffic model: tiles stream (data f32 + cols i32), x
+            # staged per colblock run, y written once
             tile_bytes = tiles.n_tiles * tcfg.group * tcfg.lane * 8
             switches = int(np.count_nonzero(np.diff(tiles.colblock)) + 1)
-            n_cb = -(-csr.n_cols // tcfg.col_block)
             y_bytes = tiles.padded_rows() * 4
-            fused = tile_bytes + switches * tcfg.col_block * 4 + y_bytes
-            partials = (tile_bytes + n_cb * tcfg.col_block * 4
-                        + tiles.n_tiles * tcfg.group * 8 + y_bytes)
-            results[label] = (t, min(fused, partials))
+            results[label] = (t, tile_bytes + switches * tcfg.col_block * 4 + y_bytes)
 
         # data+col streams + one 64B transaction per random x read + ptr+y
         csr_bytes = nnz * 12 + nnz * 64 + csr.n_rows * 12

@@ -132,7 +132,6 @@ def launch_record(dt: ops.DeviceTiles, meta: dict, x_shape: tuple, *,
     return {
         "strategy": meta["strategy"],
         "interpret": ops.resolve_interpret(meta["interpret"]),
-        "k_tiling": meta.get("k_tiling", "grid"),
         "tpu_custom_call": "tpu_custom_call" in lowered.as_text(),
     }
 

@@ -153,22 +153,17 @@ def argmax_spmm_diff(
     n_rowgroups: int,
     n_rows: int,
     col_block: int,
-    passes: int = 1,
 ) -> Callable[[jax.Array], jax.Array]:
     """Differentiable max-aggregation over staged tiles.
 
-    Forward runs the argmax SpMM — by default the one-pass paired-payload
-    kernel (max value + winning-neighbor index + winning coefficient
-    carried through a single tile-stream traversal; ``passes=3`` keeps
-    the legacy three-monoid-pass recovery); the tangent gathers
-    ``coeff * t[idx]`` and JAX's transpose of that gather is the
-    argmax-routed cotangent scatter.  Ties route to the lowest winning
-    column; rows with no live entry get zero output and pass no gradient
-    — identical conventions under either pass count.
+    Forward runs the one-pass argmax SpMM (max value + winning-neighbor
+    index + winning coefficient carried through a single tile-stream
+    traversal); the tangent gathers ``coeff * t[idx]`` and JAX's
+    transpose of that gather is the argmax-routed cotangent scatter.
+    Ties route to the lowest winning column; rows with no live entry get
+    zero output and pass no gradient.
     """
-    meta = dict(
-        n_rowgroups=n_rowgroups, n_rows=n_rows, col_block=col_block, passes=passes
-    )
+    meta = dict(n_rowgroups=n_rowgroups, n_rows=n_rows, col_block=col_block)
 
     @jax.custom_jvp
     def f(x):
@@ -211,7 +206,7 @@ def device_diff_aggregator(
 
     ``meta``/``meta_T`` are the keyword dicts :func:`repro.kernels.ops.
     hbp_spmm` needs beyond the tiles (``n_rowgroups``, ``n_rows``,
-    ``col_block``, ``strategy``, ``interpret``, optionally ``k_tiling``).  ``dt_T`` may be ``None``
+    ``col_block``, ``strategy``, ``interpret``).  ``dt_T`` may be ``None``
     for ``op="max"`` (its backward is a scatter, not a transpose SpMM)
     and for ``mode="jvp"``.  This is the layer
     :meth:`~repro.serving.registry.MatrixPlan.diff_aggregator` and

@@ -1,27 +1,19 @@
 """Pallas TPU kernels for HBP SpMV / SpMM.
 
-Two kernel strategies, both consuming the tile format of
-:mod:`repro.core.tile`:
+One kernel family, the **fused combine** (:func:`hbp_spmv_fused`,
+:func:`hbp_spmm_fused`, :func:`hbp_spmm_fused_max`), consuming the tile
+format of :mod:`repro.core.tile`.  Tiles come sorted by (row-group,
+col-block); each tile folds its rows straight into the output, so the
+"combine part" of the paper's Fig. 1 disappears into the SpMV pass.  On
+the GPU the paper tried this fusion and found atomics too expensive
+(Discussion section); the TPU's sequential grid gives it for free.
 
-* **fused combine** (:func:`hbp_spmv_fused`, :func:`hbp_spmm_fused`,
-  :func:`hbp_spmm_fused_max`) — beyond-paper.  Tiles come sorted by
-  (row-group, col-block); each tile folds its rows straight into the
-  output, so the "combine part" of Fig. 1 disappears into the SpMV pass.
-  On the GPU the paper tried this fusion and found atomics too expensive
-  (Discussion section); the TPU's sequential grid gives it for free.
-
-* **two-phase partials** (:func:`hbp_spmv_partials`,
-  :func:`hbp_spmm_partials`, :func:`hbp_spmm_partials_max`) — faithful to
-  the paper's SpMV-part/combine-part split: each tile writes its own
-  partial block, one tile a grid step; the combine is a separate segment
-  reduction in XLA (``ops.hbp_spmv(..., strategy="partials")``).
-
-All six entry points share the lane gather body (:func:`_tile_columns`);
-SpMV is the ``k = 1`` case of SpMM.  The two fused SpMM entry points also
-have a second body, the row gather (:func:`_row_kernel`), chosen per launch
-by :func:`gather_body`.  Each entry point passes its own name to
+The three entry points share the lane gather body (:func:`_tile_columns`);
+SpMV is the ``k = 1`` case of SpMM.  The two SpMM entry points also have a
+second body, the row gather (:func:`_row_kernel`), chosen per launch by
+:func:`gather_body`.  Each entry point passes its own name to
 ``pallas_call`` (``name=``, the entry point's name), so a profile tells
-the six kernels apart.
+the three kernels apart.
 
 **Blocked grid (fused).**  A fused launch runs the grid ``(n_k_tiles,
 cdiv(tiles, B))``: each step carries a block of ``B`` consecutive tiles
@@ -94,8 +86,7 @@ blocks (:func:`_chunks`).
 least :data:`K_ROW` wide and one k-tile of X fits :data:`X_BUDGET` whole;
 otherwise the lane gather.  ``K_ROW`` = 8 comes from a chip sweep of both
 bodies over ``K_BUCKETS`` on ``kron16``'s tiles (``benchmarks/
-gather_sweep.py``; PERF.md).  SpMV and the partials kernels always run the
-lane gather.
+gather_sweep.py``; PERF.md).  SpMV always runs the lane gather.
 
 **VMEM and SMEM** (group 8, lane 128, col_block 4096).  Lane gather, per
 launch: the resident X, ``n_col_blocks × 8 × 16 KiB`` for a k-tile of up
@@ -112,16 +103,10 @@ Each launch asks for X, the window and its VMEM tile blocks plus
 check both bodies against a described v5e at ``kron16``'s size.
 
 **2D k-tiled grid.**  One grid step carries at most :data:`LANE_TILE` RHS
-columns; wider blocks are padded to a LANE_TILE multiple and run a 2D grid
-in one launch:
-
-* **partials** — grid ``(T, k // LANE_TILE)``, tile-major.  Every step
-  writes its own output block ``(t, j)``; the (data, cols) block maps
-  depend only on ``t``, so each tile is fetched ONCE — the stream is read
-  once in total.
-* **fused** — grid ``(k // LANE_TILE, cdiv(T, B))``, k-tile-major: the
-  window and the resident X belong to one k-tile, so each k-tile re-reads
-  the stream.
+columns; wider blocks are padded to a LANE_TILE multiple and run the grid
+``(k // LANE_TILE, cdiv(T, B))`` in one launch, k-tile-major: the window
+and the resident X belong to one k-tile, so each k-tile re-reads the
+stream.
 
 Kernels are validated against ``ref.py`` in ``interpret=True`` mode on CPU
 and compiled for a described v5e by ``tests/test_tpu_compile.py``.
@@ -142,11 +127,8 @@ __all__ = [
     "gather_body",
     "tile_widths",
     "hbp_spmv_fused",
-    "hbp_spmv_partials",
     "hbp_spmm_fused",
-    "hbp_spmm_partials",
     "hbp_spmm_fused_max",
-    "hbp_spmm_partials_max",
 ]
 
 # Lanes of one VREG: the gather's chunk width and the widest RHS block one
@@ -205,7 +187,7 @@ def _segments(x_blocked: jax.Array) -> jax.Array:
     return jnp.swapaxes(x, 1, 2)
 
 
-def _tile_columns(data_ref, cols_ref, x_ref, idx_ref, *, combine: str, tile=0, segment=0):
+def _tile_columns(data_ref, cols_ref, x_ref, idx_ref, *, combine: str, tile, segment):
     """Tile ``tile`` of the data/cols block against segment ``segment`` of
     the x block, ``k_tile`` RHS columns: ``[group, k_tile]`` with
     ``out[g, j] = reduce_l data[g, l] * x[j, cols[g, l]]``.
@@ -355,12 +337,6 @@ def _row_kernel(rowgroup_ref, colblock_ref, width_ref, data_ref, cols_ref, x_ref
     _blocked(rowgroup_ref, y_ref, acc_ref, cur_ref, tile_part, **kw)
 
 
-def _partials_kernel(colblock_ref, data_ref, cols_ref, x_ref, y_prev_ref, y_ref,
-                     idx_ref, *, combine):
-    """One grid step = one tile: emit the tile's own partial block."""
-    y_ref[0] = _tile_columns(data_ref, cols_ref, x_ref, idx_ref, combine=combine)
-
-
 def _k_grid(k: int):
     """(k_tile, n_k_tiles) of a launch over a :func:`_padded_k` width."""
     if k <= LANE_TILE:
@@ -368,7 +344,7 @@ def _k_grid(k: int):
     return LANE_TILE, k // LANE_TILE
 
 
-def _chunks(T: int, block: int = 1):
+def _chunks(T: int, block: int):
     """(first tile, tiles) of each launch: the per-tile scalars a launch
     prefetches live in SMEM (1 MiB on v5e), so a launch carries at most
     :data:`TILE_CHUNK` tiles, a whole number of ``block``-tile grid steps
@@ -530,36 +506,6 @@ def _fused_rows(rowgroup, colblock, width, data, cols, x_blocked, *, n_rowgroups
     )
 
 
-def _partials(colblock, data, cols, x_blocked, *, combine, interpret, name):
-    T, group, lane = data.shape
-    xs = _segments(x_blocked)
-    _, k, seg = xs.shape
-    kt, n_kt = _k_grid(k)
-    y = jnp.zeros((T, group, k), jnp.float32)
-    for lo, n in _chunks(T):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n, n_kt),
-            in_specs=[
-                pl.BlockSpec((1, group, lane), lambda t, j, cb, lo=lo: (t + lo, 0, 0)),
-                pl.BlockSpec((1, group, lane), lambda t, j, cb, lo=lo: (t + lo, 0, 0)),
-                pl.BlockSpec((1, kt, seg), lambda t, j, cb: (cb[t], j, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, group, kt), lambda t, j, cb, lo=lo: (t + lo, 0, j)),
-            scratch_shapes=[pltpu.VMEM((group, LANE_TILE), jnp.int32)],
-        )
-        y = pl.pallas_call(
-            functools.partial(_partials_kernel, combine=combine),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(y.shape, jnp.float32),
-            input_output_aliases={4: 0},
-            interpret=interpret,
-            name=name,
-        )(colblock[lo : lo + n], data, cols, xs, y)
-    return y
-
-
 @functools.partial(jax.jit, static_argnames=("n_rowgroups", "interpret"))
 def hbp_spmv_fused(rowgroup, colblock, data, cols, x_blocked, *,
                    n_rowgroups: int, interpret: bool = False) -> jax.Array:
@@ -568,15 +514,6 @@ def hbp_spmv_fused(rowgroup, colblock, data, cols, x_blocked, *,
     return _fused(rowgroup, colblock, data, cols, x_blocked,
                   n_rowgroups=n_rowgroups, combine="sum", interpret=interpret,
                   name="hbp_spmv_fused")[..., 0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def hbp_spmv_partials(colblock, data, cols, x_blocked, *,
-                      interpret: bool = False) -> jax.Array:
-    """SpMV part only (paper-faithful): per-tile partial vectors [T, group];
-    the combine part reduces them by row group."""
-    return _partials(colblock, data, cols, x_blocked, combine="sum",
-                     interpret=interpret, name="hbp_spmv_partials")[..., 0]
 
 
 @functools.partial(jax.jit, static_argnames=("n_rowgroups", "interpret"))
@@ -596,16 +533,6 @@ def hbp_spmm_fused(rowgroup, colblock, data, cols, x_blocked, width, *,
                        name="hbp_spmm_fused")[..., :k]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def hbp_spmm_partials(colblock, data, cols, x_blocked, *,
-                      interpret: bool = False) -> jax.Array:
-    """SpMM part only (two-phase multi-RHS): per-tile partial blocks
-    [T, group, k]; the combine part reduces them by row group."""
-    k = x_blocked.shape[-1]
-    return _partials(colblock, data, cols, x_blocked, combine="sum",
-                     interpret=interpret, name="hbp_spmm_partials")[..., :k]
-
-
 @functools.partial(jax.jit, static_argnames=("n_rowgroups", "interpret"))
 def hbp_spmm_fused_max(rowgroup, colblock, data, cols, x_blocked, width, *,
                        n_rowgroups: int, interpret: bool = False) -> jax.Array:
@@ -617,13 +544,3 @@ def hbp_spmm_fused_max(rowgroup, colblock, data, cols, x_blocked, width, *,
     return _fused_spmm(rowgroup, colblock, data, cols, x_blocked, width,
                        n_rowgroups=n_rowgroups, combine="max", interpret=interpret,
                        name="hbp_spmm_fused_max")[..., :k]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def hbp_spmm_partials_max(colblock, data, cols, x_blocked, *,
-                          interpret: bool = False) -> jax.Array:
-    """SpMM part only under the max monoid: per-tile partial blocks
-    [T, group, k]; the combine part reduces them with ``segment_max``."""
-    k = x_blocked.shape[-1]
-    return _partials(colblock, data, cols, x_blocked, combine="max",
-                     interpret=interpret, name="hbp_spmm_partials_max")[..., :k]

@@ -28,7 +28,6 @@ __all__ = [
     "hbp_spmm_bucketed",
     "bucket_k",
     "K_BUCKETS",
-    "K_TILINGS",
     "LANE_TILE",
     "blocked_vector",
     "blocked_matrix",
@@ -49,16 +48,8 @@ K_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128)
 
 # Widest RHS block one kernel grid step carries (defined with the kernels;
 # re-exported here for the serving/bucketing layers).  Wider k runs the 2D
-# k-tiled grid — or, under the legacy ``k_tiling="loop"`` contract, a
-# host-side loop of sequential <=128-wide launches.
+# k-tiled grid.
 LANE_TILE = _k.LANE_TILE
-
-# Launch contracts for k wider than one lane tile: "grid" (default) reads
-# the tile stream once — Pallas strategies via the 2D (tile, k-tile) grid,
-# jnp strategies via a single full-width lane chain; "loop" is the legacy
-# host-side chunk loop (one launch per 128-wide chunk, the tile stream
-# re-read by each), kept as the equivalence/benchmark baseline.
-K_TILINGS = ("grid", "loop")
 
 
 class DeviceTiles(NamedTuple):
@@ -121,73 +112,29 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
-def stream_passes(k: int, strategy: str, k_tiling: str) -> int:
-    """How many times one launch walks the packed tile stream.
-
-    The structural quantity behind the HBM traffic model (and what
-    ``ref.count_traversals`` counts on the jnp references): at
-    ``k <= LANE_TILE`` every contract is a single traversal; wider k reads
-    the stream once under the one-pass geometries (``"grid"`` partials —
-    block maps depend only on the tile index — and the references' single
-    full-width trace) and once per 128-wide k-tile everywhere else (the
-    fused k-tile-major grid's revisits, the legacy chunk loop, and the
-    ``"stable"`` path's chunked lane chains under both tilings).
-    """
-    if k <= LANE_TILE:
-        return 1
-    if k_tiling == "grid" and strategy in ("partials", "reference"):
-        return 1
-    return -(-k // LANE_TILE)
-
-
-def modeled_launch_bytes(
-    dt: DeviceTiles, k: int, strategy: str, k_tiling: str
-) -> int:
-    """Modeled HBM bytes one SpMM launch moves (the bandwidth ledger).
-
-    Tile stream (data f32 + cols i32) and the gathered x values are paid
-    once per stream pass; the output block is written once.  A *model*,
-    not a measurement: it assumes no cache reuse across passes (the
-    pessimistic bound ``bench_memtraffic`` compares against) — useful for
-    attributing relative traffic across strategies and k-tilings, which
-    is exactly what Gao et al. identify as the binding constraint.  For
-    the Pallas kernels the x term is an upper bound: they fetch a column
-    block's segment into VMEM once per run of tiles in that block and
-    gather from VMEM.
-    """
-    passes = stream_passes(k, strategy, k_tiling)
-    stream = dt.data.nbytes + dt.cols.nbytes  # the packed tile arrays
-    gathers = dt.data.size * 4  # one f32 x gather per tile slot
-    out = dt.perm.size * max(k, 1) * 4  # one row per padded (hashed) row
-    return int(passes * (stream + gathers) + out)
-
-
 def _gather(op: str, strategy: str, k: int, x_rows: int) -> str:
     """The tile body a launch runs: the fused SpMM's choice
     (:func:`repro.kernels.hbp_spmv.gather_body`), the lane gather on the
-    other Pallas kernels, ``"none"`` on the jnp strategies."""
-    if strategy not in ("fused", "partials"):
+    fused SpMV, ``"none"`` on the jnp strategies."""
+    if strategy != "fused":
         return "none"
-    if op == "spmm" and strategy == "fused":
-        return _k.gather_body(k, x_rows)
-    return "lane"
+    return _k.gather_body(k, x_rows) if op == "spmm" else "lane"
 
 
 def _block(op: str, strategy: str, k: int, x_rows: int, col_block: int) -> int:
     """Tiles one grid step of the launch carries: the fused kernels'
-    (:func:`repro.kernels.hbp_spmv.tiles_per_step`), 1 on the partials
-    kernels, 0 on the jnp strategies."""
-    if strategy == "fused":
-        return _k.tiles_per_step(k if op == "spmm" else 1, x_rows, col_block)
-    return 1 if strategy == "partials" else 0
+    (:func:`repro.kernels.hbp_spmv.tiles_per_step`), 0 on the jnp
+    strategies."""
+    if strategy != "fused":
+        return 0
+    return _k.tiles_per_step(k if op == "spmm" else 1, x_rows, col_block)
 
 
 def _record_launch(
-    dt: DeviceTiles, k: int, *, op: str, strategy: str, k_tiling: str,
-    combine: str = "sum", passes: int | None = None, x_rows: int = 0,
+    k: int, *, op: str, strategy: str, combine: str = "sum", x_rows: int = 0,
     col_block: int = 1,
 ) -> None:
-    """Gated kernel-traffic accounting: one bump per *Python-level* launch.
+    """Gated launch accounting: one bump per *Python-level* launch.
 
     Calls traced inside an outer ``jit`` (e.g. the solver ``while_loop``
     body) are counted once per trace, not once per device execution — the
@@ -198,16 +145,10 @@ def _record_launch(
     if not obs.enabled():
         return
     obs.counter(
-        "kernels.launches", op=op, strategy=strategy, k_tiling=k_tiling,
-        combine=combine, gather=_gather(op, strategy, k, x_rows),
+        "kernels.launches", op=op, strategy=strategy, combine=combine,
+        gather=_gather(op, strategy, k, x_rows),
         block=_block(op, strategy, k, x_rows, col_block),
     ).inc()
-    n_passes = stream_passes(k, strategy, k_tiling) if passes is None else passes
-    obs.counter("kernels.traversals").inc(n_passes)
-    obs.counter("kernels.bytes_modeled").inc(
-        modeled_launch_bytes(dt, k, strategy, k_tiling)
-    )
-    obs.counter("kernels.k_tiling", choice=k_tiling).inc()
     obs.histogram("kernels.launch_k").observe(k)
 
 
@@ -230,12 +171,6 @@ def _hbp_spmv_device(
             dt.rowgroup, dt.colblock, dt.data, dt.cols, x_blocked,
             n_rowgroups=n_rowgroups, interpret=interpret,
         )
-    elif strategy == "partials":
-        # paper-faithful split: SpMV part (kernel) + combine part (XLA)
-        contrib = _k.hbp_spmv_partials(
-            dt.colblock, dt.data, dt.cols, x_blocked, interpret=interpret
-        )
-        y_hashed = jax.ops.segment_sum(contrib, dt.rowgroup, num_segments=n_rowgroups)
     elif strategy == "reference":
         y_hashed = _ref.hbp_spmv_hashed_ref(
             dt.rowgroup, dt.colblock, dt.data, dt.cols, x_blocked,
@@ -255,7 +190,7 @@ def _hbp_spmv_device(
 
 def _spmm_hashed_chunk(
     dt: DeviceTiles,
-    x_blocked: jax.Array,  # f32[n_blocks, col_block, k<=LANE_TILE]
+    x_blocked: jax.Array,  # f32[n_blocks, col_block, k]
     *,
     n_rowgroups: int,
     strategy: str,
@@ -274,11 +209,6 @@ def _spmm_hashed_chunk(
                 dt.rowgroup, dt.colblock, dt.data, dt.cols, x_blocked, dt.width,
                 n_rowgroups=n_rowgroups, interpret=interpret,
             )
-        if strategy == "partials":
-            contrib = _k.hbp_spmm_partials_max(
-                dt.colblock, dt.data, dt.cols, x_blocked, interpret=interpret
-            )
-            return jax.ops.segment_max(contrib, dt.rowgroup, num_segments=n_rowgroups)
         if strategy in ("reference", "stable"):
             # maximum is exactly associative/commutative: the unrolled lane
             # chain is reference, stable and batch-width-invariant at once
@@ -294,11 +224,6 @@ def _spmm_hashed_chunk(
             dt.rowgroup, dt.colblock, dt.data, dt.cols, x_blocked, dt.width,
             n_rowgroups=n_rowgroups, interpret=interpret,
         )
-    if strategy == "partials":
-        contrib = _k.hbp_spmm_partials(
-            dt.colblock, dt.data, dt.cols, x_blocked, interpret=interpret
-        )
-        return jax.ops.segment_sum(contrib, dt.rowgroup, num_segments=n_rowgroups)
     if strategy == "reference":
         return _ref.hbp_spmm_hashed_ref(
             dt.rowgroup, dt.colblock, dt.data, dt.cols, x_blocked,
@@ -313,10 +238,7 @@ def _spmm_hashed_chunk(
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=(
-        "n_rowgroups", "n_rows", "strategy", "interpret", "combine", "k_tiling",
-    ),
+    jax.jit, static_argnames=("n_rowgroups", "n_rows", "strategy", "interpret", "combine")
 )
 def _hbp_spmm_device(
     dt: DeviceTiles,
@@ -327,68 +249,31 @@ def _hbp_spmm_device(
     strategy: str,
     interpret: bool,
     combine: str = "sum",
-    k_tiling: str = "grid",
 ) -> jax.Array:
-    """Hashed SpMM + unpermute, k-tiling the RHS width.
+    """Hashed SpMM + unpermute, at any RHS width ``k``.
 
-    ``k`` lives in the lane dimension of the kernels, so one grid step
-    carries at most :data:`LANE_TILE` RHS columns.  Wider feature blocks
-    (GNN aggregation at k = 256, 512, ...) are served under one of two
-    launch contracts:
-
-    * ``k_tiling="grid"`` (default, one-pass) — the Pallas strategies pad
-      k to a LANE_TILE multiple and run the kernels' **2D k-tiled grid**
-      as ONE launch.  ``"partials"`` is tile-major: its (data, cols)
-      block maps depend only on the tile index, so the stream is fetched
-      once and revisited across k-tiles — one read total.  ``"fused"`` is
-      k-tile-major (its in-kernel accumulation revisits output blocks,
-      which Pallas TPU only preserves across consecutive steps, pinning
-      the tile index innermost): same stream bytes as the loop, but no
-      per-chunk host round-trips and the grid pipeline overlaps k-tiles.
-      ``"reference"`` runs its einsum oracle over the full width in a
-      single traversal.  ``"stable"``
-      keeps the chunked <=LANE_TILE lane chains under BOTH tilings: its
-      contract is bitwise batch-width invariance, which XLA only upholds
-      across launch widths that share codegen — a single wide trace
-      changes the tail columns' contraction by ~1 ulp (pinned by
-      tests/test_onepass.py), so for stable the two tilings are the same
-      computation and bits never move.
-    * ``k_tiling="loop"`` (legacy) — a host-side loop of sequential
-      <=LANE_TILE-wide launches, the tile stream re-read once per chunk:
-      ceil(k / 128) passes.  Kept as the equivalence baseline and for the
-      bench regression gate's before/after comparison.
-
-    The contract never changes results: each strategy's lane reduction is
-    per-column (elementwise across k), so a column's value — and for
-    ``"stable"`` its exact bit pattern — is independent of launch width,
-    chunking, and k_tiling (tests/test_onepass.py pins this at every
-    k-bucket boundary).
+    ``"fused"`` and ``"reference"`` run one launch at any k: the Pallas
+    wrappers pad k to whole lane tiles and run the kernels' 2D k-tiled
+    grid, and the reference einsum covers the full width in one trace.
+    ``"stable"`` keeps <= LANE_TILE-wide lane chains beyond one lane tile:
+    its contract is bitwise batch-width invariance, which XLA upholds only
+    across launch widths that share codegen (a single wide trace moves the
+    tail columns by ~1 ulp; tests/test_onepass.py pins this).
     """
     k = x_blocked.shape[-1]
     if dt.data.shape[0] == 0:  # empty matrix: no tiles, Y == identity-mapped 0
         return jnp.zeros((n_rows, k), jnp.float32)
-    if k_tiling not in K_TILINGS:
-        raise ValueError(f"unknown k_tiling {k_tiling!r} (expected one of {K_TILINGS})")
-    if k <= LANE_TILE:
-        y_hashed = _spmm_hashed_chunk(
-            dt, x_blocked, n_rowgroups=n_rowgroups, strategy=strategy,
-            combine=combine, interpret=interpret,
-        )
-    elif k_tiling == "grid" and strategy != "stable":
-        # the Pallas wrappers pad k to whole lane tiles and slice it back
-        y_hashed = _spmm_hashed_chunk(
-            dt, x_blocked, n_rowgroups=n_rowgroups, strategy=strategy,
-            combine=combine, interpret=interpret,
-        )
+    chunk = functools.partial(
+        _spmm_hashed_chunk, dt, n_rowgroups=n_rowgroups, strategy=strategy,
+        combine=combine, interpret=interpret,
+    )
+    if k <= LANE_TILE or strategy != "stable":
+        y_hashed = chunk(x_blocked)
     else:
-        chunks = [
-            _spmm_hashed_chunk(
-                dt, x_blocked[..., lo : lo + LANE_TILE], n_rowgroups=n_rowgroups,
-                strategy=strategy, combine=combine, interpret=interpret,
-            )
-            for lo in range(0, k, LANE_TILE)
-        ]
-        y_hashed = jnp.concatenate(chunks, axis=-1)
+        y_hashed = jnp.concatenate(
+            [chunk(x_blocked[..., lo : lo + LANE_TILE]) for lo in range(0, k, LANE_TILE)],
+            axis=-1,
+        )
     if combine == "max":
         # rows with no live entry hold the monoid identity; outputs are 0
         # there (the aggregation convention for isolated graph nodes)
@@ -414,27 +299,18 @@ def hbp_spmv(
     tiles: HBPTiles | DeviceTiles,
     x: jax.Array,
     *,
-    strategy: Literal["fused", "partials", "reference", "stable"] = "fused",
+    strategy: Literal["fused", "reference", "stable"] = "fused",
     interpret: bool | None = None,
     n_rowgroups: int | None = None,
     n_rows: int | None = None,
     col_block: int | None = None,
-    k_tiling: Literal["grid", "loop"] = "grid",
 ) -> jax.Array:
-    """HBP SpMV: ``y = A @ x`` with A in HBP tile format.
-
-    ``k_tiling`` is accepted for meta-dict uniformity with
-    :func:`hbp_spmm` (a serving plan passes one keyword set to both);
-    a single vector never spans more than one lane tile, so both
-    contracts are the same launch here.
-    """
-    if k_tiling not in K_TILINGS:
-        raise ValueError(f"unknown k_tiling {k_tiling!r} (expected one of {K_TILINGS})")
+    """HBP SpMV: ``y = A @ x`` with A in HBP tile format."""
     x = jnp.asarray(x, jnp.float32)
     dt, (n_rowgroups, n_rows, col_block) = _resolve(tiles, x, n_rowgroups, n_rows, col_block)
     x_blocked = blocked_vector(x, col_block)
-    _record_launch(dt, 1, op="spmv", strategy=strategy, k_tiling=k_tiling,
-                   x_rows=x_blocked.size, col_block=col_block)
+    _record_launch(1, op="spmv", strategy=strategy, x_rows=x_blocked.size,
+                   col_block=col_block)
     entry, kw = _entry(
         x_blocked, n_rowgroups=n_rowgroups, n_rows=n_rows, strategy=strategy,
         interpret=interpret,
@@ -442,8 +318,7 @@ def hbp_spmv(
     return entry(dt, x_blocked, **kw)
 
 
-def _entry(x_blocked, *, n_rowgroups, n_rows, strategy, interpret,
-           combine="sum", k_tiling="grid"):
+def _entry(x_blocked, *, n_rowgroups, n_rows, strategy, interpret, combine="sum"):
     """The jitted entry a wrapper call dispatches, and its static arguments:
     ``_hbp_spmv_device`` for a blocked vector, ``_hbp_spmm_device`` for a
     blocked ``[.., k]`` matrix."""
@@ -451,7 +326,7 @@ def _entry(x_blocked, *, n_rowgroups, n_rows, strategy, interpret,
               interpret=resolve_interpret(interpret))
     if len(x_blocked.shape) == 2:
         return _hbp_spmv_device, kw
-    return _hbp_spmm_device, dict(kw, combine=combine, k_tiling=k_tiling)
+    return _hbp_spmm_device, dict(kw, combine=combine)
 
 
 def lower_launch(dt: DeviceTiles, x, *, col_block: int, **meta):
@@ -519,14 +394,13 @@ def hbp_spmm_bucketed(
     return hbp_spmm(tiles, x, **kwargs)[:, :k]
 
 
-@functools.partial(jax.jit, static_argnames=("n_rowgroups", "n_rows", "passes"))
+@functools.partial(jax.jit, static_argnames=("n_rowgroups", "n_rows"))
 def _hbp_spmm_argmax_device(
     dt: DeviceTiles,
     x_blocked: jax.Array,  # f32[n_blocks, col_block, k]
     *,
     n_rowgroups: int,
     n_rows: int,
-    passes: int = 1,
 ):
     k = x_blocked.shape[-1]
     if dt.data.shape[0] == 0:  # no tiles: every row is empty
@@ -535,12 +409,7 @@ def _hbp_spmm_argmax_device(
             jnp.full((n_rows, k), -1, jnp.int32),
             jnp.zeros((n_rows, k), jnp.float32),
         )
-    hashed = (
-        _ref.hbp_spmm_hashed_argmax_onepass
-        if passes == 1
-        else _ref.hbp_spmm_hashed_argmax
-    )
-    y_h, idx_h, coeff_h = hashed(
+    y_h, idx_h, coeff_h = _ref.hbp_spmm_hashed_argmax_onepass(
         dt.rowgroup, dt.colblock, dt.data, dt.cols, x_blocked,
         n_rowgroups=n_rowgroups,
     )
@@ -559,7 +428,6 @@ def hbp_spmm_argmax(
     n_rowgroups: int | None = None,
     n_rows: int | None = None,
     col_block: int | None = None,
-    passes: Literal[1, 3] = 1,
 ):
     """Max-monoid SpMM with winner tracking: ``(y, idx, coeff)``.
 
@@ -571,50 +439,35 @@ def hbp_spmm_argmax(
     VJP scatters ``coeff * cotangent`` back to row ``idx`` of the input.
     The reduction runs on the monoid-exact jnp path (the same lane chain
     as ``strategy="stable"``), so values are bitwise identical across
-    batch widths and strategies.
-
-    ``passes=1`` (default) carries a paired (value, index, coefficient)
-    payload through a single tile-stream traversal
-    (:func:`repro.kernels.ref.hbp_spmm_hashed_argmax_onepass`);
-    ``passes=3`` runs the legacy three-monoid-pass recovery, kept as the
-    equivalence oracle.  Both return identical triples.
+    batch widths and strategies.  A paired (value, index, coefficient)
+    payload goes through a single tile-stream traversal
+    (:func:`repro.kernels.ref.hbp_spmm_hashed_argmax_onepass`).
     """
-    if passes not in (1, 3):
-        raise ValueError(f"passes must be 1 or 3, got {passes!r}")
     x = jnp.asarray(x, jnp.float32)
     dt, (n_rowgroups, n_rows, col_block) = _resolve(tiles, x, n_rowgroups, n_rows, col_block)
-    _record_launch(
-        dt, x.shape[1], op="spmm_argmax", strategy="stable", k_tiling="grid",
-        combine="max", passes=passes,
-    )
+    _record_launch(x.shape[1], op="spmm_argmax", strategy="stable", combine="max")
     x_blocked = blocked_matrix(x, col_block)
-    return _hbp_spmm_argmax_device(
-        dt, x_blocked, n_rowgroups=n_rowgroups, n_rows=n_rows, passes=passes
-    )
+    return _hbp_spmm_argmax_device(dt, x_blocked, n_rowgroups=n_rowgroups, n_rows=n_rows)
 
 
 def hbp_spmm(
     tiles: HBPTiles | DeviceTiles,
     x: jax.Array,  # [n_cols, k]
     *,
-    strategy: Literal["fused", "partials", "reference", "stable"] = "fused",
+    strategy: Literal["fused", "reference", "stable"] = "fused",
     combine: Literal["sum", "max"] = "sum",
     interpret: bool | None = None,
     n_rowgroups: int | None = None,
     n_rows: int | None = None,
     col_block: int | None = None,
-    k_tiling: Literal["grid", "loop"] = "grid",
 ) -> jax.Array:
     """HBP multi-RHS SpMM: ``Y = A (x) X`` with A in HBP tile format.
 
     One grid step serves up to :data:`LANE_TILE` columns of X; wider
-    blocks run the one-pass geometry (``k_tiling="grid"``, default): one
-    2D k-tiled kernel launch — tile-major for ``"partials"`` (the tile
-    stream is read ONCE for all k) and k-tile-major for ``"fused"``
-    (consecutive-revisit accumulation) — or the ``"reference"`` jnp
-    path's single full-width traversal; versus the ceil(k/128) separate
-    launches of the legacy host-side chunk loop (``k_tiling="loop"``) or
-    the k reads of SpMV-per-column.
+    blocks run one launch of the fused kernel's 2D k-tiled grid
+    (k-tile-major: each k-tile re-reads the tile stream) or the
+    ``"reference"`` jnp path's single full-width traversal; the
+    ``"stable"`` path runs <= LANE_TILE-wide lane chains.
 
     ``combine`` selects the reduction monoid: ``"sum"`` is the standard
     SpMM; ``"max"`` computes ``Y[i, c] = max_j A[i, j] * X[j, c]`` over
@@ -625,11 +478,11 @@ def hbp_spmm(
     dt, (n_rowgroups, n_rows, col_block) = _resolve(tiles, x, n_rowgroups, n_rows, col_block)
     x_blocked = blocked_matrix(x, col_block)
     _record_launch(
-        dt, x.shape[1], op="spmm", strategy=strategy, k_tiling=k_tiling,
-        combine=combine, x_rows=x_blocked.shape[0] * col_block, col_block=col_block,
+        x.shape[1], op="spmm", strategy=strategy, combine=combine,
+        x_rows=x_blocked.shape[0] * col_block, col_block=col_block,
     )
     entry, kw = _entry(
         x_blocked, n_rowgroups=n_rowgroups, n_rows=n_rows, strategy=strategy,
-        interpret=interpret, combine=combine, k_tiling=k_tiling,
+        interpret=interpret, combine=combine,
     )
     return entry(dt, x_blocked, **kw)

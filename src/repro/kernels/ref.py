@@ -22,7 +22,6 @@ __all__ = [
     "hbp_spmm_hashed_stable",
     "tile_contrib_spmm_max",
     "hbp_spmm_hashed_max",
-    "hbp_spmm_hashed_argmax",
     "hbp_spmm_hashed_argmax_onepass",
     "count_traversals",
     "unpermute",
@@ -30,10 +29,10 @@ __all__ = [
 
 # Tile-stream traversal accounting.  A "traversal" is one walk over the
 # packed (data, cols) stream with its x gathers — the dominant HBM traffic
-# of every kernel in this package, and the quantity the one-pass argmax
-# exists to cut from 3 to 1.  Each lane-loop body below bumps the counter
-# once per *trace*, so callers measuring it must invoke these references
-# directly (eagerly or via a fresh trace), not through a cached jit.
+# of every kernel in this package, and what the one-pass argmax keeps to
+# 1.  Each lane-loop body below bumps the counter once per *trace*, so
+# callers measuring it must invoke these references directly (eagerly or
+# via a fresh trace), not through a cached jit.
 _TRAVERSALS = [0]
 
 
@@ -219,7 +218,7 @@ def hbp_spmm_hashed_max(
     return jax.ops.segment_max(contrib, rowgroup, num_segments=n_rowgroups)
 
 
-def hbp_spmm_hashed_argmax(
+def hbp_spmm_hashed_argmax_onepass(
     rowgroup: jax.Array,
     colblock: jax.Array,
     data: jax.Array,
@@ -233,89 +232,22 @@ def hbp_spmm_hashed_argmax(
     Returns ``(y, idx, coeff)`` in hashed row order, each
     ``[n_rowgroups, group, k]``:
 
-    * ``y`` — the max-SpMM values (``-inf`` identity for rows with no live
-      entry, exactly :func:`hbp_spmm_hashed_max`);
+    * ``y`` — the max-SpMM values, bitwise those of
+      :func:`hbp_spmm_hashed_max` (the value chain is the exact
+      ``maximum`` sequence of :func:`tile_contrib_spmm_max`; ``-inf`` for
+      rows with no live entry);
     * ``idx`` — the *global column id* of the winning stored entry
       (``-1`` where the row has none), ties broken to the lowest column;
-    * ``coeff`` — the winning entry's stored value ``a_{i, idx}``
-      (0 where the row has none).
+    * ``coeff`` — the winning entry's stored value (0 where the row has
+      none).
 
-    This is the forward of max-aggregation's VJP: the backward routes the
-    cotangent to the winning neighbor, scaled by ``coeff``.  The index is
-    recovered by a **parallel index-SpMM under the same max monoid** — a
-    second pass over the tile stream that reduces ``-col`` (so the max
-    picks the lowest column) over the slots whose product attained ``y``,
-    and a third pass that reads the winner's coefficient.  Three passes
-    keep every reduction inside the monoid the kernels already implement.
-    Kept as the equivalence oracle of the production
-    :func:`hbp_spmm_hashed_argmax_onepass`, which carries (value, index,
-    coefficient) as a paired payload through a single traversal.
-    """
-    n_cb, col_block, k = x_blocked.shape
-    x_flat = x_blocked.reshape(n_cb * col_block, k)
-    base = colblock[:, None] * col_block  # [T, 1]
-    y = hbp_spmm_hashed_max(
-        rowgroup, colblock, data, cols, x_blocked, n_rowgroups=n_rowgroups
-    )
-    y_t = y[rowgroup]  # [T, group, k] each tile's target row values
-    int_min = jnp.iinfo(jnp.int32).min
-
-    def lane_parts(lane):
-        d = data[:, :, lane, None]  # [T, group, 1]
-        gcol = (base + cols[:, :, lane])[..., None]  # [T, group, 1] global col
-        prod = d * x_flat[base + cols[:, :, lane]]  # [T, group, k]
-        win = (d != 0) & (prod == y_t)
-        return d, gcol, win
-
-    # pass 2: lowest winning global column, as a max of the negated id
-    _traverse()
-    acc = None
-    for lane in range(data.shape[2]):
-        d, gcol, win = lane_parts(lane)
-        term = jnp.where(win, -gcol.astype(jnp.int32), int_min)
-        acc = term if acc is None else jnp.maximum(acc, term)
-    neg_idx = jax.ops.segment_max(acc, rowgroup, num_segments=n_rowgroups)
-    live = neg_idx > int_min  # also False for never-visited row groups
-    idx = jnp.where(live, -neg_idx, -1)
-
-    # pass 3: the winner's stored coefficient (unique per (row, col) pair)
-    _traverse()
-    idx_t = idx[rowgroup]
-    acc_c = None
-    for lane in range(data.shape[2]):
-        d = data[:, :, lane, None]
-        gcol = (base + cols[:, :, lane])[..., None].astype(jnp.int32)
-        hit = (d != 0) & (gcol == idx_t)
-        term = jnp.where(hit, jnp.broadcast_to(d, idx_t.shape), -jnp.inf)
-        acc_c = term if acc_c is None else jnp.maximum(acc_c, term)
-    coeff = jax.ops.segment_max(acc_c, rowgroup, num_segments=n_rowgroups)
-    coeff = jnp.where(live, coeff, 0.0)
-    return y, idx, coeff
-
-
-def hbp_spmm_hashed_argmax_onepass(
-    rowgroup: jax.Array,
-    colblock: jax.Array,
-    data: jax.Array,
-    cols: jax.Array,
-    x_blocked: jax.Array,
-    *,
-    n_rowgroups: int,
-):
-    """One-pass argmax SpMM: the paired-payload form of
-    :func:`hbp_spmm_hashed_argmax`.
-
-    Returns the same ``(y, idx, coeff)`` triple — bitwise-identical values
-    (the value chain is the exact ``maximum`` sequence of
-    :func:`tile_contrib_spmm_max`), identical tie-breaking (lowest global
-    column) and empty-row conventions (``idx = -1``, ``coeff = 0``) — but
-    walks the tile stream ONCE: each lane step advances a paired
+    It walks the tile stream ONCE: each lane step advances a paired
     ``(value, index, coefficient)`` payload through the max combine, where
     a lane term displaces the accumulator iff its value is strictly
     greater or equal-with-lower-column.  The per-tile payloads are then
     reduced across each row group with segment ops over the already-
     materialized ``[T, group, k]`` contributions — no further x gathers or
-    data reads, so tile-stream traffic is 1/3 of the three-pass oracle's.
+    data reads.
     """
     _traverse()
     n_cb, col_block, k = x_blocked.shape

@@ -29,12 +29,11 @@ Everything is registered as **always-live labelled gauges** on the serving
 registry's shared :class:`~repro.obs.metrics.MetricRegistry` (so they
 scrape through the OpenMetrics exporter and land in every ``obs.dump()``),
 alongside the autotune **decision provenance** (which candidates were
-measured, what each cost, why the winner won, how ``k_tiling`` was
-picked).  :func:`explain_report` joins the static picture with the
-*measured* split of the matrix's served requests (queue wait, dispatch,
-time in flight, batches ahead at launch) into the per-matrix "why is this
-fast or slow" report ``python -m repro.analysis.report --explain MATRIX``
-renders.
+measured, what each cost, why the winner won).  :func:`explain_report`
+joins the static picture with the *measured* split of the matrix's served
+requests (queue wait, dispatch, time in flight, batches ahead at launch)
+into the per-matrix "why is this fast or slow" report ``python -m
+repro.analysis.report --explain MATRIX`` renders.
 """
 from __future__ import annotations
 
@@ -215,7 +214,7 @@ def register_plan_metrics(
     ``plan.<key>`` gauges; the bounded occupancy sample feeds the
     ``plan.tile_occupancy`` histogram; autotune provenance lands as
     ``plan.autotune_*`` gauges (per-trial objective times labelled by the
-    candidate geometry) plus ``plan.k_tiling_us`` per measured contract.
+    candidate geometry).
     """
     for key in _GAUGE_KEYS:
         v = quality.get(key)
@@ -253,11 +252,6 @@ def register_plan_metrics(
         m.gauge("plan.autotune_trial_us", matrix=name, config=label).set(
             float(trial["objective_us"])
         )
-    for kt, us in sorted((provenance.get("k_tiling_us") or {}).items()):
-        m.gauge("plan.k_tiling_us", matrix=name, k_tiling=kt).set(float(us))
-    kt = provenance.get("k_tiling")
-    if kt:
-        m.gauge("plan.k_tiling_choice", matrix=name, k_tiling=kt).set(1.0)
 
 
 def _config_label(cfg: dict) -> str:
@@ -272,10 +266,9 @@ def _config_label(cfg: dict) -> str:
 
 def plan_metrics_from_snapshot(snapshot: dict, matrix: str) -> dict:
     """Every ``plan.*`` metric for ``matrix`` out of an ``obs.dump()``
-    snapshot: plain gauges as ``{short_name: value}``, the per-trial and
-    per-contract families as sorted ``(label, value)`` lists under
-    ``autotune_trials`` / ``k_tiling_us`` / ``k_tiling_choice``."""
-    out: dict = {"autotune_trials": [], "k_tiling_us": [], "k_tiling_choice": []}
+    snapshot: plain gauges as ``{short_name: value}``, the per-trial family
+    as a sorted ``(label, value)`` list under ``autotune_trials``."""
+    out: dict = {"autotune_trials": []}
     for reg in snapshot.get("registries", []):
         for m in reg.get("metrics", []):
             name = m.get("name", "")
@@ -285,15 +278,9 @@ def plan_metrics_from_snapshot(snapshot: dict, matrix: str) -> dict:
             short = name[len("plan.") :]
             if name == "plan.autotune_trial_us":
                 out["autotune_trials"].append((lab.get("config", "?"), m["value"]))
-            elif name == "plan.k_tiling_us":
-                out["k_tiling_us"].append((lab.get("k_tiling", "?"), m["value"]))
-            elif name == "plan.k_tiling_choice":
-                out["k_tiling_choice"].append(lab.get("k_tiling", "?"))
             elif "value" in m:
                 out[short] = m["value"]
     out["autotune_trials"].sort(key=lambda t: (t[1], t[0]))
-    out["k_tiling_us"].sort()
-    out["k_tiling_choice"].sort()
     return out
 
 
@@ -441,18 +428,6 @@ def explain_report(snapshot: dict, matrix: str) -> str:
             for i, (label, us) in enumerate(trials):
                 delta = "winner" if i == 0 else f"+{100 * (us / best - 1):.1f}%"
                 lines.append(f"    {label:<24} {us:>10.1f}us  {delta}")
-        choice = pm["k_tiling_choice"]
-        kt_us = dict(pm["k_tiling_us"])
-        if kt_us:
-            measured = "  ".join(f"{kt}={us:.1f}us" for kt, us in sorted(kt_us.items()))
-            lines.append(
-                f"  k_tiling: {', '.join(choice) or '?'} (measured: {measured})"
-            )
-        elif choice:
-            lines.append(
-                f"  k_tiling: {', '.join(choice)} "
-                "(contracts coincide at the served width — no measurement needed)"
-            )
 
     # --- served requests: queue / dispatch / in flight --------------------
     lines.append("-- served requests (queue wait / dispatch / in flight) --")

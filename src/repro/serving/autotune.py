@@ -38,8 +38,6 @@ __all__ = [
     "Probe",
     "spmm_probe",
     "cg_probe",
-    "measure_k_tilings",
-    "pick_k_tiling",
     "autotune_partition",
     "device_kind",
     "DEFAULT_CACHE_DIR",
@@ -183,28 +181,15 @@ class Probe:
         return self.measure(csr, cfg, repeats)
 
 
-def spmm_probe(k: int = 8, strategy: str = "stable", k_tiling: str = "grid") -> Probe:
+def spmm_probe(k: int = 8, strategy: str = "stable") -> Probe:
     """The default serving objective: one steady-state k-wide SpMM launch.
 
-    ``k_tiling`` selects the launch geometry the measurement runs under —
-    ``"grid"`` (the one-pass 2D k-tiled grid the plans serve by default)
-    or ``"loop"`` (the legacy chunked launches).  At k <= LANE_TILE the
-    two geometries are the same launch, so the params tuple stays the
-    historical two-element ``(k, strategy)`` and existing cache entries
-    keep satisfying (they measured the identical computation); at wider
-    k the geometries genuinely differ and ``k_tiling`` enters the
-    fingerprint, so a loop-era entry never silently ranks a grid-served
-    admission (or vice versa).
-    """
-    from repro.kernels.ops import LANE_TILE
-
-    params = (k, strategy) if k <= LANE_TILE else (k, strategy, k_tiling)
+    The params tuple is ``(k, strategy)`` at every width, the fingerprint
+    cache entries have always carried at ``k <= LANE_TILE``."""
     return Probe(
         kind="spmm",
-        measure=lambda csr, cfg, repeats: _measure_spmm_us(
-            csr, cfg, k, repeats, strategy, k_tiling=k_tiling
-        ),
-        params=params,
+        measure=lambda csr, cfg, repeats: _measure_spmm_us(csr, cfg, k, repeats, strategy),
+        params=(k, strategy),
     )
 
 
@@ -250,15 +235,14 @@ def _measure_spmm_us(
     k: int,
     repeats: int,
     strategy: str,
-    k_tiling: str = "grid",
 ) -> float:
     """Median microseconds of one k-wide SpMM launch under ``cfg``.
 
-    ``strategy`` (and ``k_tiling``) should be the path serving will
-    actually run (the registry passes its own), so the search ranks
-    configs under the cost model traffic pays — the jnp paths' k-scaling
-    differs from the fused kernel's, and off-TPU the kernels execute in
-    interpret mode whose timings are meaningless.
+    ``strategy`` should be the path serving will actually run (the
+    registry passes its own), so the search ranks configs under the cost
+    model traffic pays — the jnp paths' k-scaling differs from the fused
+    kernel's, and off-TPU the kernels execute in interpret mode whose
+    timings are meaningless.
     """
     from repro.kernels import ops
 
@@ -269,7 +253,6 @@ def _measure_spmm_us(
         n_rows=tiles.shape[0],
         col_block=cfg.col_block,
         strategy=strategy,
-        k_tiling=k_tiling,
     )
     x = np.random.default_rng(0).standard_normal((csr.n_cols, k)).astype(np.float32)
     ops.hbp_spmm(dt, x, **meta).block_until_ready()  # compile outside the clock
@@ -279,57 +262,6 @@ def _measure_spmm_us(
         ops.hbp_spmm(dt, x, **meta).block_until_ready()
         ts.append(time.perf_counter() - t0)
     return float(np.median(ts) * 1e6)
-
-
-def measure_k_tilings(
-    csr: CSRMatrix,
-    cfg: PartitionConfig,
-    *,
-    k: int = 256,
-    strategy: str = "stable",
-    repeats: int = 3,
-) -> Optional[dict]:
-    """Measured microseconds per launch-geometry contract, or ``None``.
-
-    Returns ``{"grid": us, "loop": us}`` at a RHS width where the two
-    contracts genuinely differ.  At ``k <= LANE_TILE`` the contracts are
-    the same launch, and under ``strategy="stable"`` they are the same
-    chunked computation at EVERY width (bitwise invariance is that path's
-    contract) — measuring would just rank noise, so both cases return
-    ``None`` and the caller keeps the default.  The non-None dict is the
-    provenance :func:`pick_k_tiling` decides from, recorded per plan so
-    ``explain()`` can show why a geometry was served.
-    """
-    from repro.kernels import ops
-
-    if k <= ops.LANE_TILE or strategy == "stable":
-        return None  # the contracts are the same computation here
-    return {
-        kt: _measure_spmm_us(csr, cfg, k, repeats, strategy, k_tiling=kt)
-        for kt in ops.K_TILINGS
-    }
-
-
-def pick_k_tiling(
-    csr: CSRMatrix,
-    cfg: PartitionConfig,
-    *,
-    k: int = 256,
-    strategy: str = "stable",
-    repeats: int = 3,
-) -> str:
-    """Measured per-matrix choice between the one-pass 2D k-tiled grid and
-    the legacy chunk loop, at a wide RHS width where the two differ.
-
-    Returns ``"grid"`` or ``"loop"``, whichever served the faster launch
-    under this matrix's geometry (the registry's ``k_tiling="auto"`` calls
-    this at admission); ``"grid"`` when :func:`measure_k_tilings`
-    short-circuits because the contracts coincide.
-    """
-    times = measure_k_tilings(csr, cfg, k=k, strategy=strategy, repeats=repeats)
-    if times is None:
-        return "grid"
-    return min(times, key=times.get)
 
 
 def autotune_partition(
@@ -342,7 +274,6 @@ def autotune_partition(
     k: int = 8,
     repeats: int = 3,
     strategy: str = "stable",
-    k_tiling: str = "grid",
     probe: Optional[Probe] = None,
 ) -> AutotuneResult:
     """Pick a :class:`PartitionConfig` for ``csr``, cheapest source first.
@@ -373,7 +304,7 @@ def autotune_partition(
     cache = cache or AutotuneCache()
     key = key or matrix_hash(csr)
     if probe is None:
-        probe = spmm_probe(k=k, strategy=strategy, k_tiling=k_tiling)
+        probe = spmm_probe(k=k, strategy=strategy)
     if search:
         # materialize once: generators must survive both the fingerprint
         # and the measurement loop

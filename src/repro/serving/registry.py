@@ -60,16 +60,11 @@ class MatrixPlan:
     autotune_searched: bool
     strategy: str = "fused"
     interpret: Optional[bool] = None
-    # launch geometry for RHS widths beyond one lane tile: "grid" = the
-    # one-pass 2D k-tiled grid, "loop" = the legacy chunked launches
-    # (an "auto" admission resolves to whichever measured faster)
-    k_tiling: str = "grid"
     # admission-time introspection: static partition-quality metrics
     # (:func:`repro.obs.planview.partition_quality`) and the autotune
-    # decision provenance — which geometry candidates were measured, what
-    # each cost, and how the served k_tiling was chosen.  Deliberately NOT
-    # part of ``_meta()``: these describe the plan, the kernels never see
-    # them.
+    # decision provenance — which geometry candidates were measured and
+    # what each cost.  Deliberately NOT part of ``_meta()``: these describe
+    # the plan, the kernels never see them.
     quality: dict = dataclasses.field(default_factory=dict, repr=False, compare=False)
     provenance: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False
@@ -104,7 +99,6 @@ class MatrixPlan:
             col_block=self.cfg.col_block,
             strategy=self.strategy,
             interpret=self.interpret,
-            k_tiling=self.k_tiling,
         )
 
     def matvec(self, x) -> np.ndarray:
@@ -202,12 +196,6 @@ class MatrixRegistry:
     run in interpret mode — slow, and ~1 ulp dependent on batch width,
     which would break the engine's coalescing-invariance guarantee).
 
-    ``k_tiling`` selects the wide-k launch geometry every plan serves:
-    ``"grid"`` (default) is the one-pass 2D k-tiled grid, ``"loop"`` the
-    legacy chunked launches, and ``"auto"`` measures both per matrix at
-    admission (:func:`repro.serving.autotune.pick_k_tiling`) so each
-    autotuned plan picks the faster contract for its own geometry.
-
     ``metrics`` is the shared :class:`~repro.obs.metrics.MetricRegistry`
     backing this registry's admission counters *and* every
     :class:`~repro.serving.engine.ServingEngine` built over it — one
@@ -245,7 +233,6 @@ class MatrixRegistry:
         autotune_k: int = 8,
         strategy: Optional[str] = None,
         interpret: Optional[bool] = None,
-        k_tiling: str = "grid",
         probe=None,
         metrics: Optional[MetricRegistry] = None,
         hbm_budget_bytes: Optional[int] = None,
@@ -254,17 +241,12 @@ class MatrixRegistry:
             from repro.kernels.ops import default_strategy
 
             strategy = default_strategy()
-        if k_tiling not in ("grid", "loop", "auto"):
-            raise ValueError(
-                f"unknown k_tiling {k_tiling!r} (expected grid, loop or auto)"
-            )
         self.cache = AutotuneCache(cache_dir)
         self.search = search
         self.candidates = candidates
         self.autotune_k = autotune_k
         self.strategy = strategy
         self.interpret = interpret
-        self.k_tiling = k_tiling
         self.probe = probe  # None: steady-state SpMM time (spmm_probe)
         self.metrics = metrics if metrics is not None else MetricRegistry(name="serving")
         self.evictor = (
@@ -320,9 +302,6 @@ class MatrixRegistry:
             # cost is attributable in dumps the same way requests are
             admit_id = mint_trace_id("a")
             sp.annotate(trace_id=admit_id)
-            # the measured search ranks candidates under the served contract;
-            # "auto" ranks under the default grid, then picks per matrix below
-            served_tiling = self.k_tiling if self.k_tiling != "auto" else "grid"
             pinned = cfg is not None
             if pinned:
                 tune_hit, tune_searched = False, False
@@ -336,20 +315,12 @@ class MatrixRegistry:
                     candidates=self.candidates,
                     k=self.autotune_k,
                     strategy=self.strategy,  # rank configs under the served path
-                    k_tiling=served_tiling,
                     probe=self.probe,  # e.g. cg_probe: rank by time-to-tolerance
                 )
                 cfg = tuned.cfg
                 tune_hit, tune_searched = tuned.cache_hit, tuned.searched
                 trials = tuned.trials
                 evaluations, objective_us = tuned.evaluations, tuned.objective_us
-            k_tiling_us = None
-            if self.k_tiling == "auto":
-                from .autotune import measure_k_tilings
-
-                k_tiling_us = measure_k_tilings(csr, cfg, strategy=self.strategy)
-                if k_tiling_us:
-                    served_tiling = min(k_tiling_us, key=k_tiling_us.get)
             t_tiles = time.perf_counter()
             # bucketed tile counts: tenants of about the same size share
             # their compiled kernels
@@ -382,9 +353,6 @@ class MatrixRegistry:
             "evaluations": evaluations,
             "objective_us": objective_us,
             "trials": [dict(t) for t in trials],
-            "k_tiling": served_tiling,
-            "k_tiling_mode": self.k_tiling,
-            "k_tiling_us": k_tiling_us,
         }
 
         plan = MatrixPlan(
@@ -402,7 +370,6 @@ class MatrixRegistry:
             autotune_searched=tune_searched,
             strategy=self.strategy,
             interpret=self.interpret,
-            k_tiling=served_tiling,
             quality=quality,
             provenance=provenance,
             phases_s=phases_s,
@@ -427,7 +394,6 @@ class MatrixRegistry:
             matrix=name,
             nnz=csr.nnz,
             preprocess_s=round(preprocess_s, 6),
-            k_tiling=served_tiling,
             trace_id=admit_id,
         )
         self._charge(plan)
@@ -619,7 +585,6 @@ class MatrixRegistry:
                 "shape": tuple(p.shape),
                 "nnz": p.nnz,
                 "config": dataclasses.asdict(p.cfg),
-                "k_tiling": p.k_tiling,
                 "admissions": p.admissions,
                 "preprocess_s": p.preprocess_s,
                 "autotune_cache_hit": p.autotune_cache_hit,

@@ -27,6 +27,37 @@ def _flight_dumps_tmpdir(tmp_path_factory):
         os.environ["REPRO_FLIGHT_DIR"] = old
 
 
+@pytest.fixture
+def kernel_strategy(monkeypatch):
+    """Resolve a kernel-path case to the ``strategy`` a call passes.
+
+    ``"fused_streamed"`` is ``strategy="fused"`` with the lane gather's X
+    over :data:`~repro.kernels.hbp_spmv.X_BUDGET` (patched to 0 here), so
+    every launch runs the lane gather and each grid step streams one
+    segment of X and carries one tile: the branch a matrix too wide for a
+    resident X takes.  The jitted wrappers are retraced on the way in and
+    out, since the budget is read at trace time.  Any other case is the
+    strategy itself."""
+    import importlib
+
+    import jax
+
+    streamed = []
+
+    def resolve(case: str) -> str:
+        if case != "fused_streamed":
+            return case
+        kernels = importlib.import_module("repro.kernels.hbp_spmv")
+        monkeypatch.setattr(kernels, "X_BUDGET", 0)
+        jax.clear_caches()
+        streamed.append(case)
+        return "fused"
+
+    yield resolve
+    if streamed:
+        jax.clear_caches()
+
+
 def hypothesis_or_shim():
     """(given, settings, st) — real hypothesis, or decorators that skip.
 

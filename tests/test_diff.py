@@ -32,13 +32,13 @@ def _obs_dump(scale_serve: float = 1.0) -> dict:
                 "metrics": [
                     {
                         "name": "attr.compute_s",
-                        "labels": {"matrix": "A", "strategy": "stable", "k_tiling": "grid"},
+                        "labels": {"matrix": "A", "strategy": "stable"},
                         "type": "counter",
                         "value": 0.010 * scale_serve,
                     },
                     {
                         "name": "attr.launches",
-                        "labels": {"matrix": "A", "strategy": "stable", "k_tiling": "grid"},
+                        "labels": {"matrix": "A", "strategy": "stable"},
                         "type": "counter",
                         "value": 5.0,
                     },

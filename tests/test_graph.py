@@ -132,8 +132,9 @@ def test_rmat_graph_is_binary():
 
 
 @pytest.mark.parametrize("op", ["sum", "mean", "max"])
-@pytest.mark.parametrize("strategy", ["fused", "partials", "reference", "stable"])
-def test_aggregate_matches_oracle_small(op, strategy, rng):
+@pytest.mark.parametrize("strategy", ["fused", "fused_streamed", "reference", "stable"])
+def test_aggregate_matches_oracle_small(op, strategy, rng, kernel_strategy):
+    strategy = kernel_strategy(strategy)
     G = power_law_graph(200, 5.0, seed=2)
     X = rng.standard_normal((200, 12)).astype(np.float32)
     tiles = build_tiles(G, PartitionConfig(row_block=64, col_block=64, group=8, lane=8))

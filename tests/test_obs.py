@@ -267,25 +267,9 @@ def test_kernel_launch_counters(obs_on):
     ops.hbp_spmv(tiles, rng.standard_normal(50).astype(np.float32), strategy="stable")
     reg = obs.registry()
     assert reg.value("kernels.launches", op="spmm", strategy="stable",
-                     k_tiling="grid", combine="sum", gather="none", block=0) == 1
+                     combine="sum", gather="none", block=0) == 1
     assert reg.value("kernels.launches", op="spmv", strategy="stable",
-                     k_tiling="grid", combine="sum", gather="none", block=0) == 1
-    assert reg.value("kernels.traversals") == 2  # both k <= LANE_TILE: 1 pass each
-    assert reg.value("kernels.bytes_modeled") > 0
-
-
-def test_stream_passes_model():
-    from repro.kernels.ops import LANE_TILE, stream_passes
-
-    assert stream_passes(1, "stable", "grid") == 1
-    assert stream_passes(LANE_TILE, "fused", "loop") == 1
-    # one-pass geometries at wide k
-    assert stream_passes(4 * LANE_TILE, "partials", "grid") == 1
-    assert stream_passes(4 * LANE_TILE, "reference", "grid") == 1
-    # chunked geometries pay one pass per lane tile
-    assert stream_passes(4 * LANE_TILE, "partials", "loop") == 4
-    assert stream_passes(4 * LANE_TILE, "stable", "grid") == 4
-    assert stream_passes(3 * LANE_TILE + 1, "fused", "loop") == 4
+                     combine="sum", gather="none", block=0) == 1
 
 
 def test_solver_history_streams_into_series(obs_on):

@@ -1,30 +1,27 @@
-"""One-pass kernel grid acceptance: 2D k-tiled SpMM + paired-payload argmax.
+"""One-launch kernel grid acceptance: 2D k-tiled SpMM + paired-payload argmax.
 
 Two contracts are pinned here:
 
-* the ``k_tiling="grid"`` launch geometry (2D (tile, k-tile) Pallas grid /
-  single-traversal jnp paths) agrees with the legacy ``"loop"`` chunked
-  launches and the dense oracle at every k-bucket boundary —
-  k ∈ {1, 127, 128, 129, 256} — on all four strategies, *bitwise* on
-  ``"stable"``;
-* the one-pass paired-payload argmax returns triples identical to the
-  legacy three-monoid-pass recovery and the dense oracle, including the
-  tie-to-lowest-column and empty-row (idx = -1, coeff = 0, y = 0, no
-  gradient) conventions, while traversing the tile stream once instead of
-  three times.
+* one SpMM launch at any width (the 2D (k-tile, tile-block) Pallas grid,
+  the reference's single trace, the stable path's <=128-lane chains)
+  agrees with the dense oracle at every k-bucket boundary —
+  k ∈ {1, 127, 128, 129, 256} — on every kernel path, *bitwise* under the
+  max monoid, and the stable path's bits never depend on the width;
+* the one-pass paired-payload argmax returns the dense oracle's triples,
+  including the tie-to-lowest-column and empty-row (idx = -1, coeff = 0,
+  y = 0, no gradient) conventions, while traversing the tile stream once.
 """
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import PartitionConfig, build_tiles, csr_from_dense
-from repro.kernels import autodiff, ops, ref
+from repro.kernels import ops, ref
 from repro.kernels import hbp_spmm, hbp_spmv
 
 K_BOUNDARIES = [1, 127, 128, 129, 256]
-STRATEGIES = ["fused", "partials", "reference", "stable"]
+STRATEGIES = ["fused", "fused_streamed", "reference", "stable"]
 CFG = PartitionConfig(row_block=32, col_block=64, group=8, lane=8)
 
 
@@ -73,46 +70,50 @@ def _argmax_oracle(dense, X):
     return y, idx, coeff
 
 
-# --- 2D-grid SpMM vs chunk loop vs dense, at every k-bucket boundary -------
+def _max_oracle(dense, X):
+    """Dense max-monoid product over stored entries; empty rows -> 0."""
+    return _argmax_oracle(dense, X)[0]
+
+
+# --- one launch vs dense, at every k-bucket boundary -----------------------
 
 
 @pytest.mark.parametrize("k", K_BOUNDARIES)
 @pytest.mark.parametrize("strategy", STRATEGIES)
-def test_grid_matches_loop_and_dense_at_k_boundaries(problem, k, strategy, rng):
+def test_grid_matches_loop_and_dense_at_k_boundaries(problem, k, strategy, rng,
+                                                     kernel_strategy):
+    """One launch at any width — fused, reference and stable alike —
+    against ``dense @ X``; under ``"stable"`` each column's bits are those
+    of its own width-1 launch."""
     dense, csr, tiles = problem
+    strategy = kernel_strategy(strategy)
     X = rng.standard_normal((90, k)).astype(np.float32)
-    Yg = np.asarray(hbp_spmm(tiles, X, strategy=strategy, interpret=True, k_tiling="grid"))
-    Yl = np.asarray(hbp_spmm(tiles, X, strategy=strategy, interpret=True, k_tiling="loop"))
-    np.testing.assert_allclose(Yg, dense @ X, rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(Yl, dense @ X, rtol=1e-4, atol=1e-4)
+    Y = np.asarray(hbp_spmm(tiles, X, strategy=strategy, interpret=True))
+    np.testing.assert_allclose(Y, dense @ X, rtol=1e-4, atol=1e-4)
     if strategy == "stable":
-        # the serving contract: bits never depend on the launch geometry
-        assert np.array_equal(Yg, Yl)
-    else:
-        np.testing.assert_allclose(Yg, Yl, rtol=1e-5, atol=1e-6)
+        # the serving contract: bits never depend on the launch width
+        for j in {0, k // 2, k - 1}:
+            yj = np.asarray(hbp_spmv(tiles, X[:, j], strategy="stable"))
+            assert np.array_equal(Y[:, j], yj), j
 
 
 @pytest.mark.parametrize("k", K_BOUNDARIES)
 @pytest.mark.parametrize("strategy", STRATEGIES)
-def test_grid_matches_loop_max_combine_bitwise(problem, k, strategy, rng):
-    """max is reassociation-free: grid and loop agree bitwise on EVERY
-    strategy, and empty rows stay exactly 0."""
+def test_grid_matches_loop_max_combine_bitwise(problem, k, strategy, rng, kernel_strategy):
+    """max is reassociation-free: one launch matches the dense max oracle
+    bitwise on EVERY kernel path, and empty rows stay exactly 0."""
     dense, csr, tiles = problem
+    strategy = kernel_strategy(strategy)
     X = rng.standard_normal((90, k)).astype(np.float32)
-    Yg = np.asarray(
-        hbp_spmm(tiles, X, strategy=strategy, combine="max", interpret=True, k_tiling="grid")
-    )
-    Yl = np.asarray(
-        hbp_spmm(tiles, X, strategy=strategy, combine="max", interpret=True, k_tiling="loop")
-    )
-    assert np.array_equal(Yg, Yl)
+    Y = np.asarray(hbp_spmm(tiles, X, strategy=strategy, combine="max", interpret=True))
+    np.testing.assert_array_equal(Y, _max_oracle(dense, X))
     empty = np.asarray(csr.row_nnz() == 0)
-    assert (Yg[empty] == 0).all()
+    assert (Y[empty] == 0).all()
 
 
 def test_stable_bits_invariant_across_k_tiling_and_width(problem, rng):
-    """A column's bits must match the width-1 launch under both tilings at
-    every serving-visible width: the engine pads requests to bucket widths
+    """A column's bits must match the width-1 launch at every
+    serving-visible width: the engine pads requests to bucket widths
     (``hbp_spmm_bucketed``), so that entry is where the bitwise guarantee
     lives — including k boundaries 127/129 that pad across a chunk edge."""
     dense, csr, tiles = problem
@@ -121,42 +122,27 @@ def test_stable_bits_invariant_across_k_tiling_and_width(problem, rng):
         j: np.asarray(hbp_spmv(tiles, X[:, j], strategy="stable"))
         for j in (0, 126, 127, 128, 129, 255)
     }
-    for k_tiling in ops.K_TILINGS:
-        for width in (127, 128, 129, 256):
-            Y = np.asarray(
-                ops.hbp_spmm_bucketed(
-                    tiles, X[:, :width], strategy="stable", k_tiling=k_tiling
-                )
-            )
-            assert Y.shape[1] == width
-            for j, yj in singles.items():
-                if j < width:
-                    assert np.array_equal(Y[:, j], yj), (k_tiling, width, j)
+    for width in (127, 128, 129, 256):
+        Y = np.asarray(ops.hbp_spmm_bucketed(tiles, X[:, :width], strategy="stable"))
+        assert Y.shape[1] == width
+        for j, yj in singles.items():
+            if j < width:
+                assert np.array_equal(Y[:, j], yj), (width, j)
 
 
-def test_unknown_k_tiling_rejected(problem):
-    _, _, tiles = problem
-    with pytest.raises(ValueError, match="k_tiling"):
-        hbp_spmm(tiles, np.ones((90, 2), np.float32), k_tiling="diagonal")
-    with pytest.raises(ValueError, match="k_tiling"):
-        hbp_spmv(tiles, np.ones(90, np.float32), k_tiling="diagonal")
-
-
-# --- one-pass argmax vs three-pass vs dense oracle -------------------------
+# --- one-pass argmax vs the dense oracle -----------------------------------
 
 
 @pytest.mark.parametrize("k", [1, 5, 127, 129])
 def test_argmax_onepass_equals_threepass_and_oracle(problem, k, rng):
+    """The argmax triple is the dense oracle's, and its ``y`` is the
+    max-combine SpMM's bits on every kernel path."""
     dense, csr, tiles = problem
     X = rng.standard_normal((90, k)).astype(np.float32)
-    one = tuple(np.asarray(a) for a in ops.hbp_spmm_argmax(tiles, X, passes=1))
-    three = tuple(np.asarray(a) for a in ops.hbp_spmm_argmax(tiles, X, passes=3))
-    want = _argmax_oracle(dense, X)
-    for got1, got3, w in zip(one, three, want):
-        assert np.array_equal(got1, got3)
-        np.testing.assert_array_equal(got1, w)
-    # y must also match the max-combine SpMM bitwise, on every strategy
-    for strategy in STRATEGIES:
+    one = tuple(np.asarray(a) for a in ops.hbp_spmm_argmax(tiles, X))
+    for got, w in zip(one, _argmax_oracle(dense, X)):
+        np.testing.assert_array_equal(got, w)
+    for strategy in ("fused", "reference", "stable"):
         Ym = np.asarray(
             hbp_spmm(tiles, X, strategy=strategy, combine="max", interpret=True)
         )
@@ -165,10 +151,8 @@ def test_argmax_onepass_equals_threepass_and_oracle(problem, k, rng):
 
 def test_argmax_tie_breaks_to_lowest_column_onepass():
     dense, csr, tiles, X = _tied_problem()
-    y1, i1, c1 = (np.asarray(a) for a in ops.hbp_spmm_argmax(tiles, X, passes=1))
-    y3, i3, c3 = (np.asarray(a) for a in ops.hbp_spmm_argmax(tiles, X, passes=3))
+    y1, i1, c1 = (np.asarray(a) for a in ops.hbp_spmm_argmax(tiles, X))
     yo, io, co = _argmax_oracle(dense, X)
-    np.testing.assert_array_equal(i1, i3)
     np.testing.assert_array_equal(i1, io)  # ties -> lowest column, always
     np.testing.assert_array_equal(y1, yo)
     np.testing.assert_array_equal(c1, co)
@@ -179,23 +163,14 @@ def test_argmax_empty_rows_convention(problem, rng):
     X = rng.standard_normal((90, 4)).astype(np.float32)
     empty = np.asarray(csr.row_nnz() == 0)
     assert empty.any()
-    for passes in (1, 3):
-        y, idx, coeff = (
-            np.asarray(a) for a in ops.hbp_spmm_argmax(tiles, X, passes=passes)
-        )
-        assert (y[empty] == 0).all()
-        assert (idx[empty] == -1).all()
-        assert (coeff[empty] == 0).all()
-
-
-def test_argmax_rejects_bad_passes(problem):
-    _, _, tiles = problem
-    with pytest.raises(ValueError, match="passes"):
-        ops.hbp_spmm_argmax(tiles, np.ones((90, 2), np.float32), passes=2)
+    y, idx, coeff = (np.asarray(a) for a in ops.hbp_spmm_argmax(tiles, X))
+    assert (y[empty] == 0).all()
+    assert (idx[empty] == -1).all()
+    assert (coeff[empty] == 0).all()
 
 
 def test_onepass_traverses_tile_stream_once(problem, rng):
-    """The point of the redesign: <= 1 traversal, vs 3 for the legacy path."""
+    """The paired payload walks the tile stream once."""
     _, _, tiles = problem
     dt = ops.device_tiles(tiles)
     xb = ops.blocked_matrix(jnp.asarray(rng.standard_normal((90, 4)), jnp.float32), 64)
@@ -204,63 +179,4 @@ def test_onepass_traverses_tile_stream_once(problem, rng):
             dt.rowgroup, dt.colblock, dt.data, dt.cols, xb,
             n_rowgroups=tiles.n_rowgroups,
         )
-    with ref.count_traversals() as three:
-        ref.hbp_spmm_hashed_argmax(
-            dt.rowgroup, dt.colblock, dt.data, dt.cols, xb,
-            n_rowgroups=tiles.n_rowgroups,
-        )
-    assert one[0] <= 1
-    assert three[0] == 3
-
-
-def test_argmax_diff_gradients_match_across_passes(problem, rng):
-    """The max-aggregation VJP routes identical gradients under either
-    forward (same winners, same coefficients)."""
-    _, csr, tiles = problem
-    dt = ops.device_tiles(tiles)
-    meta = dict(n_rowgroups=tiles.n_rowgroups, n_rows=tiles.shape[0], col_block=64)
-    x = jnp.asarray(rng.standard_normal((90, 3)), jnp.float32)
-    g = jnp.asarray(rng.standard_normal((70, 3)), jnp.float32)
-    grads = {}
-    for passes in (1, 3):
-        f = autodiff.argmax_spmm_diff(dt, passes=passes, **meta)
-        y, vjp = jax.vjp(f, x)
-        grads[passes] = (np.asarray(y), np.asarray(vjp(g)[0]))
-    np.testing.assert_array_equal(grads[1][0], grads[3][0])
-    np.testing.assert_array_equal(grads[1][1], grads[3][1])
-
-
-# --- serving plumbing: plans carry and serve the picked k_tiling -----------
-
-
-def test_registry_plan_carries_k_tiling(tmp_path, rng):
-    from repro.serving import MatrixRegistry
-
-    dense = (rng.standard_normal((40, 40)) * (rng.random((40, 40)) < 0.2)).astype(
-        np.float32
-    )
-    csr = csr_from_dense(dense)
-    X = rng.standard_normal((40, 160)).astype(np.float32)
-    results = {}
-    for k_tiling in ("grid", "loop", "auto"):
-        reg = MatrixRegistry(
-            search=False, cache_dir=tmp_path / k_tiling, k_tiling=k_tiling
-        )
-        plan = reg.admit(csr, f"m_{k_tiling}")
-        if k_tiling == "auto":
-            assert plan.k_tiling in ("grid", "loop")  # measured pick
-        else:
-            assert plan.k_tiling == k_tiling
-        assert plan._meta()["k_tiling"] == plan.k_tiling
-        assert reg.stats()[f"m_{k_tiling}"]["k_tiling"] == plan.k_tiling
-        results[k_tiling] = np.asarray(plan.matmat(X, bucketed=False))
-        np.testing.assert_allclose(results[k_tiling], dense @ X, rtol=1e-4, atol=1e-4)
-    # the default off-TPU strategy is "stable": bits identical either way
-    assert np.array_equal(results["grid"], results["loop"])
-
-
-def test_registry_rejects_unknown_k_tiling():
-    from repro.serving import MatrixRegistry
-
-    with pytest.raises(ValueError, match="k_tiling"):
-        MatrixRegistry(k_tiling="spiral")
+    assert one[0] == 1

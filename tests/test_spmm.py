@@ -34,8 +34,10 @@ CASES = [
 
 
 @pytest.mark.parametrize("m,k,density,nrhs", CASES)
-@pytest.mark.parametrize("strategy", ["fused", "partials", "reference"])
-def test_hbp_spmm_strategies_match_dense(m, k, density, nrhs, strategy, rng):
+@pytest.mark.parametrize("strategy", ["fused", "fused_streamed", "reference"])
+def test_hbp_spmm_strategies_match_dense(m, k, density, nrhs, strategy, rng,
+                                         kernel_strategy):
+    strategy = kernel_strategy(strategy)
     dense = (rng.standard_normal((m, k)) * (rng.random((m, k)) < density)).astype(
         np.float32
     )
@@ -47,10 +49,11 @@ def test_hbp_spmm_strategies_match_dense(m, k, density, nrhs, strategy, rng):
     np.testing.assert_allclose(Y, dense @ X, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("strategy", ["fused", "partials"])
-def test_spmm_equals_k_spmv_calls(strategy, rng):
+@pytest.mark.parametrize("strategy", ["fused", "fused_streamed"])
+def test_spmm_equals_k_spmv_calls(strategy, rng, kernel_strategy):
     """The acceptance property: one SpMM launch == k independent SpMV
     launches, column for column."""
+    strategy = kernel_strategy(strategy)
     dense = (rng.standard_normal((150, 220)) * (rng.random((150, 220)) < 0.07)).astype(
         np.float32
     )
@@ -145,11 +148,13 @@ def _max_oracle(dense: np.ndarray, X: np.ndarray) -> np.ndarray:
 
 
 @pytest.mark.parametrize("k", [130, 256])
-@pytest.mark.parametrize("strategy", ["fused", "partials", "reference", "stable"])
-def test_lane_tiled_wide_k_matches_dense(k, strategy, rng):
-    """k > LANE_TILE tiles over sequential <=128-lane chunks inside
-    _hbp_spmm_device instead of spilling the lane dimension."""
+@pytest.mark.parametrize("strategy", ["fused", "fused_streamed", "reference", "stable"])
+def test_lane_tiled_wide_k_matches_dense(k, strategy, rng, kernel_strategy):
+    """k > LANE_TILE runs the kernels' 2D k-tiled grid (the stable path's
+    <=128-lane chunks) instead of spilling the lane dimension."""
     from repro.kernels.ops import LANE_TILE
+
+    strategy = kernel_strategy(strategy)
 
     assert k > LANE_TILE
     dense = (rng.standard_normal((70, 90)) * (rng.random((70, 90)) < 0.12)).astype(
@@ -186,8 +191,9 @@ def test_stable_strategy_invariant_across_lane_tiles(rng):
 
 
 @pytest.mark.parametrize("k", [1, 5, 16, 256])
-@pytest.mark.parametrize("strategy", ["fused", "partials", "reference", "stable"])
-def test_hbp_spmm_max_matches_oracle(k, strategy, rng):
+@pytest.mark.parametrize("strategy", ["fused", "fused_streamed", "reference", "stable"])
+def test_hbp_spmm_max_matches_oracle(k, strategy, rng, kernel_strategy):
+    strategy = kernel_strategy(strategy)
     dense = (rng.standard_normal((60, 70)) * (rng.random((60, 70)) < 0.12)).astype(
         np.float32
     )
@@ -204,11 +210,12 @@ def test_hbp_spmm_max_matches_oracle(k, strategy, rng):
     np.testing.assert_array_equal(Y, _max_oracle(dense, X))
 
 
-@pytest.mark.parametrize("strategy", ["fused", "partials", "stable"])
-def test_max_identity_never_leaks_on_empty_rows(strategy, rng):
+@pytest.mark.parametrize("strategy", ["fused", "fused_streamed", "stable"])
+def test_max_identity_never_leaks_on_empty_rows(strategy, rng, kernel_strategy):
     """Satellite acceptance: with all-negative features, empty rows must
     yield exactly 0 — the -inf identity of the max monoid (and the 0 of a
     padded slot's product) must never surface."""
+    strategy = kernel_strategy(strategy)
     dense = np.zeros((48, 50), np.float32)
     keep = rng.random((48, 50)) < 0.1
     keep[::5] = False  # every 5th row fully empty
@@ -270,6 +277,23 @@ def test_unknown_combine_rejected(rng):
         hbp_spmm(tiles, np.ones((8, 2), np.float32), combine="min", interpret=True)
 
 
+@pytest.mark.parametrize("call", [
+    lambda t: hbp_spmv(t, np.ones(8, np.float32), strategy="partials", interpret=True),
+    lambda t: hbp_spmm(t, np.ones((8, 2), np.float32), strategy="partials", interpret=True),
+    lambda t: hbp_spmm(t, np.ones((8, 2), np.float32), strategy="partials", combine="max",
+                       interpret=True),
+], ids=["spmv", "spmm", "spmm_max"])
+def test_unknown_strategy_rejected(call):
+    """The kernel paths are ``fused``, ``reference`` and ``stable``; any
+    other name, the retired two-phase ``partials`` among them, raises."""
+    tiles = build_tiles(
+        csr_from_dense(np.eye(8, dtype=np.float32)),
+        PartitionConfig(row_block=8, col_block=8, group=4, lane=4),
+    )
+    with pytest.raises(ValueError, match="unknown strategy"):
+        call(tiles)
+
+
 # --- end-to-end equivalence across the scaled Table-I structural families ---
 
 FAMILIES = {
@@ -312,8 +336,9 @@ def test_end_to_end_equivalence(family):
 
 @pytest.mark.parametrize("k", [1, 3, 256])
 @pytest.mark.parametrize("combine", ["sum", "max"])
-@pytest.mark.parametrize("strategy", ["fused", "partials"])
-def test_launches_split_into_tile_chunks_match_dense(strategy, combine, k, rng, monkeypatch):
+@pytest.mark.parametrize("strategy", ["fused", "fused_streamed"])
+def test_launches_split_into_tile_chunks_match_dense(strategy, combine, k, rng, monkeypatch,
+                                                     kernel_strategy):
     """A tile stream longer than one launch's SMEM budget runs as several
     launches over one output buffer; row-group runs that straddle a launch
     boundary continue where the previous launch left off."""
@@ -321,6 +346,7 @@ def test_launches_split_into_tile_chunks_match_dense(strategy, combine, k, rng, 
 
     import jax
 
+    strategy = kernel_strategy(strategy)
     kernels = importlib.import_module("repro.kernels.hbp_spmv")
     monkeypatch.setattr(kernels, "TILE_CHUNK", 5)
     # fused grid steps of 5 tiles: each launch is one step over several row groups
@@ -462,7 +488,7 @@ def test_row_gather_matches_reference(lane, k, combine, split, rng, monkeypatch)
 
 BLOCKED_CASES = [
     (body, k, combine, split)
-    for body in ("lane", "row") for k in (1, 4, 8, 16, 128, 256)
+    for body in ("lane", "row", "streamed") for k in (1, 4, 8, 16, 128, 256)
     for combine in ("sum", "max") for split in (False, True)
 ]
 
@@ -473,8 +499,10 @@ def test_blocked_grid_matches_reference(body, k, combine, split, rng, monkeypatc
     The 47 tiles are no whole number of blocks; a block spans several row
     groups, and one row group's run of 27 tiles spans several blocks (or,
     under the lane gather, one short block holds all 47); two row groups
-    have no tile, so they keep the identity.  With ``split``:
-    blocks of 3, launches of 12 tiles and a window of 2 row groups, so runs
+    have no tile, so they keep the identity.  ``streamed`` is the lane
+    gather with X over its VMEM budget: a step streams one segment of X
+    and carries one tile.  With ``split``: blocks of 3 (the streamed body
+    keeps 1), launches of 12 tiles and a window of 2 row groups, so runs
     cross launch boundaries and windows: a launch that did not continue
     from what the previous one wrote back would fail here."""
     import importlib
@@ -484,8 +512,12 @@ def test_blocked_grid_matches_reference(body, k, combine, split, rng, monkeypatc
     kernels = importlib.import_module("repro.kernels.hbp_spmv")
     dense, tiles = _row_gather_problem(rng, 8)
     dt = ops.device_tiles(tiles)
-    block = 3 if split else {"lane": kernels.BLOCK_LANE, "row": kernels.BLOCK_ROW}[body]
-    assert tiles.n_tiles % block != 0
+    if body == "streamed":
+        monkeypatch.setattr(kernels, "X_BUDGET", 0)
+        block = 1
+    else:
+        block = 3 if split else {"lane": kernels.BLOCK_LANE, "row": kernels.BLOCK_ROW}[body]
+        assert tiles.n_tiles % block != 0
     if block < tiles.n_tiles:  # else one short block holds every tile
         runs = np.diff(np.append(np.flatnonzero(tiles.first), tiles.n_tiles))
         assert (runs > block).any()  # a run spans blocks
@@ -498,7 +530,9 @@ def test_blocked_grid_matches_reference(body, k, combine, split, rng, monkeypatc
     xb = ops.blocked_matrix(jnp.asarray(X), tiles.cfg.col_block)
     kw = dict(n_rowgroups=tiles.n_rowgroups, combine=combine, interpret=True,
               name="hbp_spmm_fused", block=block)
-    if body == "lane":
+    if body == "streamed":
+        assert kernels.tiles_per_step(k, xb.shape[0] * xb.shape[1], tiles.cfg.col_block) == 1
+    if body in ("lane", "streamed"):
         hashed = kernels._fused(dt.rowgroup, dt.colblock, dt.data, dt.cols, xb, **kw)
     else:
         hashed = kernels._fused_rows(dt.rowgroup, dt.colblock, dt.width, dt.data, dt.cols,
@@ -537,16 +571,17 @@ def test_gather_body_choice(k, x_rows, body):
     ("spmm", "fused", 8, "row", "BLOCK_ROW"),
     ("spmm", "fused", 4, "lane", "BLOCK_LANE"),
     ("spmv", "fused", 1, "lane", "BLOCK_LANE"),
-    ("spmm", "partials", 8, "lane", 1),
+    ("spmm", "fused_streamed", 8, "lane", 1),
     ("spmm", "stable", 8, "none", 0),
 ])
-def test_launch_counter_labels_gather(op, strategy, k, gather, block, rng):
+def test_launch_counter_labels_gather(op, strategy, k, gather, block, rng, kernel_strategy):
     """``kernels.launches`` names the tile body each launch runs and the
     tiles each of its grid steps carries."""
     import importlib
 
     from repro import obs
 
+    strategy = kernel_strategy(strategy)
     if isinstance(block, str):
         block = getattr(importlib.import_module("repro.kernels.hbp_spmv"), block)
         assert block > 1
@@ -564,8 +599,8 @@ def test_launch_counter_labels_gather(op, strategy, k, gather, block, rng):
         else:
             hbp_spmm(tiles, X, strategy=strategy, interpret=True)
         assert obs.registry().value(
-            "kernels.launches", op=op, strategy=strategy, k_tiling="grid", combine="sum",
-            gather=gather, block=block,
+            "kernels.launches", op=op, strategy=strategy, combine="sum", gather=gather,
+            block=block,
         ) == 1
     finally:
         obs.disable()

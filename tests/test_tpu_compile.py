@@ -20,12 +20,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 from repro.kernels.hbp_spmv import (
+    LANE_TILE,
+    X_BUDGET,
+    _lane_x_bytes,
     hbp_spmm_fused,
     hbp_spmm_fused_max,
-    hbp_spmm_partials,
-    hbp_spmm_partials_max,
     hbp_spmv_fused,
-    hbp_spmv_partials,
+    tiles_per_step,
 )
 
 T = 4096  # tiles per launch
@@ -67,11 +68,20 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _shapes(sharding, lane, k, col_block=4096, tiles=T):
+def _streamed_col_blocks(k, col_block):
+    """Fewest column blocks whose X the lane gather cannot hold resident:
+    its launches stream one segment of X a grid step, one tile a step."""
+    per_block = _lane_x_bytes(1, min(k, LANE_TILE), -(-col_block // LANE_TILE) * LANE_TILE)
+    n_cb = X_BUDGET // per_block + 1
+    assert tiles_per_step(k, n_cb * col_block, col_block) == 1
+    return n_cb
+
+
+def _shapes(sharding, lane, k, col_block=4096, tiles=T, n_col_blocks=N_COL_BLOCKS):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    x = (N_COL_BLOCKS, col_block) if k == 1 else (N_COL_BLOCKS, col_block, k)
+    x = (n_col_blocks, col_block) if k == 1 else (n_col_blocks, col_block, k)
     return dict(
         scalars=s((tiles,), jnp.int32),
         data=s((tiles, GROUP, lane), jnp.float32),
@@ -90,54 +100,58 @@ def _fused(fn, spmm=False):
     return call
 
 
-def _partials(fn):
-    def call(a):
-        return fn.lower(a["scalars"], a["data"], a["cols"], a["x"])
-    return call
-
-
 LAUNCHES = {
     "spmv_fused": _fused(hbp_spmv_fused),
-    "spmv_partials": _partials(hbp_spmv_partials),
     "spmm_fused": _fused(hbp_spmm_fused, spmm=True),
-    "spmm_partials": _partials(hbp_spmm_partials),
     "spmm_fused_max": _fused(hbp_spmm_fused_max, spmm=True),
-    "spmm_partials_max": _partials(hbp_spmm_partials_max),
 }
 
-# (kernel, lane, k, col_block): lanes 8 and 128 (the heuristic's narrowest
-# and widest) on every kernel, the lanes between once each, one RHS column,
-# one 8-row group, and the 2D k-grid (k=256)
+# (kernel, lane, k, col_block, streamed): lanes 8 and 128 (the heuristic's
+# narrowest and widest) on every kernel, the lanes between once each, one
+# RHS column, one 8-row group, and the 2D k-grid (k=256); ``streamed``
+# widens X past the lane gather's VMEM budget, so each grid step streams
+# one segment of X
 CASES = [
-    ("spmv_fused", 8, 1, 4096),
-    ("spmv_fused", 128, 1, 4096),
-    ("spmv_fused", 16, 1, 1024),
-    ("spmm_partials", 64, 128, 1024),
-    ("spmv_partials", 8, 1, 4096),
-    ("spmv_partials", 128, 1, 4096),
-    ("spmm_fused", 8, 8, 4096),
-    ("spmm_fused", 128, 256, 4096),
-    ("spmm_fused", 32, 16, 1024),
-    ("spmm_partials", 8, 256, 4096),
-    ("spmm_partials", 128, 8, 4096),
-    ("spmm_fused_max", 8, 8, 4096),
-    ("spmm_fused_max", 128, 256, 4096),
-    ("spmm_partials_max", 8, 256, 4096),
-    ("spmm_partials_max", 128, 8, 4096),
+    ("spmv_fused", 8, 1, 4096, False),
+    ("spmv_fused", 128, 1, 4096, False),
+    ("spmv_fused", 16, 1, 1024, False),
+    ("spmm_fused", 64, 128, 1024, True),
+    ("spmv_fused", 8, 1, 4096, True),
+    ("spmv_fused", 128, 1, 4096, True),
+    ("spmm_fused", 8, 8, 4096, False),
+    ("spmm_fused", 128, 256, 4096, False),
+    ("spmm_fused", 32, 16, 1024, False),
+    ("spmm_fused", 8, 256, 4096, True),
+    ("spmm_fused", 128, 8, 4096, True),
+    ("spmm_fused_max", 8, 8, 4096, False),
+    ("spmm_fused_max", 128, 256, 4096, False),
+    ("spmm_fused_max", 8, 256, 4096, True),
+    ("spmm_fused_max", 128, 8, 4096, True),
 ]
 
 
-@pytest.mark.parametrize("kernel,lane,k,col_block", CASES)
-def test_kernel_compiles_for_v5e(one_chip, kernel, lane, k, col_block):
-    compiled = LAUNCHES[kernel](_shapes(one_chip, lane, k, col_block)).compile()
+def _case_id(case):
+    kernel, lane, k, col_block, streamed = case
+    return f"{kernel}{'_streamed' if streamed else ''}-{lane}-{k}-{col_block}"
+
+
+@pytest.mark.parametrize("kernel,lane,k,col_block,streamed", CASES, ids=map(_case_id, CASES))
+def test_kernel_compiles_for_v5e(one_chip, kernel, lane, k, col_block, streamed):
+    n_cb = _streamed_col_blocks(k, col_block) if streamed else N_COL_BLOCKS
+    a = _shapes(one_chip, lane, k, col_block, n_col_blocks=n_cb)
+    compiled = LAUNCHES[kernel](a).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("kernel,k", [("spmv_fused", 1), ("spmm_partials_max", 8)])
-def test_published_size_stream_fits_smem(one_chip, kernel, k):
+@pytest.mark.parametrize("kernel,k,streamed", [("spmv_fused", 1, False),
+                                               ("spmm_fused_max", 8, True)],
+                         ids=["spmv_fused-1", "spmm_fused_max_streamed-8"])
+def test_published_size_stream_fits_smem(one_chip, kernel, k, streamed):
     """A published-size tile stream: the per-tile scalars of one launch
     must fit the chip's 1 MiB SMEM, so the stream runs as several launches."""
-    compiled = LAUNCHES[kernel](_shapes(one_chip, 8, k, tiles=T_KRON16)).compile()
+    n_cb = _streamed_col_blocks(k, 4096) if streamed else N_COL_BLOCKS
+    a = _shapes(one_chip, 8, k, tiles=T_KRON16, n_col_blocks=n_cb)
+    compiled = LAUNCHES[kernel](a).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
