@@ -5,10 +5,19 @@ Runs on a TPU only: the lane gather (``hbp_spmv._fused``) and the row
 gather (``hbp_spmv._fused_rows``) over the same staged tiles of the
 benchmark's ``kron16`` configuration (its pinned geometry), one sum-monoid
 launch set per (body, k, B), and prints one JSON line each: the median of
-``--reps`` timed calls, microseconds per tile, and the largest error
-against a float64 CSR product, relative to the largest ``|A||x|``.
+``--reps`` timed calls, microseconds per tile, the body's visits per tile
+next to them, and the largest error against a float64 CSR product,
+relative to the largest ``|A||x|``.  Lane lines count 128-column chunk
+gathers (``chunk_visits_per_tile``: every chunk of the segment for every
+RHS column) and the share of them that reach a chunk holding a live slot
+(``hit_chunk_visits_per_tile``); row lines count X row loads
+(``slot_visits_per_tile``: ``group`` rows times the tile's live width, for
+every k-tile).
 ``K_ROW`` is the narrowest bucket at which the row body is the faster
 one; ``BLOCK_LANE`` and ``BLOCK_ROW`` are the fastest ``B`` of each body.
+Lane lines also run at ``B`` = ``BLOCK_LANE`` with every count of tiles
+a loop iteration computes in ``INTERLEAVES`` (``interleave``; the others
+run ``INTERLEAVE_LANE``, its fastest).
 Last, for each ``B``, the row body with every tile's width set to 0: the
 launches' fixed cost without the slot loop.
 
@@ -29,6 +38,7 @@ import numpy as np
 
 ROOT = Path(__file__).resolve().parent.parent
 BLOCKS = {"lane": (1, 32, 64, 128, 256, 512), "row": (1, 4, 8, 16, 32)}
+INTERLEAVES = (1, 2, 3, 4)
 
 
 def _timed(fn, args, reps):
@@ -75,11 +85,25 @@ def main(argv=None) -> int:
     kw = dict(n_rowgroups=tiles.n_rowgroups, combine="sum", interpret=False,
               name="hbp_spmm_fused")
     bodies = {
-        "lane": lambda b: jax.jit(functools.partial(K._fused, block=b, **kw)),
-        "row": lambda b: jax.jit(functools.partial(K._fused_rows, block=b, **kw)),
+        "lane": lambda b, u: jax.jit(functools.partial(K._fused, block=b, interleave=u,
+                                                       **kw)),
+        "row": lambda b, u: jax.jit(functools.partial(K._fused_rows, block=b, **kw)),
     }
+    runs = [(body, b, K.INTERLEAVE_LANE) for body in bodies for b in BLOCKS[body]]
+    runs += [("lane", K.BLOCK_LANE, u) for u in INTERLEAVES if u != K.INTERLEAVE_LANE]
     rng = np.random.default_rng(args.seed)
     lines = []
+    n_chunks = -(-col_block // K.LANE_TILE)
+    live = np.nonzero(tiles.data)
+    hit_chunks = np.unique(live[0] * n_chunks + tiles.cols[live] // K.LANE_TILE).size / T
+    slots = tiles.cfg.group * float(np.asarray(dt.width).mean())
+
+    def visits(body, k):
+        kp = K._padded_k(k)
+        if body == "row":
+            return dict(slot_visits_per_tile=slots * K._k_grid(kp)[1])
+        return dict(chunk_visits_per_tile=n_chunks * kp,
+                    hit_chunk_visits_per_tile=hit_chunks * kp)
 
     def emit(**rec):
         rec.update(device=jax.devices()[0].device_kind, tiles=T)
@@ -91,20 +115,22 @@ def main(argv=None) -> int:
         xh = rng.standard_normal((c.shape[1], k), np.float32)
         want, scale = csr @ xh, abs(csr) @ np.abs(xh)
         x = ops.blocked_matrix(jnp.asarray(xh), col_block)
-        for body, make in bodies.items():
-            for b in BLOCKS[body]:
-                width = (dt.width,) if body == "row" else ()
-                t, y = _timed(make(b), (*head, *width, dt.data, dt.cols, x), args.reps)
-                y = np.asarray(ref.unpermute(y[..., :k], dt.perm, n_rows))
-                err = float((np.abs(y - want) / np.maximum(scale, 1e-30)).max())
-                emit(body=body, k=k, block=b, call_ms=t * 1e3, us_per_tile=t / T * 1e6,
-                     chosen=K.gather_body(k, x.shape[0] * col_block) == body
-                     and K.tiles_per_step(k, x.shape[0] * col_block, col_block) == b,
-                     rel_err=err)
+        for body, b, u in runs:
+            width = (dt.width,) if body == "row" else ()
+            t, y = _timed(bodies[body](b, u), (*head, *width, dt.data, dt.cols, x), args.reps)
+            y = np.asarray(ref.unpermute(y[..., :k], dt.perm, n_rows))
+            err = float((np.abs(y - want) / np.maximum(scale, 1e-30)).max())
+            lane = dict(interleave=u) if body == "lane" else {}
+            emit(body=body, k=k, block=b, **lane, call_ms=t * 1e3, us_per_tile=t / T * 1e6,
+                 **visits(body, k),
+                 chosen=K.gather_body(k, x.shape[0] * col_block) == body
+                 and K.tiles_per_step(k, x.shape[0] * col_block, col_block) == b
+                 and u == K.INTERLEAVE_LANE,
+                 rel_err=err)
     x = ops.blocked_matrix(jnp.ones((c.shape[1], 8), jnp.float32), col_block)
     for b in BLOCKS["row"]:
-        t0, _ = _timed(bodies["row"](b), (*head, jnp.zeros_like(dt.width), dt.data, dt.cols, x),
-                       args.reps)
+        t0, _ = _timed(bodies["row"](b, None),
+                       (*head, jnp.zeros_like(dt.width), dt.data, dt.cols, x), args.reps)
         emit(body="row_width0", k=8, block=b, call_ms=t0 * 1e3, us_per_tile=t0 / T * 1e6,
              slots=int(tiles.cfg.group * np.asarray(dt.width).sum()))
     if args.out:

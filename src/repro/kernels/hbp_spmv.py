@@ -19,7 +19,10 @@ the three kernels apart.
 cdiv(tiles, B))``: each step carries a block of ``B`` consecutive tiles
 and walks them in a ``fori_loop``; the last block may be short, and the
 loop stops at the launch's tile count (nothing is padded in HBM).  Each
-tile reads its row group and column block from scalar prefetch.  ``B`` is
+tile reads its row group and column block from scalar prefetch.  Under
+the lane gather one iteration computes :data:`INTERLEAVE_LANE` tiles
+before it folds any of them into the output, so their latency chains
+overlap; the folds keep the stream's order.  ``B`` is
 a constant per body (:func:`tiles_per_step`): :data:`BLOCK_LANE` tiles
 under the lane gather, :data:`BLOCK_ROW` under the row gather, 1 where the
 lane gather's X cannot be resident.  A grid step has a fixed cost of about
@@ -93,11 +96,12 @@ launch: the resident X, ``n_col_blocks × 8 × 16 KiB`` for a k-tile of up
 to 8 (2 MiB on 65,536 columns), ``n_col_blocks × 2 MiB`` at ``k_tile`` =
 128, one buffer; the window, 4 KiB a row group (``[8, k_tile]`` takes a
 whole (8, 128) tile), :data:`WINDOW_BYTES` at most; data and cols ``B ×
-4 KiB`` each, double-buffered; the id scratch 4 KiB.  Row gather: the
-resident X, ``n_col_blocks * col_block × 128 lanes × 4 B`` whatever
-``k_tile`` (32 MiB for 65,536 columns), one buffer, and the window; data
-and cols sit in SMEM, ``B × 4 KiB`` each, double-buffered, beside the
-three scalar-prefetch arrays (3 × 128 KiB at :data:`TILE_CHUNK` tiles).
+4 KiB`` each, double-buffered; the id scratch 4 KiB for each tile of a
+loop iteration.  Row gather: the resident X, ``n_col_blocks * col_block ×
+128 lanes × 4 B`` whatever ``k_tile`` (32 MiB for 65,536 columns), one
+buffer, and the window; data and cols sit in SMEM, ``B × 4 KiB`` each,
+double-buffered, beside the three scalar-prefetch arrays (3 × 128 KiB at
+:data:`TILE_CHUNK` tiles).
 Each launch asks for X, the window and its VMEM tile blocks plus
 :data:`VMEM_SLACK`.  The compile tests (``tests/test_tpu_compile.py``)
 check both bodies against a described v5e at ``kron16``'s size.
@@ -149,6 +153,12 @@ K_ROW = 8
 # beside the scalar prefetch, which bounds it: 32 fits a v5e, 64 does not.
 BLOCK_LANE = 128
 BLOCK_ROW = 16
+# Tiles one iteration of the lane gather's tile loop computes before it
+# folds them: a k=1 tile is bound by the latency of its own chain, which
+# independent tiles overlap (on a v5e over kron16's tiles at B = 128, a
+# k=1 call takes 22.2 / 17.5 / 16.4 / 15.2 ms at 1 / 2 / 3 / 4; 2 is the
+# count measured end to end; PERF.md).
+INTERLEAVE_LANE = 2
 # Most VMEM a resident X k-tile may take (the v5e has 128 MiB): wider X
 # keeps the lane gather, and under the lane gather streams one segment a
 # step, one tile a step.
@@ -237,10 +247,13 @@ def _tile_columns(data_ref, cols_ref, x_ref, idx_ref, *, combine: str, tile, seg
 
 
 def _blocked(rowgroup_ref, y_ref, acc_ref, cur_ref, tile_part, *, n, block,
-             n_rowgroups, combine):
+             n_rowgroups, combine, interleave=1):
     """One grid step of a fused launch: its block of up to ``block`` tiles
     (``n`` tiles in the launch; the last block may be short), each folded
     into row ``rowgroup[t]`` of the output window in ``acc_ref``.
+    ``tile_part(i, t, u)`` computes tile ``t``, the block's ``i``-th, as
+    the ``u``-th of an iteration's ``interleave`` tiles; a block's last
+    ``cnt % interleave`` tiles run one an iteration.
 
     The window holds ``window`` row groups of the k-tile in VMEM; window
     ``w`` covers row groups ``[w * window, (w + 1) * window)``, its copy
@@ -265,8 +278,8 @@ def _blocked(rowgroup_ref, y_ref, acc_ref, cur_ref, tile_part, *, n, block,
         cur_ref[0] = rowgroup_ref[0] // window
         pltpu.sync_copy(hbm(cur_ref[0])[0], acc_ref)
 
-    def tile(i, carry):
-        t = b * block + i
+    def fold(t, part):
+        """Fold ``part()``, tile ``t``'s rows, into the window."""
         rg = rowgroup_ref[t]
         w = rg // window
 
@@ -277,15 +290,31 @@ def _blocked(rowgroup_ref, y_ref, acc_ref, cur_ref, tile_part, *, n, block,
             cur_ref[0] = w
 
         r = rg - hbm(w)[1]
-        part = tile_part(i, t)
+        part = part()
         cols = slice(None, part.shape[1])
         if combine == "sum":
             acc_ref[r, :, cols] += part
         else:
             acc_ref[r, :, cols] = jnp.maximum(acc_ref[r, :, cols], part)
-        return carry
 
-    lax.fori_loop(0, jnp.minimum(block, n - b * block), tile, 0)
+    def tiles(first, count):
+        def body(p, carry):
+            i = first + p * count
+            t = b * block + i
+            if count == 1:
+                fold(t, lambda: tile_part(i, t, 0))
+                return carry
+            parts = [tile_part(i + u, t + u, u) for u in range(count)]
+            for u, part in enumerate(parts):
+                fold(t + u, lambda part=part: part)
+            return carry
+        return body
+
+    cnt = jnp.minimum(block, n - b * block)
+    lax.fori_loop(0, cnt // interleave, tiles(0, interleave), 0)
+    if interleave > 1:
+        rest = cnt // interleave * interleave
+        lax.fori_loop(0, cnt - rest, tiles(rest, 1), 0)
 
     @pl.when(b == pl.num_programs(1) - 1)
     def _store():
@@ -295,15 +324,18 @@ def _blocked(rowgroup_ref, y_ref, acc_ref, cur_ref, tile_part, *, n, block,
 def _lane_kernel(rowgroup_ref, colblock_ref, data_ref, cols_ref, x_ref, y_in_ref, y_ref,
                  acc_ref, cur_ref, idx_ref, *, resident, **kw):
     """The lane gather body: each tile against its x segment, read from the
-    resident X at ``colblock[t]`` (or, streamed, the step's one segment)."""
+    resident X at ``colblock[t]`` (or, streamed, the step's one segment),
+    :data:`INTERLEAVE_LANE` tiles an iteration, each with its own id
+    scratch."""
     del y_in_ref  # aliased to y_ref
 
-    def tile_part(i, t):
+    def tile_part(i, t, u):
         segment = colblock_ref[t] if resident else 0
-        return _tile_columns(data_ref, cols_ref, x_ref, idx_ref, combine=kw["combine"],
+        return _tile_columns(data_ref, cols_ref, x_ref, idx_ref.at[u], combine=kw["combine"],
                              tile=i, segment=segment)
 
-    _blocked(rowgroup_ref, y_ref, acc_ref, cur_ref, tile_part, **kw)
+    _blocked(rowgroup_ref, y_ref, acc_ref, cur_ref, tile_part,
+             interleave=idx_ref.shape[0], **kw)
 
 
 def _row_kernel(rowgroup_ref, colblock_ref, width_ref, data_ref, cols_ref, x_ref, y_in_ref,
@@ -317,7 +349,7 @@ def _row_kernel(rowgroup_ref, colblock_ref, width_ref, data_ref, cols_ref, x_ref
     group, kt = data_ref.shape[1], x_ref.shape[1]
     identity = 0.0 if kw["combine"] == "sum" else -jnp.inf
 
-    def tile_part(i, t):
+    def tile_part(i, t, _u):
         base = colblock_ref[t] * col_block
 
         def slot(s, acc):
@@ -459,11 +491,12 @@ def _launches(kernel, scalars, data, cols, x, x_spec, *, x_vmem, tile_space, blo
 
 
 def _fused(rowgroup, colblock, data, cols, x_blocked, *, n_rowgroups, combine,
-           interpret, name, block=None):
+           interpret, name, block=None, interleave=None):
     """The fused launches under the lane gather.  X, ``[n_cb, k, seg]``
     segments, is resident in VMEM for the launch, one k-tile of it, when it
     fits :data:`X_BUDGET`; otherwise a step carries one tile and streams
-    its segment.  ``block`` overrides :data:`BLOCK_LANE` (the sweep)."""
+    its segment.  ``block`` overrides :data:`BLOCK_LANE` and
+    ``interleave`` :data:`INTERLEAVE_LANE` (the sweep)."""
     xs = _segments(x_blocked)
     n_cb, k, seg = xs.shape
     kt, _ = _k_grid(k)
@@ -480,7 +513,9 @@ def _fused(rowgroup, colblock, data, cols, x_blocked, *, n_rowgroups, combine,
     return _launches(
         _lane_kernel, (rowgroup, colblock), data, cols, xs, x_spec, x_vmem=x_vmem,
         tile_space=pltpu.VMEM, block=block, kp=k, n_rowgroups=n_rowgroups, combine=combine,
-        scratch=[pltpu.VMEM((data.shape[1], LANE_TILE), jnp.int32)], interpret=interpret,
+        scratch=[pltpu.VMEM((interleave or INTERLEAVE_LANE, data.shape[1], LANE_TILE),
+                            jnp.int32)],
+        interpret=interpret,
         name=name, resident=resident,
     )
 

@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops
 from repro.kernels.hbp_spmv import (
     LANE_TILE,
+    TILE_CHUNK,
     X_BUDGET,
     _lane_x_bytes,
     hbp_spmm_fused,
@@ -90,12 +91,12 @@ def _shapes(sharding, lane, k, col_block=4096, tiles=T, n_col_blocks=N_COL_BLOCK
     )
 
 
-def _fused(fn, spmm=False):
+def _fused(fn, spmm=False, n_rowgroups=N_ROWGROUPS):
     def call(a):
         width = (a["scalars"],) if spmm else ()  # the fused SpMM's per-tile widths
         return fn.lower(
             a["scalars"], a["scalars"], a["data"], a["cols"], a["x"], *width,
-            n_rowgroups=N_ROWGROUPS,
+            n_rowgroups=n_rowgroups,
         )
     return call
 
@@ -258,3 +259,20 @@ def test_fused_output_in_windows_compiles(one_chip, k, body, block):
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") == 4
 
+
+@pytest.mark.parametrize("k,streamed,col_block", [
+    (1, False, 4096), (1, True, 4096), (4, False, 4096), (4, True, 4096),
+    (1, False, 8192),  # 64 chunks a segment
+])
+def test_lane_body_compiles_at_kron16_size(one_chip, k, streamed, col_block):
+    """The lane body at kron16's size through the entry point a call of
+    that k launches (SpMV at k=1, SpMM below ``K_ROW``), X resident or
+    streamed a segment a step: launches of :data:`TILE_CHUNK` tiles, whose
+    scalar prefetch must fit the v5e's SMEM beside the body's blocks."""
+    assert -(-KRON16["tiles"] // TILE_CHUNK) == 4
+    n_cb = _streamed_col_blocks(k, col_block) if streamed else KRON16["cols"] // col_block
+    assert (tiles_per_step(k, n_cb * col_block, col_block) == 1) == streamed
+    a = _shapes(one_chip, 128, k, col_block, tiles=KRON16["tiles"], n_col_blocks=n_cb)
+    fn = _fused(hbp_spmv_fused if k == 1 else hbp_spmm_fused, spmm=k > 1,
+                n_rowgroups=KRON16["rowgroups"])
+    assert fn(a).compile().as_text().count("tpu_custom_call") == 4
